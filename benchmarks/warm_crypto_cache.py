@@ -9,8 +9,12 @@ prior full run populate `.jax_cache/`) and the heavy tier becomes
 minutes-fast.
 
 Usage (CPU tests):
-    env PYTHONPATH= JAX_PLATFORMS=cpu python benchmarks/warm_crypto_cache.py
-The cache location honors HBBFT_TPU_JAX_CACHE (default .jax_cache/).
+    env JAX_PLATFORMS=cpu python benchmarks/warm_crypto_cache.py
+The cache goes where JAX_COMPILATION_CACHE_DIR says (default .jax_cache/).
+
+``WARM_SERVICE_LEGS=1`` warms the crypto-plane WORKER's cache instead:
+the worker process owns the device, so this parent then never imports
+jax and the in-process legs do not run (one process per chip).
 """
 
 from __future__ import annotations
@@ -28,21 +32,15 @@ def log(msg: str) -> None:
 
 
 def main() -> None:
-    from hbbft_tpu.utils.jaxcache import enable_cache
-
-    enable_cache()
-
     from hbbft_tpu.crypto.backend import VerifyRequest
     from hbbft_tpu.crypto.bls.suite import BLSSuite
     from hbbft_tpu.crypto.keys import SecretKeySet
-    from hbbft_tpu.crypto.tpu.backend import TpuBackend
 
     suite = BLSSuite()
     rng = random.Random(7)
     sks = SecretKeySet.random(1, rng, suite)
     pks = sks.public_keys()
     msg = b"warmup"
-    backend = TpuBackend(suite)
 
     # Warm each legs bucket the heavy tier touches (floor=2: buckets
     # 2/4/8 — the test-tier mixed batches land in nl=8, bisection
@@ -72,6 +70,18 @@ def main() -> None:
             ),
         ],
     }
+    if os.environ.get("WARM_SERVICE_LEGS"):
+        _warm_service_process(suite, batches[8])
+        log("done")
+        return
+
+    from hbbft_tpu.utils.jaxcache import enable_cache
+
+    enable_cache()
+
+    from hbbft_tpu.crypto.tpu.backend import TpuBackend
+
+    backend = TpuBackend(suite)
     for nl, reqs in sorted(batches.items()):
         t0 = time.time()
         ok = backend.verify_batch(reqs)
@@ -157,40 +167,40 @@ def main() -> None:
     svc.stop()
     log(f"cryptoplane service flush warmed in {time.time() - t0:.0f}s")
 
-    # Service-PROCESS arm (round 18): WARM_SERVICE_LEGS=1 spawns the
-    # RPC worker with the TpuBackend and pushes the same mixed-kind
-    # batch through the socket, so the WORKER's own .jax_cache entries
-    # (config9's service-proc-bls BLS/TPU arm) get built now instead of
-    # on first cluster traffic.  The worker inherits this process's
-    # JAX_PLATFORMS/HBBFT_TPU_JAX_CACHE via force_cpu_jax=False — run
-    # this under the same env the deployment will use.
-    if os.environ.get("WARM_SERVICE_LEGS"):
-        from hbbft_tpu.cryptoplane.proc_service import (
-            RpcServiceClient,
-            ServiceProcess,
-        )
-
-        t0 = time.time()
-        with ServiceProcess(
-            suite="bls", backend="tpu", force_cpu_jax=False,
-            ready_timeout_s=600.0,
-        ) as proc:
-            rpc = RpcServiceClient(
-                proc.addr, suite, BatchedBackend(suite), timeout_s=3600.0
-            )
-            ok = rpc.verify_batch(batches[8])
-            assert all(ok)
-            assert rpc.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0, (
-                rpc.metrics.counters
-            )
-            stats = proc.stats()["counters"]
-            assert stats.get("crypto.flushes", 0) == 1, stats
-            rpc.close()
-        log(
-            "service-process (rpc) flush warmed in "
-            f"{time.time() - t0:.0f}s"
-        )
     log("done")
+
+
+def _warm_service_process(suite, reqs) -> None:
+    """Service-PROCESS arm (round 18): spawn the RPC worker with the
+    TpuBackend and push the mixed-kind batch through the socket, so the
+    WORKER's own compile-cache entries (config9's service-proc-bls
+    BLS/TPU arm) get built now instead of on first cluster traffic.
+    The worker inherits this process's environment — run this under the
+    one the deployment will use."""
+    from hbbft_tpu.crypto.backend import BatchedBackend
+    from hbbft_tpu.cryptoplane.proc_service import (
+        COLD_COMPILE_TIMEOUT_S,
+        RpcServiceClient,
+        ServiceProcess,
+    )
+
+    t0 = time.time()
+    with ServiceProcess(
+        suite="bls", backend="tpu", ready_timeout_s=600.0
+    ) as proc:
+        rpc = RpcServiceClient(
+            proc.addr, suite, BatchedBackend(suite),
+            timeout_s=COLD_COMPILE_TIMEOUT_S,
+        )
+        ok = rpc.verify_batch(reqs)
+        assert all(ok)
+        assert rpc.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0, (
+            rpc.metrics.counters
+        )
+        stats = proc.stats()["counters"]
+        assert stats.get("crypto.flushes", 0) == 1, stats
+        rpc.close()
+    log(f"service-process (rpc) flush warmed in {time.time() - t0:.0f}s")
 
 
 if __name__ == "__main__":

@@ -1,0 +1,258 @@
+"""Chip smoke: the crypto-plane worker answers real-BLS flushes on one TPU.
+
+The quickest proof that the system still starts on the chip.  The
+parent (this process) never imports jax: it builds BLS12-381 signature
+share requests with the pure-Python suite, starts ONE worker
+(``python -m hbbft_tpu.cryptoplane.proc_service --suite bls --backend
+tpu``) that owns the chip, and sends two flushes through
+``RpcServiceClient.verify_batch``:
+
+* ``round`` — one N=16 coin round: 16 shares on one document, f=5 of
+  them a valid share of ANOTHER key index at seeded positions.  Bucket
+  (16, 16, 2); expects the exact per-share verdict vector.
+* ``chunk`` — 2048 valid shares on one document, exactly one production
+  chunk (``TpuBackend.CHUNK``).  Bucket (2048, 2048, 2); expects all
+  True.
+
+That is three jitted programs (two scan buckets, one 3-pair pairing
+stage).  Verdicts are compared with the pure-Python oracle
+(``EagerBackend``): every request of ``round``, a seeded sample of 16 of
+``chunk`` (the other 2032 repeat the same 8 signatures).  Any client
+fallback, any worker flush error, a flush count that differs from the
+calls made, or a worker that dies or exits non-zero fails the run.
+
+Output: one JSON line per phase (``host_wall_s`` numbers are host wall
+clock, compile included in ``first_call`` — not device metrics), then
+``{"ok": true, "device": {...}}`` as the worker reported its device.
+Without a TPU nothing is printed on stdout and the exit code is 1.
+
+    python chip_smoke.py [--seed N]
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+import sys
+import time
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from hbbft_tpu.crypto.backend import (  # noqa: E402
+    BatchedBackend,
+    EagerBackend,
+    VerifyRequest,
+)
+from hbbft_tpu.crypto.bls.suite import BLSSuite  # noqa: E402
+from hbbft_tpu.crypto.keys import SecretKeySet  # noqa: E402
+from hbbft_tpu.cryptoplane.proc_service import (  # noqa: E402
+    COLD_COMPILE_TIMEOUT_S,
+    RpcServiceClient,
+    ServiceProcess,
+)
+from hbbft_tpu.utils.metrics import Metrics  # noqa: E402
+
+N_VALIDATORS = 16
+N_FAULTY = 5
+CHUNK = 2048  # TpuBackend.CHUNK's default; importing it would import jax
+SAMPLE = 16
+
+
+class Phase(NamedTuple):
+    name: str
+    bucket: Tuple[int, int, int]
+    reqs: List[VerifyRequest]
+    expected: List[bool]
+    #: indices whose expected verdict is confirmed by the oracle
+    oracle_idx: List[int]
+
+
+def make_phases(seed: int, suite: BLSSuite) -> List[Phase]:
+    """Keys, document and fault positions from ``seed``."""
+    rng = random.Random(seed)
+    sks = SecretKeySet.random(N_FAULTY, rng, suite)
+    pks = sks.public_keys()
+    doc = b"chip-smoke coin round %d" % seed
+    pk = [pks.public_key_share(i) for i in range(N_VALIDATORS)]
+    sig = [sks.secret_key_share(i).sign(doc) for i in range(N_VALIDATORS)]
+
+    bad = set(rng.sample(range(N_VALIDATORS), N_FAULTY))
+    round_reqs = [
+        # wrong but well-formed: a valid share of the next key index
+        VerifyRequest.sig_share(
+            pk[i], doc, sig[(i + 1) % N_VALIDATORS if i in bad else i]
+        )
+        for i in range(N_VALIDATORS)
+    ]
+    # 8 signatures reused across the chunk: verification cost is per
+    # request, and 2048 pure-Python signings would be most of a minute
+    chunk_reqs = [
+        VerifyRequest.sig_share(pk[i % 8], doc, sig[i % 8])
+        for i in range(CHUNK)
+    ]
+    return [
+        Phase(
+            "round", (16, 16, 2), round_reqs,
+            [i not in bad for i in range(N_VALIDATORS)],
+            list(range(N_VALIDATORS)),
+        ),
+        Phase(
+            "chunk", (CHUNK, CHUNK, 2), chunk_reqs, [True] * CHUNK,
+            sorted(rng.sample(range(CHUNK), SAMPLE)),
+        ),
+    ]
+
+
+def check_reference(suite: BLSSuite, phase: Phase) -> Optional[str]:
+    """The construction's expected verdicts against the oracle."""
+    got = EagerBackend(suite).verify_batch(
+        [phase.reqs[i] for i in phase.oracle_idx]
+    )
+    want = [phase.expected[i] for i in phase.oracle_idx]
+    if got != want:
+        return f"{phase.name}: oracle says {got}, construction says {want}"
+    return None
+
+
+def drive(
+    proc: ServiceProcess,
+    suite: BLSSuite,
+    phases: Sequence[Phase],
+    timeout_s: float = COLD_COMPILE_TIMEOUT_S,
+) -> Tuple[List[Dict[str, Any]], List[str]]:
+    """Send each phase twice (the first call compiles) through one RPC
+    client; returns (one row per phase, failures)."""
+    metrics = Metrics()
+    client = RpcServiceClient(
+        proc.addr, suite, BatchedBackend(suite),
+        timeout_s=timeout_s, metrics=metrics,
+    )
+    rows: List[Dict[str, Any]] = []
+    failures: List[str] = []
+    calls = 0
+    flush_total_s = 0.0
+    try:
+        for ph in phases:
+            walls, flush_s = [], []
+            for which in ("first", "repeat"):
+                t0 = time.perf_counter()
+                got = client.verify_batch(ph.reqs)
+                walls.append(time.perf_counter() - t0)
+                calls += 1
+                if got != ph.expected:
+                    wrong = [
+                        i for i, (g, w) in enumerate(zip(got, ph.expected))
+                        if g != w
+                    ]
+                    failures.append(
+                        f"{ph.name} ({which} call): {len(wrong)} verdicts "
+                        f"differ from the reference, first at {wrong[:8]}"
+                    )
+                fell = {
+                    k: v for k, v in metrics.counters.items()
+                    if k.startswith("crypto.rpc.fallback") and v
+                }
+                if fell:
+                    failures.append(
+                        f"{ph.name} ({which} call): client fell back to its "
+                        f"local backend: {fell}"
+                    )
+                if not proc.alive:
+                    failures.append(
+                        f"{ph.name} ({which} call): worker died "
+                        f"(rc={proc.proc.poll()})"
+                    )
+                    return rows, failures
+                stats = proc.stats()
+                worker = stats["counters"]
+                if worker.get("crypto.flush_errors", 0):
+                    failures.append(
+                        f"{ph.name} ({which} call): worker counted "
+                        f"{worker['crypto.flush_errors']} flush errors "
+                        "(traceback on its stderr)"
+                    )
+                if worker.get("crypto.flushes", 0) != calls:
+                    failures.append(
+                        f"{ph.name} ({which} call): worker counted "
+                        f"{worker.get('crypto.flushes', 0)} flushes "
+                        f"after {calls} calls"
+                    )
+                if failures:
+                    return rows, failures
+                # the worker's crypto.flush timer is cumulative
+                total_s = stats["timers"]["crypto.flush"]["total_s"]
+                flush_s.append(total_s - flush_total_s)
+                flush_total_s = total_s
+            ready = proc.ready or {}
+            rows.append({
+                "phase": ph.name,
+                "requests": len(ph.reqs),
+                "bucket": list(ph.bucket),
+                "verdicts_true": sum(ph.expected),
+                "oracle_checked": len(ph.oracle_idx),
+                "host_wall_s": {
+                    "first_call": walls[0],
+                    "repeat_call": walls[1],
+                    "first_call_worker_flush": flush_s[0],
+                    "repeat_call_worker_flush": flush_s[1],
+                },
+                "compile_cache_dir": ready.get("compile_cache_dir"),
+                "compile_cache_empty_at_start": ready.get(
+                    "compile_cache_empty"
+                ),
+                "jax": ready.get("jax"),
+            })
+    finally:
+        client.close()
+    return rows, failures
+
+
+def worker_exit(proc: ServiceProcess) -> Optional[str]:
+    """Stop the worker; a failure string unless it exited 0."""
+    proc.stop(grace_s=30.0)
+    rc = proc.proc.returncode if proc.proc is not None else None
+    return None if rc == 0 else f"worker exit code {rc}"
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    suite = BLSSuite()
+    phases = make_phases(args.seed, suite)
+    failures = [f for f in (check_reference(suite, p) for p in phases) if f]
+    rows: List[Dict[str, Any]] = []
+    device = None
+    if not failures:
+        proc = ServiceProcess(suite="bls", backend="tpu", ready_timeout_s=600.0)
+        try:
+            proc.start()
+            device = (proc.ready or {}).get("device")
+            if not device or device.get("platform") != "tpu":
+                failures.append(
+                    f"the worker holds no TPU: its ready line says {device}"
+                )
+            else:
+                rows, failures = drive(proc, suite, phases)
+        except (OSError, TimeoutError) as e:
+            failures.append(f"worker did not start: {e}")
+        finally:
+            bad_exit = worker_exit(proc)
+        if bad_exit:
+            failures.append(bad_exit)
+    for row in rows:
+        print(json.dumps(row), flush=True)
+    if failures:
+        for f in failures:
+            print(f"chip_smoke: FAIL: {f}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -2,7 +2,7 @@
 
 Import surface for callers (benchmarks, embedders): ``TpuBackend`` —
 the device flush; ``HybridBackend`` — size-routed host/device with
-dead-relay failover.  Submodules (``curve``, ``fq``, ``fq2``,
+dead-device failover.  Submodules (``curve``, ``fq``, ``fq2``,
 ``pairing``) are the kernel internals.
 """
 

@@ -92,8 +92,10 @@ def _keccak_f_cols(state: jnp.ndarray, interpret: bool, blk: int) -> jnp.ndarray
         _keccak_kernel,
         out_shape=jax.ShapeDtypeStruct((50, padded), jnp.uint32),
         grid=(padded // blk,),
-        in_specs=[pl.BlockSpec((50, blk), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((50, blk), lambda i: (0, i)),
+        # a typed zero: under x64 (the test setting) a Python 0 becomes an
+        # i64 block index, which Mosaic refuses to compile for the TPU
+        in_specs=[pl.BlockSpec((50, blk), lambda i: (np.int32(0), i))],
+        out_specs=pl.BlockSpec((50, blk), lambda i: (np.int32(0), i)),
         interpret=interpret,
     )(state)
     return out[:, :n]
