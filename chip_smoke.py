@@ -1,25 +1,28 @@
-"""Chip smoke: the crypto-plane worker answers real-BLS flushes on one TPU.
+"""Chip smoke: the crypto-plane worker answers a real-BLS flush on one TPU.
 
 The quickest proof that the system still starts on the chip.  The
 parent (this process) never imports jax: it builds BLS12-381 signature
 share requests with the pure-Python suite, starts ONE worker
 (``python -m hbbft_tpu.cryptoplane.proc_service --suite bls --backend
-tpu``) that owns the chip, and sends two flushes through
-``RpcServiceClient.verify_batch``:
+tpu``) that owns the chip, and sends phase ``round`` through
+``RpcServiceClient.verify_batch``, twice (the first call compiles):
 
 * ``round`` — one N=16 coin round: 16 shares on one document, f=5 of
   them a valid share of ANOTHER key index at seeded positions.  Bucket
-  (16, 16, 2); expects the exact per-share verdict vector.
-* ``chunk`` — 2048 valid shares on one document, exactly one production
-  chunk (``TpuBackend.CHUNK``).  Bucket (2048, 2048, 2); expects all
-  True.
+  (16, 16, 2); expects the exact per-share verdict vector, so the
+  worker's fault isolation (bisection, re-flushing halves through the
+  same two programs) runs too.
 
-That is three jitted programs (two scan buckets, one 3-pair pairing
-stage).  Verdicts are compared with the pure-Python oracle
-(``EagerBackend``): every request of ``round``, a seeded sample of 16 of
-``chunk`` (the other 2032 repeat the same 8 signatures).  Any client
-fallback, any worker flush error, a flush count that differs from the
-calls made, or a worker that dies or exits non-zero fails the run.
+That is two jitted programs, ``_scan_kernel(16, 16, 2)`` and
+``_pair_kernel(3)``, each 8-10 minutes of XLA compile for a v5e; a cold
+flush compiles them side by side.  The 2048-share production chunk (a
+third program, ten more minutes) does not fit a 1200 s cold run and
+comes with the first benchmark (CHANGES.md, PR 25, has its chip run).
+
+Every verdict is compared with the pure-Python oracle
+(``EagerBackend``).  Any client fallback, any worker flush error, a
+flush count that differs from the calls made, or a worker that dies or
+exits non-zero fails the run.
 
 Output: one JSON line per phase (``host_wall_s`` numbers are host wall
 clock, compile included in ``first_call`` — not device metrics), then
@@ -57,8 +60,6 @@ from hbbft_tpu.utils.metrics import Metrics  # noqa: E402
 
 N_VALIDATORS = 16
 N_FAULTY = 5
-CHUNK = 2048  # TpuBackend.CHUNK's default; importing it would import jax
-SAMPLE = 16
 
 
 class Phase(NamedTuple):
@@ -66,8 +67,6 @@ class Phase(NamedTuple):
     bucket: Tuple[int, int, int]
     reqs: List[VerifyRequest]
     expected: List[bool]
-    #: indices whose expected verdict is confirmed by the oracle
-    oracle_idx: List[int]
 
 
 def make_phases(seed: int, suite: BLSSuite) -> List[Phase]:
@@ -87,33 +86,22 @@ def make_phases(seed: int, suite: BLSSuite) -> List[Phase]:
         )
         for i in range(N_VALIDATORS)
     ]
-    # 8 signatures reused across the chunk: verification cost is per
-    # request, and 2048 pure-Python signings would be most of a minute
-    chunk_reqs = [
-        VerifyRequest.sig_share(pk[i % 8], doc, sig[i % 8])
-        for i in range(CHUNK)
-    ]
     return [
         Phase(
             "round", (16, 16, 2), round_reqs,
             [i not in bad for i in range(N_VALIDATORS)],
-            list(range(N_VALIDATORS)),
-        ),
-        Phase(
-            "chunk", (CHUNK, CHUNK, 2), chunk_reqs, [True] * CHUNK,
-            sorted(rng.sample(range(CHUNK), SAMPLE)),
         ),
     ]
 
 
 def check_reference(suite: BLSSuite, phase: Phase) -> Optional[str]:
     """The construction's expected verdicts against the oracle."""
-    got = EagerBackend(suite).verify_batch(
-        [phase.reqs[i] for i in phase.oracle_idx]
-    )
-    want = [phase.expected[i] for i in phase.oracle_idx]
-    if got != want:
-        return f"{phase.name}: oracle says {got}, construction says {want}"
+    got = EagerBackend(suite).verify_batch(phase.reqs)
+    if got != phase.expected:
+        return (
+            f"{phase.name}: oracle says {got}, "
+            f"construction says {phase.expected}"
+        )
     return None
 
 
@@ -192,7 +180,6 @@ def drive(
                 "requests": len(ph.reqs),
                 "bucket": list(ph.bucket),
                 "verdicts_true": sum(ph.expected),
-                "oracle_checked": len(ph.oracle_idx),
                 "host_wall_s": {
                     "first_call": walls[0],
                     "repeat_call": walls[1],

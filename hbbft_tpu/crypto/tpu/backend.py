@@ -36,6 +36,7 @@ Replaces the per-share CPU pairing checks of upstream
 from __future__ import annotations
 
 import os
+import threading
 import warnings
 from functools import lru_cache
 from typing import Any, Dict, List, Sequence, Tuple
@@ -144,6 +145,40 @@ def _pair_kernel(n_pairs: int):
     return jax.jit(run)
 
 
+#: PAIR-stage compiles started ahead of their first use, by pair count.
+_EARLY_PAIR_COMPILES: Dict[int, threading.Thread] = {}
+
+
+def _compile_pair_kernel_early(n_pairs: int) -> None:
+    """Cold start: compile the PAIR stage on a thread while the caller
+    compiles its SCAN stage.
+
+    Each stage is minutes of XLA compile (measured for a v5e: 8-10 min
+    each, CHANGES.md PR 25) and a flush runs them one after the other,
+    so a cold flush used to wait for the sum; XLA compiles outside the
+    GIL, so it now waits for the longer one.  Identity pairs compile the
+    same ``(n_pairs,)``-shaped program the flush will call and their
+    product is 1.  Once per process and pair count; a warm persistent
+    cache makes it a deserialization.  :meth:`TpuBackend._check_parts`
+    joins the thread before it calls the kernel, so one program is never
+    compiled twice at once; if the compile fails here the flush's own
+    call raises the same error.
+    """
+    if n_pairs in _EARLY_PAIR_COMPILES:
+        return
+
+    def work() -> None:
+        lhs = dcurve.identity(dcurve.G1_OPS, (n_pairs,))
+        rhs = dcurve.identity(dcurve.G2_OPS, (n_pairs,))
+        _pair_kernel(n_pairs)(lhs, rhs)
+
+    thread = threading.Thread(
+        target=work, name=f"pair-compile-{n_pairs}", daemon=True
+    )
+    _EARLY_PAIR_COMPILES[n_pairs] = thread
+    thread.start()
+
+
 def _pairs_bucket(n: int) -> int:
     """Pair-count bucket: exact for small counts, multiples of 8 above.
 
@@ -245,13 +280,17 @@ class TpuBackend(CryptoBackend):
         return g2_entries, g1_entries, rhs
 
     def _aggregate_ok(self, reqs: Sequence[VerifyRequest]) -> bool:
-        return bool(self._check_parts([self._scan_dev(reqs)]))
+        return bool(self._check_parts([self._scan_dev(reqs, alone=True)]))
 
-    def _scan_dev(self, reqs: Sequence[VerifyRequest]):
+    def _scan_dev(self, reqs: Sequence[VerifyRequest], alone: bool = False):
         """Dispatch one chunk's SCAN kernel; returns (sub_ok, lhs, rhs)
         device values WITHOUT forcing a host sync, so independent chunks
-        pipeline on device."""
+        pipeline on device.  ``alone``: this chunk is the whole flush, so
+        its own pair count is the PAIR stage's (several chunks combine
+        into a bucket only :meth:`verify_batch` knows)."""
         (n1, n2, nl), args = self._scan_prep(reqs)
+        if alone and self._mesh is None:
+            _compile_pair_kernel_early(_pairs_bucket(1 + nl))
         return _scan_kernel(n1, n2, nl)(*args)
 
     def _scan_prep(self, reqs: Sequence[VerifyRequest]):
@@ -366,6 +405,9 @@ class TpuBackend(CryptoBackend):
             rhs = tuple(
                 jnp.concatenate([rhs[c], pad2[c]]) for c in range(4)
             )
+        early = _EARLY_PAIR_COMPILES.get(b)
+        if early is not None:
+            early.join()
         ok = _pair_kernel(b)(lhs, rhs)
         for s in sub_oks:
             ok = ok & s
@@ -406,7 +448,10 @@ class TpuBackend(CryptoBackend):
         # Dispatch every chunk's SCAN kernel before syncing on anything:
         # jax dispatch is async, so the device pipelines the chunks and
         # the host pays one round-trip total instead of one per chunk.
-        scans = [self._scan_dev([reqs[i] for i in c]) for c in chunks]
+        scans = [
+            self._scan_dev([reqs[i] for i in c], alone=len(chunks) == 1)
+            for c in chunks
+        ]
         if len(chunks) > 1:
             # Fast path: ALL chunks' pairs through one batched Miller
             # loop + one final exponentiation (fixed pairing cost paid

@@ -25,7 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import chip_smoke  # noqa: E402
-from hbbft_tpu.crypto.backend import BatchedBackend, CryptoBackend  # noqa: E402
+from hbbft_tpu.crypto.backend import CryptoBackend  # noqa: E402
 from hbbft_tpu.crypto.suite import ScalarSuite  # noqa: E402
 from hbbft_tpu.cryptoplane import proc_service  # noqa: E402
 from hbbft_tpu.cryptoplane.proc_service import ServiceProcess  # noqa: E402
@@ -210,22 +210,23 @@ def scalar_phases():
     suite = ScalarSuite()
     phases = chip_smoke.make_phases(11, suite)
     assert [(p.name, len(p.reqs), sum(p.expected)) for p in phases] == [
-        ("round", 16, 11), ("chunk", 2048, 2048),
+        ("round", 16, 11),
     ]
     # the construction agrees with the oracle (same check main() makes)
-    assert [chip_smoke.check_reference(suite, p) for p in phases] == [None] * 2
+    assert [chip_smoke.check_reference(suite, p) for p in phases] == [None]
     return suite, phases
 
 
 def test_phases_follow_the_seed(scalar_phases):
     suite, phases = scalar_phases
-    again = chip_smoke.make_phases(11, suite)
-    other = chip_smoke.make_phases(12, suite)
-    assert again[0].expected == phases[0].expected
-    assert again[1].oracle_idx == phases[1].oracle_idx
-    assert (other[0].expected, other[1].oracle_idx) != (
-        phases[0].expected, phases[1].oracle_idx,
-    )
+    (again,) = chip_smoke.make_phases(11, suite)
+    (other,) = chip_smoke.make_phases(12, suite)
+    assert again.expected == phases[0].expected
+    assert again.reqs == phases[0].reqs
+    assert other.expected != phases[0].expected
+    assert chip_smoke.check_reference(
+        suite, again._replace(expected=other.expected)
+    ).startswith("round: oracle says")
 
 
 def test_drive_passes_against_a_healthy_worker(scalar_phases):
@@ -234,7 +235,7 @@ def test_drive_passes_against_a_healthy_worker(scalar_phases):
         rows, failures = chip_smoke.drive(proc, suite, phases, timeout_s=60.0)
     assert failures == []
     assert [(r["phase"], r["requests"], r["bucket"]) for r in rows] == [
-        ("round", 16, [16, 16, 2]), ("chunk", 2048, [2048, 2048, 2]),
+        ("round", 16, [16, 16, 2]),
     ]
     for r in rows:
         assert set(r["host_wall_s"]) == {
@@ -246,7 +247,7 @@ def test_drive_passes_against_a_healthy_worker(scalar_phases):
 
 
 def test_drive_fails_on_a_wrong_verdict(scalar_phases):
-    suite, (round_, _) = scalar_phases
+    suite, (round_,) = scalar_phases
     lying = round_._replace(expected=[True] * 16)
     with ServiceProcess(suite="scalar", backend="batched") as proc:
         rows, failures = chip_smoke.drive(proc, suite, [lying], timeout_s=60.0)
@@ -257,7 +258,7 @@ def test_drive_fails_on_a_wrong_verdict(scalar_phases):
 def test_drive_fails_on_a_counted_fallback(scalar_phases):
     """A dead worker: the client's local backend answers (correctly), and
     that is exactly what must not pass."""
-    suite, (round_, _) = scalar_phases
+    suite, (round_,) = scalar_phases
     proc = ServiceProcess(suite="scalar", backend="batched").start()
     try:
         proc.kill()
@@ -274,7 +275,7 @@ def test_drive_fails_on_a_counted_fallback(scalar_phases):
 def test_drive_fails_when_the_worker_counts_a_flush_error(scalar_phases):
     """The worker's own flush failed (the client fell back to a right
     answer): both counters are reported."""
-    suite, (round_, _) = scalar_phases
+    suite, (round_,) = scalar_phases
 
     class Broken(CryptoBackend):
         def verify_batch(self, reqs):
@@ -389,5 +390,5 @@ def test_last_line_is_the_contract_and_nothing_more(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "BLSSuite", lambda: suite)
     assert chip_smoke.main(["--seed", "5"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
-    assert [json.loads(ln)["phase"] for ln in lines[:-1]] == ["round", "chunk"]
+    assert [json.loads(ln)["phase"] for ln in lines[:-1]] == ["round"]
     assert json.loads(lines[-1]) == {"ok": True, "device": device}

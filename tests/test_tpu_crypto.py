@@ -451,6 +451,71 @@ def test_tpu_backend_multi_chunk_combined():
     assert be.verify_batch(reqs) == [True] * 24
 
 
+def test_cold_flush_compiles_its_pair_stage_beside_its_scan(monkeypatch):
+    """A single-chunk flush starts the PAIR stage's compile on a thread
+    before it dispatches (and so compiles) its SCAN stage, and joins
+    that thread before calling the pair kernel itself — one program is
+    never compiled twice at once.  Kernels stubbed: no XLA compile."""
+    import threading
+
+    from hbbft_tpu.crypto.tpu import backend as B
+
+    events = []
+    scan_running = threading.Event()
+
+    def fake_pair_kernel(n_pairs):
+        def run(lhs, rhs):
+            early = threading.current_thread().name == f"pair-compile-{n_pairs}"
+            assert int(lhs[3].shape[0]) == int(rhs[3].shape[0]) == n_pairs
+            if early:
+                # "compiling" while the main thread is in its scan
+                assert scan_running.wait(30.0)
+                events.append("pair compiled early")
+            else:
+                events.append("pair called")
+            return jnp.asarray(True)
+
+        return run
+
+    def fake_scan_kernel(n1, n2, nl):
+        def run(*args):
+            events.append("scan")
+            scan_running.set()
+            return (
+                jnp.asarray(True),
+                dc.identity(dc.G1_OPS, (1 + nl,)),
+                dc.identity(dc.G2_OPS, (1 + nl,)),
+            )
+
+        return run
+
+    monkeypatch.setattr(B, "_pair_kernel", fake_pair_kernel)
+    monkeypatch.setattr(B, "_scan_kernel", fake_scan_kernel)
+    monkeypatch.setattr(B, "_EARLY_PAIR_COMPILES", {})
+
+    suite = BLSSuite()
+    sks = SecretKeySet.random(1, random.Random(5), suite)
+    pks = sks.public_keys()
+    reqs = [
+        VerifyRequest.sig_share(
+            pks.public_key_share(i), b"doc", sks.secret_key_share(i).sign(b"doc")
+        )
+        for i in range(2)
+    ]
+    be = TpuBackend(suite)
+    assert be.verify_batch(reqs) == [True, True]
+    assert events == ["scan", "pair compiled early", "pair called"]
+    assert list(B._EARLY_PAIR_COMPILES) == [3]
+    # warm: no second thread, the kernel is called directly
+    assert be.verify_batch(reqs) == [True, True]
+    assert events[3:] == ["scan", "pair called"]
+    # several chunks combine into a bucket no single chunk knows: no
+    # early compile on that path
+    be.CHUNK = 1
+    assert be.verify_batch(reqs) == [True, True]
+    assert list(B._EARLY_PAIR_COMPILES) == [3]
+
+
 def test_hybrid_backend_routing():
     """HybridBackend: device for big flushes, host for small, host-only
     when no accelerator is present (routing logic is platform-free)."""
