@@ -105,6 +105,44 @@ def check_reference(suite: BLSSuite, phase: Phase) -> Optional[str]:
     return None
 
 
+def _call_failures(
+    got: List[bool],
+    expected: List[bool],
+    client: Metrics,
+    worker: Optional[Dict[str, int]],
+    calls: int,
+) -> List[str]:
+    """What one ``verify_batch`` call did wrong (``worker``: the worker's
+    counters after it, None if the worker is dead)."""
+    out = []
+    wrong = [i for i, (g, w) in enumerate(zip(got, expected)) if g != w]
+    if wrong:
+        out.append(
+            f"{len(wrong)} verdicts differ from the reference, "
+            f"first at {wrong[:8]}"
+        )
+    fell = {
+        k: v for k, v in client.counters.items()
+        if k.startswith("crypto.rpc.fallback") and v
+    }
+    if fell:
+        out.append(f"client fell back to its local backend: {fell}")
+    if worker is None:
+        out.append("worker died")
+        return out
+    if worker.get("crypto.flush_errors", 0):
+        out.append(
+            f"worker counted {worker['crypto.flush_errors']} flush errors "
+            "(traceback on its stderr)"
+        )
+    if worker.get("crypto.flushes", 0) != calls:
+        out.append(
+            f"worker counted {worker.get('crypto.flushes', 0)} flushes "
+            f"after {calls} calls"
+        )
+    return out
+
+
 def drive(
     proc: ServiceProcess,
     suite: BLSSuite,
@@ -112,14 +150,15 @@ def drive(
     timeout_s: float = COLD_COMPILE_TIMEOUT_S,
 ) -> Tuple[List[Dict[str, Any]], List[str]]:
     """Send each phase twice (the first call compiles) through one RPC
-    client; returns (one row per phase, failures)."""
+    client; returns (one row per phase, failures) and stops at the first
+    call that fails."""
     metrics = Metrics()
     client = RpcServiceClient(
         proc.addr, suite, BatchedBackend(suite),
         timeout_s=timeout_s, metrics=metrics,
     )
+    ready = proc.ready or {}
     rows: List[Dict[str, Any]] = []
-    failures: List[str] = []
     calls = 0
     flush_total_s = 0.0
     try:
@@ -130,51 +169,18 @@ def drive(
                 got = client.verify_batch(ph.reqs)
                 walls.append(time.perf_counter() - t0)
                 calls += 1
-                if got != ph.expected:
-                    wrong = [
-                        i for i, (g, w) in enumerate(zip(got, ph.expected))
-                        if g != w
-                    ]
-                    failures.append(
-                        f"{ph.name} ({which} call): {len(wrong)} verdicts "
-                        f"differ from the reference, first at {wrong[:8]}"
-                    )
-                fell = {
-                    k: v for k, v in metrics.counters.items()
-                    if k.startswith("crypto.rpc.fallback") and v
-                }
-                if fell:
-                    failures.append(
-                        f"{ph.name} ({which} call): client fell back to its "
-                        f"local backend: {fell}"
-                    )
-                if not proc.alive:
-                    failures.append(
-                        f"{ph.name} ({which} call): worker died "
-                        f"(rc={proc.proc.poll()})"
-                    )
-                    return rows, failures
-                stats = proc.stats()
-                worker = stats["counters"]
-                if worker.get("crypto.flush_errors", 0):
-                    failures.append(
-                        f"{ph.name} ({which} call): worker counted "
-                        f"{worker['crypto.flush_errors']} flush errors "
-                        "(traceback on its stderr)"
-                    )
-                if worker.get("crypto.flushes", 0) != calls:
-                    failures.append(
-                        f"{ph.name} ({which} call): worker counted "
-                        f"{worker.get('crypto.flushes', 0)} flushes "
-                        f"after {calls} calls"
-                    )
+                stats = proc.stats() if proc.alive else None
+                failures = _call_failures(
+                    got, ph.expected, metrics,
+                    stats and stats["counters"], calls,
+                )
                 if failures:
-                    return rows, failures
+                    where = f"{ph.name} ({which} call): "
+                    return rows, [where + f for f in failures]
                 # the worker's crypto.flush timer is cumulative
                 total_s = stats["timers"]["crypto.flush"]["total_s"]
                 flush_s.append(total_s - flush_total_s)
                 flush_total_s = total_s
-            ready = proc.ready or {}
             rows.append({
                 "phase": ph.name,
                 "requests": len(ph.reqs),
@@ -194,7 +200,7 @@ def drive(
             })
     finally:
         client.close()
-    return rows, failures
+    return rows, []
 
 
 def worker_exit(proc: ServiceProcess) -> Optional[str]:
