@@ -1,0 +1,127 @@
+"""Generator ``sig_share_rounds``: rounds of signature shares on one document.
+
+One flush is one round: ``requests`` shares of ``requests`` distinct signers
+on a document that no other flush of the run uses (the worker caches
+``hash_to_g2`` by document, and a real round always brings a new one).
+``wrong`` of them are a valid share of the NEXT key index, which is
+well-formed, in the subgroup, and fails only the pairing equation.
+
+Parameters (a traffic file's ``params``):
+
+* ``requests``: shares per flush.
+* ``wrong``: wrong shares per flush (0: a clean round).
+* ``bisection_hit_nodes``: with ``wrong`` > 0, every round's set of wrong
+  positions is drawn from the seed among those that make a halving
+  bisection over ``requests`` leaves re-check exactly this many failing
+  groups of two or more.  It fixes the work per flush (one aggregate check
+  for the whole round and two for each failing group), so seeds differ in
+  positions, keys and documents, not in the amount of fault isolation.
+
+Keys come from the configuration: a degree-``threshold`` polynomial over the
+scalar field drawn from the seed; signer ``i`` holds ``poly(i + 1)``.
+
+Everything is computed with the benchmark's own plain arithmetic
+(chipbench/reference) and wrapped into the program's request types; the wire
+bytes the plain reference verifies are kept beside each request.
+"""
+
+from __future__ import annotations
+
+import random
+from typing import Any, Dict, List, NamedTuple, Sequence, Tuple
+
+from chipbench.reference import curve as C
+from chipbench.reference import verify as V
+from chipbench.reference.fields import R
+
+
+class Keys(NamedTuple):
+    secrets: List[int]
+    pk_jac: List[tuple]
+    pk_bytes: List[bytes]
+
+
+class Flush(NamedTuple):
+    """One ``verify_batch`` call: the requests, the verdicts the
+    construction expects, and each request's wire form for the reference."""
+
+    requests: List[Any]
+    expected: List[bool]
+    wire: List[Tuple[bytes, bytes, bytes]]
+    documents: int
+
+
+def make_keys(config: Dict[str, Any], params: Dict[str, Any], seed: int) -> Keys:
+    rng = random.Random(f"chipbench keys {seed}")
+    coeffs = [rng.randrange(R) for _ in range(int(config["threshold"]) + 1)]
+    secrets = []
+    for i in range(int(params["requests"])):
+        x, acc = i + 1, 0
+        for c in reversed(coeffs):
+            acc = (acc * x + c) % R
+        secrets.append(acc)
+    pk_jac = [V.public_share(s) for s in secrets]
+    return Keys(secrets, pk_jac, [V.g1_to_bytes(p) for p in pk_jac])
+
+
+def hit_nodes(n: int, wrong: Sequence[int]) -> int:
+    """Groups of two or more leaves that a halving bisection over ``n``
+    leaves finds failing (the whole round included)."""
+    bad = set(wrong)
+
+    def walk(lo: int, hi: int) -> int:
+        if hi - lo < 2 or not any(lo <= b < hi for b in bad):
+            return 0
+        mid = lo + (hi - lo) // 2
+        return 1 + walk(lo, mid) + walk(mid, hi)
+
+    return walk(0, n)
+
+
+def wrong_positions(params: Dict[str, Any], rng: random.Random) -> List[int]:
+    n, k = int(params["requests"]), int(params.get("wrong", 0))
+    if k == 0:
+        return []
+    want = params.get("bisection_hit_nodes")
+    while True:
+        pos = sorted(rng.sample(range(n), k))
+        if want is None or hit_nodes(n, pos) == int(want):
+            return pos
+
+
+def make_flush(
+    config: Dict[str, Any],
+    params: Dict[str, Any],
+    seed: int,
+    index: int,
+    keys: Keys,
+) -> Flush:
+    """Flush ``index`` of the run with ``seed``; independent of every other
+    index, so a pool can be built in any order or in parallel."""
+    from hbbft_tpu.crypto.backend import VerifyRequest
+    from hbbft_tpu.crypto.bls.suite import BLSSuite, G1Elem, G2Elem
+    from hbbft_tpu.crypto.keys import PublicKeyShare, SignatureShare
+
+    suite = BLSSuite()
+    n = int(params["requests"])
+    rng = random.Random(f"chipbench flush {seed} {index}")
+    doc = b"chipbench %s seed %d flush %d" % (
+        str(config["name"]).encode(), seed, index,
+    )
+    bad = set(wrong_positions(params, rng))
+    h = C.hash_to_g2(doc)
+    sig_jac = [V.sign(s, h) for s in keys.secrets]
+    sig_bytes = [V.g2_to_bytes(s) for s in sig_jac]
+    requests, expected, wire = [], [], []
+    for i in range(n):
+        j = (i + 1) % n if i in bad else i
+        requests.append(
+            VerifyRequest.sig_share(
+                PublicKeyShare(G1Elem(keys.pk_jac[i]), suite),
+                doc,
+                SignatureShare(G2Elem(sig_jac[j]), suite),
+            )
+        )
+        expected.append(i not in bad)
+        wire.append((keys.pk_bytes[i], doc, sig_bytes[j]))
+    return Flush(requests, expected, wire, 1)
