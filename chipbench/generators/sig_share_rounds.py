@@ -3,13 +3,20 @@
 One flush is one round: ``requests`` shares of ``requests`` distinct signers
 on a document that no other flush of the run uses (the worker caches
 ``hash_to_g2`` by document, and a real round always brings a new one).
-``wrong`` of them are a valid share of the NEXT key index, which is
-well-formed, in the subgroup, and fails only the pairing equation.
+``wrong`` of them are bad shares, of the kinds ``wrong_kinds`` lists.
 
-Parameters (a traffic file's ``params``):
+Parameters (a traffic file's ``params``, and its ``probe``):
 
 * ``requests``: shares per flush.
 * ``wrong``: wrong shares per flush (0: a clean round).
+* ``wrong_kinds``: the kinds of the wrong shares, dealt in turn to the
+  wrong positions in rising order (default ``["next_key"]``):
+  ``next_key``, a valid share of the NEXT key index, which is well-formed,
+  in the subgroup, and fails only the pairing equation; ``identity``, the
+  point at infinity, which decodes and is in every subgroup.  A share off
+  the curve or outside the r-torsion cannot be sent: the RPC server's
+  decode refuses the whole frame (PERF.md, section 4), so the call fails
+  and no verdict comes back.
 * ``bisection_hit_nodes``: with ``wrong`` > 0, every round's set of wrong
   positions is drawn from the seed among those that make a halving
   bisection over ``requests`` leaves re-check exactly this many failing
@@ -108,20 +115,31 @@ def make_flush(
     doc = b"chipbench %s seed %d flush %d" % (
         str(config["name"]).encode(), seed, index,
     )
-    bad = set(wrong_positions(params, rng))
+    kinds = list(params.get("wrong_kinds") or ["next_key"])
+    bad = {
+        pos: kinds[k % len(kinds)]
+        for k, pos in enumerate(wrong_positions(params, rng))
+    }
     h = C.hash_to_g2(doc)
     sig_jac = [V.sign(s, h) for s in keys.secrets]
-    sig_bytes = [V.g2_to_bytes(s) for s in sig_jac]
     requests, expected, wire = [], [], []
     for i in range(n):
-        j = (i + 1) % n if i in bad else i
+        kind = bad.get(i)
+        if kind is None:
+            share = sig_jac[i]
+        elif kind == "next_key":
+            share = sig_jac[(i + 1) % n]
+        elif kind == "identity":
+            share = C.jac_identity(C.FQ2_OPS)
+        else:
+            raise ValueError(f"unknown kind of wrong share {kind!r}")
         requests.append(
             VerifyRequest.sig_share(
                 PublicKeyShare(G1Elem(keys.pk_jac[i]), suite),
                 doc,
-                SignatureShare(G2Elem(sig_jac[j]), suite),
+                SignatureShare(G2Elem(share), suite),
             )
         )
-        expected.append(i not in bad)
-        wire.append((keys.pk_bytes[i], doc, sig_bytes[j]))
+        expected.append(kind is None)
+        wire.append((keys.pk_bytes[i], doc, V.g2_to_bytes(share)))
     return Flush(requests, expected, wire, 1)

@@ -7,12 +7,13 @@ imports jax.
 
 Set-up (``setup_s``: process start to the first timed flush) is the worker's
 start, one warm-up flush of the cell's own traffic (the compile, or the
-cache read) and, overlapped with it in this process and its helpers, the
-building of the seeded traffic pool and the plain reference's verdicts for
-the sample that decides ``correct``.
+cache read), the traffic's probe flush (a round with one wrong share of each
+kind, so that a path that accepts everything shows in every cell) and,
+overlapped with the warm-up in helper processes, the building of the seeded
+traffic pool and the plain reference's verdict on every request of it.
 
 The pieces (:class:`Session`, :class:`Prepared`, :func:`judge`) are apart so
-that ``chipbench/tools/seeds.py`` can drive many seeds through one worker.
+that a builder can drive many seeds through one worker.
 """
 
 from __future__ import annotations
@@ -22,7 +23,6 @@ import importlib
 import json
 import multiprocessing
 import os
-import random
 import shutil
 import subprocess
 import sys
@@ -85,91 +85,73 @@ class Cell:
         ]
 
 
+#: Helper processes that build the pool and run the plain reference while
+#: the worker warms up: plain Python, no jax, ended before the window.
+HELPERS = max(2, min(6, (os.cpu_count() or 2) // 2))
+
+WARM_UP = 0    # index of the warm-up flush; the window's are 1..pool_flushes
+PROBE = -1     # index of the probe flush
+
+
 def _build_one(args: Tuple[str, Dict, Dict, int, int, Any]):
+    """One flush from the seed and the plain reference's verdict on each of
+    its requests, from their wire bytes."""
+    from chipbench.reference.verify import Reference
+
     generator, config, params, seed, index, keys = args
     mod = importlib.import_module("chipbench.generators." + generator)
-    return mod.make_flush(config, params, seed, index, keys)
-
-
-def draw_sample(seed: int, pool_flushes: int, requests: int, n: int) -> List[Tuple[int, int]]:
-    """The (flush, position) pairs whose answers the plain reference judges:
-    every other one from flush 1, which every run completes, the rest from
-    the first half of the pool (what a run is expected to reach)."""
-    rng = random.Random(f"chipbench sample {seed}")
-    reach = max(1, pool_flushes // 2)
-    picked: List[Tuple[int, int]] = []
-    seen = set()
-    for k in range(n):
-        flush = 1 if k % 2 == 0 else rng.randrange(1, reach + 1)
-        pair = (flush, rng.randrange(requests))
-        if pair not in seen:
-            seen.add(pair)
-            picked.append(pair)
-    return picked
+    flush = mod.make_flush(config, params, seed, index, keys)
+    t = time.perf_counter()
+    reference = Reference()
+    verdicts = [reference.verify(*wire) for wire in flush.wire]
+    return flush, verdicts, time.perf_counter() - t
 
 
 class Prepared:
-    """One seed's traffic and the reference's verdicts on its sample.
+    """One seed's traffic and the plain reference's verdict on all of it.
 
-    Flush 0 is the warm-up.  The flushes are built from the seed by
-    ``build_processes`` helper processes (plain Python, no jax) and the sample
-    is judged by the plain reference on a thread of this process, both while
-    the worker warms up; :meth:`finish` waits for both and ends the helpers,
-    so nothing of this runs inside the window."""
+    Flush ``WARM_UP`` is the warm-up, ``PROBE`` the traffic's probe round
+    (absent where the traffic file has no ``probe``), 1.. the window's pool.
+    ``HELPERS`` processes build each flush from the seed and judge it while
+    the worker warms up; :meth:`finish` waits for them and ends them, so
+    nothing of this runs inside the window."""
 
     def __init__(self, cell: Cell, seed: int, pool_flushes: Optional[int] = None) -> None:
-        from chipbench.reference.verify import Reference
-
         traffic = cell.traffic
         self.pool_flushes = int(pool_flushes or traffic["pool_flushes"])
         self.keys = cell.generator.make_keys(cell.config, traffic["params"], seed)
         self._executor = concurrent.futures.ProcessPoolExecutor(
-            max_workers=int(traffic.get("build_processes", 2)),
-            mp_context=multiprocessing.get_context("spawn"),
+            max_workers=HELPERS, mp_context=multiprocessing.get_context("spawn"),
         )
-        self._futures = [
-            self._executor.submit(
+        order = [(WARM_UP, traffic["params"])]
+        if traffic.get("probe"):
+            order.append((PROBE, traffic["probe"]))
+        order += [(i, traffic["params"]) for i in range(1, 1 + self.pool_flushes)]
+        self._futures = {
+            index: self._executor.submit(
                 _build_one,
-                (traffic["generator"], cell.config, traffic["params"], seed, i,
-                 self.keys),
+                (traffic["generator"], cell.config, params, seed, index, self.keys),
             )
-            for i in range(1 + self.pool_flushes)
-        ]
-        self.sample = draw_sample(
-            seed, self.pool_flushes, cell.requests_per_flush,
-            int(traffic["check_requests"]),
-        )
-        self.reference_verdicts: Dict[Tuple[int, int], bool] = {}
-        self.reference_s = 0.0
-        self._reference = Reference()
-        self._judge = threading.Thread(
-            target=self._judge_sample, name="chipbench-reference"
-        )
-        self._judge.start()
+            for index, params in order
+        }
+        self.has_probe = PROBE in self._futures
         self.flushes: List[Any] = []
-
-    def _judge_sample(self) -> None:
-        for flush_i, pos in self.sample:
-            try:
-                wire = self.get(flush_i).wire[pos]
-            except concurrent.futures.CancelledError:
-                return  # abandoned: the run prints no result
-            t = time.perf_counter()
-            self.reference_verdicts[(flush_i, pos)] = self._reference.verify(*wire)
-            self.reference_s += time.perf_counter() - t
+        self.reference_s = 0.0
 
     def get(self, index: int):
-        return self._futures[index].result()
+        return self._futures[index].result()[0]
+
+    def reference(self, index: int) -> List[bool]:
+        return self._futures[index].result()[1]
 
     def finish(self) -> None:
-        self._judge.join()
-        self.flushes = [f.result() for f in self._futures]
+        self.flushes = [self.get(i) for i in range(1 + self.pool_flushes)]
+        self.reference_s = sum(f.result()[2] for f in self._futures.values())
         self._executor.shutdown(wait=True)
 
     def abandon(self) -> None:
-        """End the helpers and the reference's thread, built or not."""
+        """End the helpers, built or not."""
         self._executor.shutdown(wait=True, cancel_futures=True)
-        self._judge.join()
 
 
 class _NoFallback:
@@ -258,73 +240,48 @@ class Session:
             return None
         return sorted(os.listdir(path))
 
-    def warm_up(self, flush: Any) -> Dict[str, Any]:
+    def setup_flush(self, index: int, flush: Any) -> Dict[str, Any]:
+        """One flush of the set-up (the warm-up, the probe): its answers and
+        how long it took."""
         t = time.perf_counter()
         got = self.client.verify_batch(flush.requests)
         seconds = time.perf_counter() - t
         if not self.worker.alive:
-            raise NoResult(EXIT_WORKER, "the worker died during the warm-up flush")
-        return {
-            "seconds": seconds,
-            "wrong": sum(g != w for g, w in zip(got, flush.expected)),
-        }
+            raise NoResult(EXIT_WORKER, f"the worker died during set-up flush {index}")
+        return {"index": index, "answers": got, "seconds": seconds}
 
     def window(
         self,
         flushes: Sequence[Any],
         seconds: float,
-        trace_flushes: int = 0,
+        trace: Optional[Dict[str, Any]] = None,
         on_start: Optional[Callable[[], None]] = None,
-        trace_options: Optional[Dict[str, Any]] = None,
-        trace_seconds: Optional[float] = None,
     ) -> Dict[str, Any]:
         """Drive flushes 1.. in a closed loop.  Untraced: until ``seconds``
         have passed, the flush in flight then is finished, and the window is
-        from the first send to the last answer.  Traced (``trace_flushes`` >
-        0): one flush, then the profiler's window around that many whole
-        flushes, and no more, however long ``seconds`` is.  Where the traffic
-        sets ``trace_seconds`` the profiler's window is closed that long
-        after it opened, inside the flush: the device's trace buffer holds
-        about 5 million op events, 1.3 s of these programs (PERF.md)."""
-        trace = trace_flushes > 0
+        from the first send to the last answer.  Traced (``trace`` is the
+        traffic's plan): one flush in steady state, then ``host_flushes``
+        whole flushes under a profiler window that records the host's
+        runtime events alone (its stop takes 0.3 s and it holds every program
+        launch of a flush however long), then the device's window around
+        ``flushes`` whole flushes, and no more, however long ``seconds`` is.
+        Where the plan sets ``seconds`` the device's window is closed that
+        long after it opened, inside the flush: the device's trace buffer
+        holds about 5 million op events, 1.3 s of these programs (PERF.md)."""
         if trace:
-            shutil.rmtree(self.trace_dir, ignore_errors=True)  # one trace on disk
+            shutil.rmtree(self.trace_dir, ignore_errors=True)  # one run's on disk
         stats0 = self.stats()
         cache0 = self._cache_entries()
-        fell0 = self._fallback_requests()
+        fell_seen = self._fallback_requests()
+        fell_calls = wraps = 0
+        nxt = 1
+        t_end = 0.0
         lat: List[float] = []
         stamps: List[Tuple[int, int]] = []
         answers: List[Tuple[int, List[bool]]] = []
-        wraps = fell_calls = traced_from = 0
-        trace_window: Optional[Tuple[int, int]] = None
-        trace_file: Dict[str, Any] = {}
-        cut: Dict[str, int] = {}  # the window's end, where a timer closed it
-        timer: Optional[threading.Timer] = None
 
-        def close_trace() -> None:
-            cut["stop_ns"] = time.time_ns()
-            trace_file.update(self.worker.control(op="trace_stop"))
-        stats_a = stats0
-        nxt = 1
-        if on_start:
-            on_start()
-        t_start = t_end = time.perf_counter()
-        while True:
-            if trace and len(lat) == 1 and trace_window is None:
-                stats_a = self.stats()
-                started = time.time_ns()
-                if self.device:  # a worker without jax (the tests') has no trace
-                    started = int(
-                        self.worker.control(
-                            op="trace_start", dir=self.trace_dir,
-                            options=trace_options,
-                        )["wall_ns"]
-                    )
-                trace_window = (started, 0)
-                traced_from = len(lat)
-                if trace_seconds and self.device:
-                    timer = threading.Timer(trace_seconds, close_trace)
-                    timer.start()
+        def call() -> None:
+            nonlocal fell_seen, fell_calls, wraps, nxt, t_end
             w0 = time.time_ns()
             c0 = time.perf_counter()
             got = self.client.verify_batch(flushes[nxt].requests)
@@ -333,40 +290,90 @@ class Session:
             lat.append(t_end - c0)
             answers.append((nxt, got))
             fell = self._fallback_requests()
-            if fell != fell0:
+            if fell != fell_seen:
+                fell_seen = fell
                 fell_calls += 1
-                fell0 = fell
             nxt += 1
             if nxt >= len(flushes):
                 nxt = 1
                 wraps += 1
-            if trace:
-                if trace_window and len(lat) - traced_from >= trace_flushes:
+
+        def opened(which: str, host_only: bool) -> int:
+            """Open a profiler window in the worker; its start on the wall
+            clock.  A worker without jax (the tests') traces nothing."""
+            if not self.device:
+                return time.time_ns()
+            return int(self.worker.control(
+                op="trace_start", dir=os.path.join(self.trace_dir, which),
+                host_only=host_only,
+            )["wall_ns"])
+
+        traced: Dict[str, Any] = {}
+        if on_start:
+            on_start()
+        t_start = time.perf_counter()
+        if not trace:
+            while True:
+                call()
+                if t_end - t_start >= seconds:
                     break
-            elif t_end - t_start >= seconds:
-                break
-        if trace_window:
-            stop_ns = time.time_ns()
+        else:
+            call()
+            host_flushes = int(trace.get("host_flushes", 0))
+            if host_flushes:
+                h0 = self.stats()
+                start = opened("host", host_only=True)
+                first = len(lat)
+                for _ in range(host_flushes):
+                    call()
+                stop = time.time_ns()
+                if self.device:
+                    self.worker.control(op="trace_stop")
+                traced["host"] = {
+                    "window": (start, stop), "from": first, "to": len(lat),
+                    "stats0": h0, "stats1": self.stats(),
+                }
+            cut: Dict[str, Any] = {}
+
+            def close_device() -> None:
+                cut["stop_ns"] = time.time_ns()
+                cut.update(self.worker.control(op="trace_stop"))
+
+            stats_a = self.stats()
+            start = opened("device", host_only=False)
+            first = len(lat)
+            timer = None
+            if trace.get("seconds") and self.device:
+                timer = threading.Timer(float(trace["seconds"]), close_device)
+                timer.start()
+            for _ in range(int(trace["flushes"])):
+                call()
+            stop = time.time_ns()
             if timer is not None:
                 timer.cancel()  # a flush shorter than the cap: close it here
                 timer.join()
+            reply = cut
             if self.device and not cut:
-                trace_file.update(self.worker.control(op="trace_stop"))
-            trace_window = (trace_window[0], cut.get("stop_ns", stop_ns))
+                reply = self.worker.control(op="trace_stop")
+            traced["device"] = {
+                "window": (start, cut.get("stop_ns", stop)), "from": first,
+                "to": len(lat), "stats0": stats_a, "cut": bool(cut),
+                "stop_s": reply.get("stop_s"),
+            }
         alive = self.worker.alive
         stats1 = self.stats() if alive else None
         cache1 = self._cache_entries()
+        if "device" in traced:
+            traced["device"]["stats1"] = stats1
         return {
             "lat": lat, "stamps": stamps, "answers": answers, "wraps": wraps,
             "fell_calls": fell_calls, "window_s": t_end - t_start,
-            "stats0": stats0, "stats_a": stats_a, "stats1": stats1,
+            "stats0": stats0, "stats1": stats1,
             "new_cache_entries": (
                 len(set(cache1) - set(cache0))
                 if cache0 is not None and cache1 is not None else 0
             ),
-            "trace_window": trace_window, "traced_from": traced_from,
-            "trace_stop_s": trace_file.get("stop_s"),
-            "trace_cut": bool(cut),
+            "traced": traced,
         }
 
     def memory_peak(self) -> Optional[int]:
@@ -384,15 +391,17 @@ class Session:
 
 
 def judge(
-    cell: Cell, prep: Prepared, obs: Dict[str, Any], warm_wrong: int = 0,
+    cell: Cell, prep: Prepared, obs: Dict[str, Any],
+    setup_answers: Sequence[Dict[str, Any]] = (),
     worker_rc: Optional[int] = 0,
 ) -> Tuple[bool, Dict[str, Dict[str, int]], int, List[str]]:
     """``correct``, the numbers compared each beside its limit, ``failed``
     and what went wrong on the way, for one window.
 
-    Every answer is held against the verdict the construction expects, and
-    the seeded sample against the plain reference, which so judges the
-    construction as well.  All limits are 0: the comparison is exact."""
+    Every answer of the window and of the set-up's flushes is held against
+    the plain reference's verdict on that request and against the verdict
+    the construction expects, and the construction against the reference on
+    every request built.  All limits are 0: the comparison is exact."""
     n_req = cell.requests_per_flush
     calls = len(obs["lat"])
     c0 = obs["stats0"].get("counters", {})
@@ -409,36 +418,34 @@ def judge(
     if obs["stats1"] is not None and worker_flushes != calls:
         problems.append(f"worker counted {worker_flushes} flushes for {calls} calls")
 
-    vs_construction = warm_wrong
-    first_answer: Dict[int, List[bool]] = {}
-    for idx, got in obs["answers"]:
-        first_answer.setdefault(idx, got)
-        vs_construction += sum(
-            g != w for g, w in zip(got, prep.flushes[idx].expected)
-        )
-    vs_reference = construction_vs_reference = checked = 0
-    for (flush_i, pos), verdict in prep.reference_verdicts.items():
-        if prep.flushes[flush_i].expected[pos] != verdict:
-            construction_vs_reference += 1
-        if flush_i in first_answer:
-            checked += 1
-            if first_answer[flush_i][pos] != verdict:
-                vs_reference += 1
-    min_checked = len(prep.sample) // 3
+    vs_reference = vs_construction = checked = 0
+    every = [(s["index"], s["answers"]) for s in setup_answers] + list(obs["answers"])
+    for index, got in every:
+        vs_reference += sum(g != w for g, w in zip(got, prep.reference(index)))
+        vs_construction += sum(g != w for g, w in zip(got, prep.get(index).expected))
+        checked += len(got)
+    built = [WARM_UP] + ([PROBE] if prep.has_probe else [])
+    built += range(1, 1 + prep.pool_flushes)
+    construction_vs_reference = sum(
+        e != v
+        for i in built
+        for e, v in zip(prep.get(i).expected, prep.reference(i))
+    )
+    due = n_req * len(every)
     compared = {
         "answers_differing_from_reference": {"value": vs_reference, "limit": 0},
         "answers_differing_from_construction": {"value": vs_construction, "limit": 0},
         "construction_differing_from_reference": {
             "value": construction_vs_reference, "limit": 0,
         },
-        "answers_checked_by_reference": {"value": checked, "at_least": min_checked},
+        "answers_checked_by_reference": {"value": checked, "at_least": due},
         "requests_not_answered_by_chip_path": {"value": failed, "limit": 0},
         "flush_count_mismatch_or_errors": {"value": len(problems), "limit": 0},
         "programs_compiled_in_window": {
             "value": obs["new_cache_entries"], "limit": 0,
         },
     }
-    correct = checked >= min_checked and all(
+    correct = checked >= due and all(
         entry["value"] == 0 for entry in compared.values() if "limit" in entry
     )
     return correct, compared, failed, problems
@@ -471,38 +478,57 @@ def _flush_total_s(stats: Optional[Dict[str, Any]]) -> float:
     return (stats or {}).get("timers", {}).get("crypto.flush", {}).get("total_s", 0.0)
 
 
+def _flushes(stats: Optional[Dict[str, Any]]) -> int:
+    return (stats or {}).get("counters", {}).get("crypto.flushes", 0)
+
+
 def layer_metrics(
     cell: Cell, session: Session, prep: Prepared, obs: Dict[str, Any],
     notes: Dict[str, Any],
 ) -> Tuple[Dict[str, Dict[str, Any]], Optional[Dict[str, Any]]]:
     """The per-layer metrics of a traced window, each from its own reader
-    under ``chipbench/layer_metrics``, and the trace's reduction."""
-    reduced = None
-    path = find_trace(session.trace_dir) if session.device else None
-    if path:
-        reduced = reduce_trace_in_child({
-            "path": path,
-            "window_wall_ns": list(obs["trace_window"]),
-            "flushes_wall_ns": [list(s) for s in obs["stamps"][obs["traced_from"]:]],
-        })
-        notes["trace_bytes"] = os.path.getsize(path)
-        notes["trace_stop_s"] = obs["trace_stop_s"]
-        notes["trace_cut_inside_flush"] = obs["trace_cut"]
-    counters1 = (obs["stats1"] or {}).get("counters", {})
+    under ``chipbench/layer_metrics``, and the device trace's reduction."""
+    dev = obs["traced"]["device"]
+    host = obs["traced"].get("host")
+    reduced = host_seen = None
+    if session.device:
+        request: Dict[str, Any] = {}
+        path = find_trace(os.path.join(session.trace_dir, "device"))
+        if path:
+            request.update(
+                path=path, window_wall_ns=list(dev["window"]),
+                flushes_wall_ns=[list(s) for s in obs["stamps"][dev["from"]:dev["to"]]],
+            )
+            notes["trace_bytes"] = os.path.getsize(path)
+            notes["trace_stop_s"] = dev["stop_s"]
+            notes["trace_cut_inside_flush"] = dev["cut"]
+        host_path = host and find_trace(os.path.join(session.trace_dir, "host"))
+        if host_path:
+            request.update(host_path=host_path, host_window_wall_ns=list(host["window"]))
+        if request:
+            out = reduce_trace_in_child(request)
+            reduced = out.get("device")
+            if host_path and out.get("host_launches"):
+                host_seen = {
+                    "launches": out["host_launches"],
+                    "flushes": host["to"] - host["from"],
+                    "worker_flush_s": _flush_total_s(host["stats1"])
+                    - _flush_total_s(host["stats0"]),
+                    "worker_flushes": _flushes(host["stats1"]) - _flushes(host["stats0"]),
+                }
+                notes["host_window_launches"] = host_seen["launches"]
     seen = {
         "cell": cell.cell,
         "config": cell.config,
         "traffic": cell.traffic,
         "device_kind": session.device.get("kind"),
-        "flushes": len(obs["lat"]) - obs["traced_from"],
-        "client_s": sum(obs["lat"][obs["traced_from"]:]),
-        "worker_flush_s": _flush_total_s(obs["stats1"]) - _flush_total_s(obs["stats_a"]),
-        "worker_flushes": (
-            counters1.get("crypto.flushes", 0)
-            - obs["stats_a"].get("counters", {}).get("crypto.flushes", 0)
-        ),
+        "flushes": dev["to"] - dev["from"],
+        "client_s": sum(obs["lat"][dev["from"]:dev["to"]]),
+        "worker_flush_s": _flush_total_s(dev["stats1"]) - _flush_total_s(dev["stats0"]),
+        "worker_flushes": _flushes(dev["stats1"]) - _flushes(dev["stats0"]),
         "trace": reduced,
-        "trace_cut": obs["trace_cut"],
+        "trace_cut": dev["cut"],
+        "host": host_seen,
         "documents_per_flush": prep.flushes[1].documents,
         "document_bytes": len(prep.flushes[1].wire[0][1]),
         "notes": notes,
@@ -560,15 +586,15 @@ def run_cell(
     try:
         prep = Prepared(cell, seed)
         session.connect()
-        warm = session.warm_up(prep.get(0))
+        set_up = [session.setup_flush(WARM_UP, prep.get(WARM_UP))]
+        if prep.has_probe:
+            set_up.append(session.setup_flush(PROBE, prep.get(PROBE)))
         waited = time.perf_counter()
         prep.finish()
         parent_behind_s = time.perf_counter() - waited
         obs = session.window(
-            prep.flushes, seconds,
-            int(cell.traffic["trace_flushes"]) if trace else 0,
+            prep.flushes, seconds, cell.traffic["trace"] if trace else None,
             on_start=lambda: setup.update(s=time.perf_counter() - t0),
-            trace_seconds=cell.traffic.get("trace_seconds"),
         )
         memory = session.memory_peak()
     except NoResult as e:
@@ -580,7 +606,7 @@ def run_cell(
         session.close()
 
     correct, compared, failed, problems = judge(
-        cell, prep, obs, warm_wrong=warm["wrong"], worker_rc=session.worker_rc
+        cell, prep, obs, set_up, worker_rc=session.worker_rc
     )
     calls = len(obs["lat"])
     attempted = calls * cell.requests_per_flush
@@ -605,6 +631,8 @@ def run_cell(
             notes["idle_by_label_s"] = reduced["idle_by_label_s"]
             notes["trace_anchored"] = reduced["anchored"]
             notes["device_launches"] = reduced["launches"]
+            notes["host_launch_events_in_device_window"] = reduced["host_launch_events"]
+            notes["module_s_per_launch"] = reduced["module_s_per_launch"]
     else:
         lat_ms = [x * 1e3 for x in obs["lat"]]
         values = {
@@ -631,7 +659,9 @@ def run_cell(
     line["run"] = {
         "workload": cell.name, "seed": seed, "trace": bool(trace),
         "flushes": calls, "window_s": obs["window_s"], "setup_s": setup["s"],
-        "warmup_flush_s": warm["seconds"], "reference_s": prep.reference_s,
+        "warmup_flush_s": set_up[0]["seconds"],
+        "probe_flush_s": set_up[-1]["seconds"] if prep.has_probe else None,
+        "reference_s": prep.reference_s,
         "parent_behind_worker_s": parent_behind_s,
         "pool_wraps": obs["wraps"],
         "compile_cache_dir": ready.get("compile_cache_dir"),

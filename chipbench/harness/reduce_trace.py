@@ -8,7 +8,12 @@ module's or an op's name: both flush stages are ``jit_run`` today.
 * launches: the events on its ``XLA Modules`` line, one per program run;
 * busy: the union of the intervals of its ``XLA Ops`` line (of the modules
   line where a trace has no ops line), so overlapping events count once;
-* idle gaps: the stretches of the traced window that the union leaves.
+* idle gaps: the stretches of the traced window that the union leaves;
+* launches as the host saw them: the runtime's ``tpu::System::Execute=>Done``
+  events on the host's planes, one per program run.  A window that records
+  the host alone holds them for a flush of any length (the device's buffer
+  does not), and in a device window they stand beside the modules line, so
+  every traced run checks the one count against the other.
 
 The trace has its own clock.  The worker entry writes one host event named
 ``chipbench_anchor:<time.time_ns()>`` right after the trace starts; its
@@ -29,6 +34,7 @@ DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 ANCHOR = "chipbench_anchor:"
+HOST_LAUNCH = "tpu::System::Execute=>Done"
 TOP = 10
 NAME_CHARS = 80
 
@@ -75,6 +81,38 @@ def _anchor_offset_ns(data: Any) -> Optional[float]:
     return None
 
 
+def host_launches(data: Any, window_wall_ns: Optional[Interval] = None) -> int:
+    """The runtime's launch events on the host's planes; inside the window
+    where the trace has its anchor and a window is given."""
+    offset = _anchor_offset_ns(data)
+    lo = hi = None
+    if offset is not None and window_wall_ns is not None:
+        lo, hi = window_wall_ns[0] - offset, window_wall_ns[1] - offset
+    count = 0
+    for plane in data.planes:
+        if DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == HOST_LAUNCH and (
+                    lo is None or lo <= float(ev.start_ns) <= hi
+                ):
+                    count += 1
+    return count
+
+
+def whole_periods(names: Sequence[str]) -> int:
+    """How many of ``names``, from the first, make up whole repeats of the
+    shortest pattern that the sequence repeats at least twice; all of them
+    where it repeats none.  A window closed inside a flush ends inside one
+    aggregate check, and a mean over whole checks is not skewed by it."""
+    n = len(names)
+    for p in range(1, n // 2 + 1):
+        if all(names[i] == names[i + p] for i in range(n - p)):
+            return n // p * p
+    return n
+
+
 def short_name(name: str) -> str:
     """An op's name as the trace gives it is its whole HLO line; what comes
     before `` = `` names it."""
@@ -101,6 +139,7 @@ def reduce_profile(
     offset = _anchor_offset_ns(data)
     planes = [p for p in data.planes if DEVICE_PLANE.match(p.name)]
     per_plane = []
+    all_modules: List[Tuple[float, float, str]] = []
     op_seconds: Dict[str, float] = {}
     module_seconds: Dict[str, float] = {}
     launches = 0
@@ -118,6 +157,7 @@ def reduce_profile(
         if not busy_src:
             continue
         launches += len(modules)
+        all_modules += modules
         for s, e, name in modules:
             module_seconds[name] = module_seconds.get(name, 0.0) + (e - s) / 1e9
         for s, e, name in ops:
@@ -160,11 +200,19 @@ def reduce_profile(
     top_ops = sorted(op_seconds.items(), key=lambda kv: -kv[1])
     device_ops = [["module:" + n, s] for n, s in top_modules[:3]]
     device_ops += [[n, s] for n, s in top_ops[: TOP - len(device_ops)]]
+    all_modules.sort()
+    whole = whole_periods([name for _, _, name in all_modules])
     return {
         "device_planes": len(per_plane),
         "busy_s": busy_ns / 1e9 / len(per_plane),
         "window_s": (hi - lo) / 1e9,
         "launches": launches,
+        "launches_in_whole_periods": whole,
+        "module_s_per_launch": (
+            sum(e - s for s, e, _ in all_modules[:whole]) / 1e9 / whole
+            if whole else None
+        ),
+        "host_launch_events": host_launches(data, window_wall_ns),
         "anchored": offset is not None and window_wall_ns is not None,
         "device_ops": device_ops,
         "idle_gaps": idle_gaps,
@@ -211,9 +259,12 @@ def reduce_file(
 
 def main() -> int:
     """``python -m chipbench.harness.reduce_trace``: one JSON request on
-    stdin (``path``, ``window_wall_ns``, ``flushes_wall_ns``), the reduction
-    as one JSON line on stdout (``null`` where no device event was found).
-    With ``"describe": true`` the line is :func:`describe`'s list instead."""
+    stdin, one JSON line on stdout.  ``path``, ``window_wall_ns`` and
+    ``flushes_wall_ns`` name the device window's trace, whose reduction comes
+    back as ``device`` (``null`` where no device event was found);
+    ``host_path`` and ``host_window_wall_ns`` the host-only window's, whose
+    launch count comes back as ``host_launches``.  With ``"describe": true``
+    the line is :func:`describe`'s list of ``path`` instead."""
     import json
     import sys
 
@@ -223,13 +274,21 @@ def main() -> int:
     if req.get("describe"):
         print(json.dumps(describe(ProfileData.from_file(req["path"]))))
         return 0
-    window = req.get("window_wall_ns")
-    result = reduce_file(
-        req["path"],
-        tuple(window) if window else None,
-        [tuple(f) for f in req.get("flushes_wall_ns", ())],
-    )
-    print(json.dumps(result))
+    out: Dict[str, Any] = {}
+    if req.get("path"):
+        window = req.get("window_wall_ns")
+        out["device"] = reduce_file(
+            req["path"],
+            tuple(window) if window else None,
+            [tuple(f) for f in req.get("flushes_wall_ns", ())],
+        )
+    if req.get("host_path"):
+        window = req.get("host_window_wall_ns")
+        out["host_launches"] = host_launches(
+            ProfileData.from_file(req["host_path"]),
+            tuple(window) if window else None,
+        )
+    print(json.dumps(out))
     return 0
 
 

@@ -1,10 +1,13 @@
 """Parent-side handle of the one worker process that owns the chip.
 
 The spawn protocol is ``ServiceProcess``'s (a pipe on stdin is the stop
-channel, one ready JSON line on stdout, one summary line at the end), kept
-here as the benchmark's own copy because the entry is the benchmark's
-(worker_entry.py) and so that the yardstick does not move with the
-program's handle.  The parent never imports jax.
+channel, one ready JSON line on stdout, one summary line at the end).  It is
+the benchmark's own copy because ``ServiceProcess`` cannot start this
+worker: its command is fixed to ``-m hbbft_tpu.cryptoplane.proc_service``
+(``python=`` replaces the interpreter, not the entry), its pump drops every
+line but the ready and the summary one, so the entry's control port would
+never arrive, and it can set variables in the worker's environment but not
+take the ``HBBFT_TPU_*`` ones out.  The parent never imports jax.
 """
 
 from __future__ import annotations

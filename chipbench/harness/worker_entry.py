@@ -6,8 +6,9 @@ the worker has no RPC op for either (ROADMAP B-I.1).  This entry runs
 thread and, beside it, one control thread on a localhost port that answers
 three commands, one JSON object per line:
 
-* ``{"op": "trace_start", "dir": ...}``: open a profiler session (device
-  and host events, Python tracer off) and write one anchor event
+* ``{"op": "trace_start", "dir": ..., "host_only": bool}``: open a profiler
+  session (device and host events, or the host's alone; Python tracer off)
+  and write one anchor event
 * ``{"op": "trace_stop"}``: close it and write ``<dir>/.../worker.xplane.pb``
 * ``{"op": "memory"}``: ``peak_bytes_in_use`` of the fullest local device
 
@@ -70,22 +71,20 @@ class Control:
 
     def _trace_start(self, jax: Any, cmd: Dict[str, Any]) -> Dict[str, Any]:
         """Open a profiler session: device events and the runtime's own host
-        events; the Python tracer stays off, because it would record every
-        call of the worker's pure-Python curve arithmetic.  ``options`` may
-        set ``host_tracer_level``, ``python_tracer_level`` and ``advanced``
-        (the profiler's ``advanced_configuration``)."""
+        events, or with ``host_only`` the host's alone (``tpu_trace_mode``
+        ``TRACE_ONLY_HOST``: no device event, and a stop of 0.3 s where a
+        device window's first stop takes minutes, PERF.md).  The Python
+        tracer stays off: it would record every call of the worker's
+        pure-Python curve arithmetic."""
         from jax._src.lib import _profiler
 
         if self._session is not None:
             return {"ok": False, "error": "a trace is already open"}
-        asked = cmd.get("options") or {}
         options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = int(asked.get("python_tracer_level", 0))
-        options.host_tracer_level = int(asked.get("host_tracer_level", 1))
-        if "enable_hlo_proto" in asked:
-            options.enable_hlo_proto = bool(asked["enable_hlo_proto"])
-        if asked.get("advanced"):
-            options.advanced_configuration = dict(asked["advanced"])
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        if cmd.get("host_only"):
+            options.advanced_configuration = {"tpu_trace_mode": "TRACE_ONLY_HOST"}
         jax.devices()  # the backend before the session, or no device is traced
         self._session = _profiler.ProfilerSession(options)
         self._dir = cmd["dir"]

@@ -58,7 +58,7 @@ def _obs(**over):
         "traffic": {"params": {"requests": 16, "wrong": 0}},
         "device_kind": "TPU v5 lite",
         "flushes": 4, "client_s": 1.0, "worker_flush_s": 0.8, "worker_flushes": 4,
-        "trace": {"busy_s": 0.2, "launches": 8}, "trace_cut": False,
+        "trace": {"busy_s": 0.2, "launches": 8}, "trace_cut": False, "host": None,
         "documents_per_flush": 1, "document_bytes": 40, "notes": {},
     }
     obs.update(over)
@@ -81,6 +81,21 @@ def test_readers_return_nothing_where_there_is_nothing_to_read():
     for nothing in (_obs(trace=None), _obs(trace_cut=True)):
         for reader in (flush_host_ms, device_programs_per_flush, device_busy_ms, flush_roofline):
             assert reader.read(nothing) is None
+    # a window cut inside a flush: a whole flush's launches from the
+    # host-only window, its device time from launches times module time
+    cut = _obs(
+        trace={"busy_s": 0.6, "launches": 54, "module_s_per_launch": 0.0125},
+        trace_cut=True,
+        host={"launches": 231, "flushes": 1, "worker_flush_s": 4.3, "worker_flushes": 1},
+    )
+    assert device_programs_per_flush.read(cut) == 231.0
+    assert device_busy_ms.read(cut) == pytest.approx(2887.5)
+    assert flush_host_ms.read(cut) == pytest.approx(4300.0 - 2887.5)
+    assert flush_roofline.read(cut) is None
+    assert cut["notes"] == {
+        "device_programs_from": "host_only_window",
+        "device_busy_from": "launches_x_module_time",
+    }
     # a faulty round's device time is not the least work's: no share
     faulty = _obs(traffic={"params": {"requests": 16, "wrong": 5}})
     assert flush_roofline.read(faulty) is None

@@ -74,3 +74,25 @@ def test_byz5of16_expected_verdicts_against_the_oracle_and_the_reference(coin16)
     assert EagerBackend(BLSSuite()).verify_batch(flush.requests) == flush.expected
     reference = Reference()
     assert [reference.verify(*w) for w in flush.wire] == flush.expected
+
+
+def test_wrong_kinds_are_dealt_in_turn_and_judged_false(coin16):
+    from hbbft_tpu.crypto.backend import EagerBackend
+    from hbbft_tpu.crypto.bls.suite import BLSSuite
+
+    params = _load("traffic", "byz5of16")["probe"]
+    assert params["wrong_kinds"] == ["next_key", "identity"]
+    keys = gen.make_keys(coin16, params, BIG_SEED)
+    flush = gen.make_flush(coin16, params, BIG_SEED, -1, keys)
+    bad = [i for i, ok in enumerate(flush.expected) if not ok]
+    assert len(bad) == 2 and gen.hit_nodes(16, bad) == 4
+    # the second wrong share is the point at infinity, in the program's
+    # encoding of it, and the first a share that decodes to a point
+    assert flush.wire[bad[1]][2] == bytes(193)
+    assert flush.wire[bad[0]][2][0] == 1
+    assert [r.payload[2].to_bytes() for r in flush.requests] == [w[2] for w in flush.wire]
+    assert EagerBackend(BLSSuite()).verify_batch(flush.requests) == flush.expected
+    reference = Reference()
+    assert [reference.verify(*w) for w in flush.wire] == flush.expected
+    with pytest.raises(ValueError):
+        gen.make_flush(coin16, dict(params, wrong_kinds=["off_curve"]), 1, 1, keys)

@@ -50,6 +50,26 @@ def test_busy_launches_and_gaps_with_anchor(profile):
     )
     assert got["device_ops"][0] == ["module:jit_run(1)", pytest.approx(4e-3)]
     assert ["fusion.2", pytest.approx(3.5e-3)] in got["device_ops"]
+    # the host's launch events inside the window are as many as the modules
+    assert got["host_launch_events"] == 3
+    # jit_run(1), jit_run(2), jit_run(1) repeats nothing twice: all three
+    assert got["launches_in_whole_periods"] == 3
+    assert got["module_s_per_launch"] == pytest.approx(2e-3, rel=1e-9)
+
+
+def test_host_launches_with_and_without_a_window(profile):
+    assert rt.host_launches(profile) == 4
+    assert rt.host_launches(profile, (WALL + 900_000, WALL + 22_000_000)) == 3
+    assert rt.host_launches(profile, (WALL + 2_000_000, WALL + 7_000_000)) == 1
+
+
+def test_whole_periods_drops_the_check_that_the_window_cut():
+    check = ["slice", "scan", "slice", "pair"]
+    assert rt.whole_periods(check * 4 + check[:3]) == 16
+    assert rt.whole_periods(check * 2) == 8
+    assert rt.whole_periods(check + check[:1]) == 5  # nothing repeats twice
+    assert rt.whole_periods(["a"] * 7) == 7
+    assert rt.whole_periods([]) == 0
 
 
 def test_without_a_window_the_span_of_device_events(profile):
