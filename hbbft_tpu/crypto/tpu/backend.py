@@ -60,6 +60,7 @@ from hbbft_tpu.crypto.bls.suite import BLSSuite
 from hbbft_tpu.crypto.tpu import curve as dcurve
 from hbbft_tpu.crypto.tpu import pairing as dpairing
 from hbbft_tpu.utils import canonical_bytes
+from hbbft_tpu.utils.metrics import Metrics
 
 NBITS = 128  # RLC coefficient width
 
@@ -94,6 +95,12 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
     chunks' pairs can share ONE batched Miller loop + final
     exponentiation (round-5 fixed-cost amortization; the stage split is
     also what the per-stage timing in BASELINE.md measures).
+
+    The jitted function is named ``hbbft_scan_<n_g1>_<n_g2>_<n_legs>``, so
+    a device trace's module is ``jit_hbbft_scan_...`` whatever a refactor
+    renumbers, and its stages sit under the ``jax.named_scope``s
+    ``scan_g1``, ``scan_g2``, ``subgroup`` and ``leg_sums`` (metadata of
+    the ops: the compiled code is the same with and without them).
     """
 
     def run(
@@ -110,18 +117,27 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         # verified in this same kernel (fail-closed; see dcurve notes).
         # Equivalence + soundness pinned in tests/test_bls.py and
         # tests/test_tpu_crypto.py.
-        scaled1, chain1 = dcurve.scalar_mul_rlc_g1(g1_pts, g1_bits)
-        scaled2, chain2 = dcurve.scalar_mul_rlc_g2(g2_pts, g2_bits_s, g2_bits_q)
-        sub1 = dcurve.endo_subgroup_eq(dcurve.G1_OPS, g1_pts, chain1)
-        sub2 = dcurve.endo_subgroup_eq(dcurve.G2_OPS, g2_pts, chain2)
-        sub_ok = jnp.all(sub1 | (g1_chk == 0)) & jnp.all(sub2 | (g2_chk == 0))
-        gen_leg = dcurve.tree_sum(dcurve.G2_OPS, scaled2)
-        leg_sums = []
-        for l in range(n_legs):
-            masked = dcurve.select(
-                seg[l], scaled1, dcurve.identity(dcurve.G1_OPS, (n_g1,)), dcurve.G1_OPS
+        with jax.named_scope("scan_g1"):
+            scaled1, chain1 = dcurve.scalar_mul_rlc_g1(g1_pts, g1_bits)
+        with jax.named_scope("scan_g2"):
+            scaled2, chain2 = dcurve.scalar_mul_rlc_g2(
+                g2_pts, g2_bits_s, g2_bits_q
             )
-            leg_sums.append(dcurve.tree_sum(dcurve.G1_OPS, masked))
+        with jax.named_scope("subgroup"):
+            sub1 = dcurve.endo_subgroup_eq(dcurve.G1_OPS, g1_pts, chain1)
+            sub2 = dcurve.endo_subgroup_eq(dcurve.G2_OPS, g2_pts, chain2)
+            sub_ok = (
+                jnp.all(sub1 | (g1_chk == 0)) & jnp.all(sub2 | (g2_chk == 0))
+            )
+        with jax.named_scope("leg_sums"):
+            gen_leg = dcurve.tree_sum(dcurve.G2_OPS, scaled2)
+            leg_sums = []
+            for l in range(n_legs):
+                masked = dcurve.select(
+                    seg[l], scaled1,
+                    dcurve.identity(dcurve.G1_OPS, (n_g1,)), dcurve.G1_OPS,
+                )
+                leg_sums.append(dcurve.tree_sum(dcurve.G1_OPS, masked))
         # Pair list: (gen, gen_leg) + (leg_sum_l, rhs_l).
         lhs = tuple(
             jnp.stack([gen_pt[c]] + [p[c] for p in leg_sums]) for c in range(4)
@@ -131,17 +147,22 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         )
         return sub_ok, lhs, rhs
 
+    run.__name__ = run.__qualname__ = f"hbbft_scan_{n_g1}_{n_g2}_{n_legs}"
     return jax.jit(run)
 
 
 @lru_cache(maxsize=32)
 def _pair_kernel(n_pairs: int):
     """Compiled PAIR stage: batched Miller loop over ``n_pairs`` pairing
-    pairs + ONE shared final exponentiation -> product == 1."""
+    pairs + ONE shared final exponentiation -> product == 1.  The jitted
+    function is named ``hbbft_pair_<n_pairs>`` (a trace's module
+    ``jit_hbbft_pair_...``); its two stages are the ``jax.named_scope``s
+    ``miller_loop`` and ``final_exp`` of ``pairing_product_is_one``."""
 
     def run(lhs, rhs):
         return dpairing.pairing_product_is_one(lhs, rhs)
 
+    run.__name__ = run.__qualname__ = f"hbbft_pair_{n_pairs}"
     return jax.jit(run)
 
 
@@ -217,14 +238,37 @@ class TpuBackend(CryptoBackend):
 
     ``shard=True`` (or env ``HBBFT_TPU_SHARD=1``) lays the batch axis
     over all visible devices data-parallel; default is single-device.
+
+    ``metrics`` takes the backend's spans (:meth:`Metrics.span`: a timer
+    each and, under an open profiler session, an event on its clock) and
+    counters, each counter at its span's boundary so that the ``stats`` op
+    reads what a trace reads.  One aggregate check is one device verdict:
+    ``crypto.tpu.check`` (args ``rows``, ``depth``: 0 the flush's own,
+    +1 per bisection level) around ``crypto.tpu.scan_prep`` (``rows``,
+    ``n1``, ``n2``, ``legs``; inside it ``crypto.tpu.coefficients``, one
+    ``crypto.tpu.hash_to_g2`` (``bytes``) per call, ``crypto.tpu.pack``),
+    ``crypto.tpu.scan_dispatch``, ``crypto.tpu.pair_dispatch`` (``pairs``)
+    and ``crypto.tpu.verdict_sync`` (the host blocked on the device);
+    beside the checks ``crypto.tpu.well_formed`` (``requests``) and one
+    ``crypto.tpu.leaf`` per request that bisection hands to the oracle.
+    Counters: ``crypto.tpu.checks``, ``crypto.tpu.checks_failed``,
+    ``crypto.tpu.rows`` (requests summed over checks),
+    ``crypto.tpu.rows_padded`` (bucket rows less real rows, G1 and G2
+    summed), ``crypto.tpu.hash_to_g2_calls``, ``crypto.tpu.leaves``.
+    A flush of several chunks dispatches every chunk's scan before any
+    verdict, so there ``scan_prep`` and ``scan_dispatch`` lie beside the
+    checks, not inside them: a check is then the combined pair stage and
+    its sync, and after a failure one more per chunk.
     """
 
     def __init__(
-        self, suite: BLSSuite | None = None, shard: bool | None = None
+        self,
+        suite: BLSSuite | None = None,
+        shard: bool | None = None,
+        metrics: Metrics | None = None,
     ) -> None:
-        import os
-
         self.suite = suite or BLSSuite()
+        self.metrics = metrics if metrics is not None else Metrics()
         self._eager = EagerBackend(self.suite)
         if shard is None:
             shard = os.environ.get("HBBFT_TPU_SHARD") == "1"
@@ -253,17 +297,22 @@ class TpuBackend(CryptoBackend):
                 rhs.append(point_jac)
             return leg_of[key]
 
+        def hash_to_g2(data: bytes) -> Any:
+            self.metrics.count("crypto.tpu.hash_to_g2_calls")
+            with self.metrics.span("crypto.tpu.hash_to_g2", bytes=len(data)):
+                return self.suite.hash_to_g2(data).jac
+
         for r, c in zip(reqs, coeffs):
             if r.kind == SIG_SHARE:
                 pk, msg, share = r.payload
                 g2_entries.append((c, share.g2.jac, 1))
-                l = leg(canonical_bytes(b"m", msg), self.suite.hash_to_g2(msg).jac)
+                l = leg(canonical_bytes(b"m", msg), hash_to_g2(msg))
                 g1_entries.append((c, (-pk.g1).jac, l, 0))
             elif r.kind == DEC_SHARE:
                 pk, ct, share = r.payload
                 l = leg(
                     canonical_bytes(b"c", ct.hash_input()),
-                    self.suite.hash_to_g2(ct.hash_input()).jac,
+                    hash_to_g2(ct.hash_input()),
                 )
                 g1_entries.append((c, share.g1.jac, l, 1))
                 lw = leg(canonical_bytes(b"w", ct.w.to_bytes()), ct.w.jac)
@@ -273,14 +322,31 @@ class TpuBackend(CryptoBackend):
                 g2_entries.append((c, ct.w.jac, 1))
                 l = leg(
                     canonical_bytes(b"c", ct.hash_input()),
-                    self.suite.hash_to_g2(ct.hash_input()).jac,
+                    hash_to_g2(ct.hash_input()),
                 )
                 # -U is in the subgroup iff U is.
                 g1_entries.append((c, (-ct.u).jac, l, 1))
         return g2_entries, g1_entries, rhs
 
-    def _aggregate_ok(self, reqs: Sequence[VerifyRequest]) -> bool:
-        return bool(self._check_parts([self._scan_dev(reqs, alone=True)]))
+    def _aggregate_ok(self, reqs: Sequence[VerifyRequest], depth: int = 0) -> bool:
+        """One aggregate check of a whole flush or of a bisection's half:
+        scan, pair stage, verdict."""
+        with self.metrics.span("crypto.tpu.check", rows=len(reqs), depth=depth):
+            return self._verdict(
+                [self._scan_dev(reqs, alone=True)], len(reqs)
+            )
+
+    def _verdict(self, parts, rows: int) -> bool:
+        """The pair stage over ``parts`` and the host's wait for its
+        verdict; counts the check that this ends."""
+        ok_dev = self._check_parts(parts)
+        with self.metrics.span("crypto.tpu.verdict_sync"):
+            ok = bool(ok_dev)
+        self.metrics.count("crypto.tpu.checks")
+        self.metrics.count("crypto.tpu.rows", rows)
+        if not ok:
+            self.metrics.count("crypto.tpu.checks_failed")
+        return ok
 
     def _scan_dev(self, reqs: Sequence[VerifyRequest], alone: bool = False):
         """Dispatch one chunk's SCAN kernel; returns (sub_ok, lhs, rhs)
@@ -291,26 +357,40 @@ class TpuBackend(CryptoBackend):
         (n1, n2, nl), args = self._scan_prep(reqs)
         if alone and self._mesh is None:
             _compile_pair_kernel_early(_pairs_bucket(1 + nl))
-        return _scan_kernel(n1, n2, nl)(*args)
+        with self.metrics.span("crypto.tpu.scan_dispatch"):
+            return _scan_kernel(n1, n2, nl)(*args)
 
     def _scan_prep(self, reqs: Sequence[VerifyRequest]):
         """Host prep for one chunk: returns ((n1, n2, nl), kernel args).
         Split from :meth:`_scan_dev` so measurement tooling
         (benchmarks/flush_roofline.py) can lower the cached kernel on
         the exact production inputs."""
-        coeffs = _batch_coefficients(self.suite, reqs)
-        g2e, g1e, rhs = self._build_legs(reqs, coeffs)
-        n1 = _bucket(max(len(g1e), 1))
-        n2 = _bucket(max(len(g2e), 1))
-        # Legs become pairing-product pairs (a Miller loop each, even
-        # when identity-padded), so keep their floor LOW: on the 1-core
-        # virtual-CPU test platform every padded leg costs real execution
-        # minutes across the suite (a floor-8 experiment tripled warm
-        # suite time).  The cost side — one ~7-min cold compile per
-        # distinct legs bucket (2/4/8 under bisection) — is paid once and
-        # covered by benchmarks/warm_crypto_cache.py + the persistent
-        # .jax_cache.
-        nl = _bucket(max(len(rhs), 1), floor=2)
+        with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
+            with self.metrics.span("crypto.tpu.coefficients"):
+                coeffs = _batch_coefficients(self.suite, reqs)
+            g2e, g1e, rhs = self._build_legs(reqs, coeffs)
+            n1 = _bucket(max(len(g1e), 1))
+            n2 = _bucket(max(len(g2e), 1))
+            # Legs become pairing-product pairs (a Miller loop each, even
+            # when identity-padded), so keep their floor LOW: on the 1-core
+            # virtual-CPU test platform every padded leg costs real
+            # execution minutes across the suite (a floor-8 experiment
+            # tripled warm suite time).  The cost side — one ~7-min cold
+            # compile per distinct legs bucket (2/4/8 under bisection) — is
+            # paid once and covered by benchmarks/warm_crypto_cache.py +
+            # the persistent .jax_cache.
+            nl = _bucket(max(len(rhs), 1), floor=2)
+            note(n1=n1, n2=n2, legs=nl)
+            self.metrics.count(
+                "crypto.tpu.rows_padded", n1 - len(g1e) + n2 - len(g2e)
+            )
+            with self.metrics.span("crypto.tpu.pack"):
+                args = self._pack(g1e, g2e, rhs, n1, n2, nl)
+        return (n1, n2, nl), args
+
+    def _pack(self, g1e, g2e, rhs, n1: int, n2: int, nl: int):
+        """The legs as the SCAN kernel's arguments: limbs, bit planes and
+        masks, padded to the buckets and put on the device."""
         ident1 = (1, 1, 0)
         ident2 = ((1, 0), (1, 0), (0, 0))
         g1_pts = dcurve.g1_to_dev(
@@ -364,7 +444,7 @@ class TpuBackend(CryptoBackend):
             seg = put(seg, seg_sh)
             rhs_pts = tuple(put(c, repl) for c in rhs_pts)
             gen_pt = tuple(put(c, repl) for c in gen_pt)
-        return (n1, n2, nl), (
+        return (
             g1_pts, g1_bits, g1_chk, seg,
             g2_pts, g2_bits_s, g2_bits_q, g2_chk, rhs_pts, gen_pt,
         )
@@ -384,34 +464,34 @@ class TpuBackend(CryptoBackend):
         cancel).  On any False the caller re-checks per chunk, so
         verdicts are identical to the per-chunk path.
         """
-        sub_oks = [p[0] for p in parts]
-        if len(parts) == 1:
-            lhs, rhs = parts[0][1], parts[0][2]
-        else:
-            lhs = tuple(
-                jnp.concatenate([p[1][c] for p in parts]) for c in range(4)
-            )
-            rhs = tuple(
-                jnp.concatenate([p[2][c] for p in parts]) for c in range(4)
-            )
-        n = int(lhs[3].shape[0])
+        n = sum(int(p[1][3].shape[0]) for p in parts)
         b = _pairs_bucket(n)
-        if b > n:
-            pad1 = dcurve.identity(dcurve.G1_OPS, (b - n,))
-            pad2 = dcurve.identity(dcurve.G2_OPS, (b - n,))
-            lhs = tuple(
-                jnp.concatenate([lhs[c], pad1[c]]) for c in range(4)
-            )
-            rhs = tuple(
-                jnp.concatenate([rhs[c], pad2[c]]) for c in range(4)
-            )
-        early = _EARLY_PAIR_COMPILES.get(b)
-        if early is not None:
-            early.join()
-        ok = _pair_kernel(b)(lhs, rhs)
-        for s in sub_oks:
-            ok = ok & s
-        return ok
+        with self.metrics.span("crypto.tpu.pair_dispatch", pairs=b):
+            if len(parts) == 1:
+                lhs, rhs = parts[0][1], parts[0][2]
+            else:
+                lhs = tuple(
+                    jnp.concatenate([p[1][c] for p in parts]) for c in range(4)
+                )
+                rhs = tuple(
+                    jnp.concatenate([p[2][c] for p in parts]) for c in range(4)
+                )
+            if b > n:
+                pad1 = dcurve.identity(dcurve.G1_OPS, (b - n,))
+                pad2 = dcurve.identity(dcurve.G2_OPS, (b - n,))
+                lhs = tuple(
+                    jnp.concatenate([lhs[c], pad1[c]]) for c in range(4)
+                )
+                rhs = tuple(
+                    jnp.concatenate([rhs[c], pad2[c]]) for c in range(4)
+                )
+            early = _EARLY_PAIR_COMPILES.get(b)
+            if early is not None:
+                early.join()
+            ok = _pair_kernel(b)(lhs, rhs)
+            for p in parts:
+                ok = ok & p[0]
+            return ok
 
     # -- public API ----------------------------------------------------
 
@@ -439,51 +519,68 @@ class TpuBackend(CryptoBackend):
         out = [False] * len(reqs)
         # Host: structure + on-curve only; the r-torsion checks run
         # batched inside the flush kernel (subgroup=False here).
-        idxs = [
-            i
-            for i, r in enumerate(reqs)
-            if request_well_formed(self.suite, r, subgroup=False)
-        ]
+        with self.metrics.span("crypto.tpu.well_formed", requests=len(reqs)):
+            idxs = [
+                i
+                for i, r in enumerate(reqs)
+                if request_well_formed(self.suite, r, subgroup=False)
+            ]
         chunks = [idxs[s : s + self.CHUNK] for s in range(0, len(idxs), self.CHUNK)]
+        if not chunks:
+            return out
+        if len(chunks) == 1:
+            if self._aggregate_ok([reqs[i] for i in idxs]):
+                for i in idxs:
+                    out[i] = True
+            else:
+                self._bisect(reqs, idxs, out, depth=1)
+            return out
         # Dispatch every chunk's SCAN kernel before syncing on anything:
         # jax dispatch is async, so the device pipelines the chunks and
         # the host pays one round-trip total instead of one per chunk.
-        scans = [
-            self._scan_dev([reqs[i] for i in c], alone=len(chunks) == 1)
-            for c in chunks
-        ]
-        if len(chunks) > 1:
-            # Fast path: ALL chunks' pairs through one batched Miller
-            # loop + one final exponentiation (fixed pairing cost paid
-            # once per flush, not once per chunk — _check_parts notes).
-            if bool(self._check_parts(scans)):
-                for c in chunks:
-                    for i in c:
-                        out[i] = True
-                return out
+        scans = [self._scan_dev([reqs[i] for i in c]) for c in chunks]
+
+        def check(parts, rows: int) -> bool:
+            with self.metrics.span("crypto.tpu.check", rows=rows, depth=0):
+                return self._verdict(parts, rows)
+
+        # Fast path: ALL chunks' pairs through one batched Miller loop +
+        # one final exponentiation (fixed pairing cost paid once per
+        # flush, not once per chunk — _check_parts notes).
+        if check(scans, len(idxs)):
+            for i in idxs:
+                out[i] = True
+            return out
         for c, part in zip(chunks, scans):
-            if bool(self._check_parts([part])):
+            if check([part], len(c)):
                 for i in c:
                     out[i] = True
             else:
-                self._bisect(reqs, c, out)
+                self._bisect(reqs, c, out, depth=1)
         return out
 
     def _bisect(
-        self, all_reqs: List[VerifyRequest], idxs: List[int], out: List[bool]
+        self,
+        all_reqs: List[VerifyRequest],
+        idxs: List[int],
+        out: List[bool],
+        depth: int,
     ) -> None:
         """Bisection fallback — the caller knows idxs' aggregate FAILED,
-        so split immediately and aggregate only the halves."""
+        so split immediately and aggregate only the halves (``depth``:
+        how many splits lie above them)."""
         if len(idxs) == 1:
-            out[idxs[0]] = self._eager.verify_batch([all_reqs[idxs[0]]])[0]
+            self.metrics.count("crypto.tpu.leaves")
+            with self.metrics.span("crypto.tpu.leaf"):
+                out[idxs[0]] = self._eager.verify_batch([all_reqs[idxs[0]]])[0]
             return
         mid = len(idxs) // 2
         for half in (idxs[:mid], idxs[mid:]):
-            if self._aggregate_ok([all_reqs[i] for i in half]):
+            if self._aggregate_ok([all_reqs[i] for i in half], depth):
                 for i in half:
                     out[i] = True
             else:
-                self._bisect(all_reqs, half, out)
+                self._bisect(all_reqs, half, out, depth + 1)
 
 
 class HybridBackend(CryptoBackend):

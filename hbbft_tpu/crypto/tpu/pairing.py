@@ -324,5 +324,10 @@ def miller_product(g1s: dcurve.Point, g2s: dcurve.Point) -> jnp.ndarray:
 
 
 def pairing_product_is_one(g1s: dcurve.Point, g2s: dcurve.Point) -> jnp.ndarray:
-    """prod_i e(P_i, Q_i) == 1 over a batch axis; one final exponentiation."""
-    return final_exp_is_one(miller_product(g1s, g2s))
+    """prod_i e(P_i, Q_i) == 1 over a batch axis; one final exponentiation.
+    The two stages carry ``jax.named_scope``s so that a device trace's ops
+    say which they belong to."""
+    with jax.named_scope("miller_loop"):
+        f = miller_product(g1s, g2s)
+    with jax.named_scope("final_exp"):
+        return final_exp_is_one(f)

@@ -149,7 +149,12 @@ def suite_arg_for(suite: Any) -> str:
     return "bls" if suite.name == "bls12-381" else "scalar"
 
 
-def _build_backend(name: str, suite: Any) -> CryptoBackend:
+def _build_backend(
+    name: str, suite: Any, metrics: Optional[Metrics] = None
+) -> CryptoBackend:
+    """``metrics`` is the worker's one :class:`Metrics`: a backend that has
+    spans and counters of its own (``tpu``) writes them there, so they
+    come back through the ``stats`` op."""
     if name == "batched":
         from hbbft_tpu.crypto.backend import BatchedBackend
 
@@ -173,7 +178,7 @@ def _build_backend(name: str, suite: Any) -> CryptoBackend:
             )
         from hbbft_tpu.crypto.tpu import TpuBackend
 
-        return TpuBackend(suite)
+        return TpuBackend(suite, metrics=metrics)
     raise ValueError(f"unknown backend {name!r} (batched | eager | tpu)")
 
 
@@ -356,7 +361,7 @@ class CryptoRpcServer:
                 kind, payload = _recv_frame(sock, dec, None)
                 if kind != KIND_CRYPTO_REQ:
                     raise FrameError("expected crypto REQ")
-                sock.sendall(self._handle_req(payload))
+                self._serve_req(cid, sock, payload)
         except (FrameError, serde.DecodeError):
             self.metrics.count("crypto.rpc.bad_frames")
         except OSError:
@@ -369,24 +374,43 @@ class CryptoRpcServer:
             with self._lock:
                 self._conns.pop(cid, None)
 
-    def _handle_req(self, payload: bytes) -> bytes:
-        obj = serde.loads(payload, suite=self.suite)  # DecodeError -> drop
-        if not isinstance(obj, tuple) or len(obj) != 3:
-            raise FrameError("malformed crypto REQ")
-        req_id, op, body = obj
-        if type(req_id) is not int or type(op) is not str:
-            raise FrameError("malformed crypto REQ header")
-        if op == "stats":
-            return self._resp(req_id, op, (self._stats_json(),))
-        if op != "verify":
-            raise FrameError(f"unknown crypto RPC op {op!r}")
-        if not isinstance(body, tuple) or not all(
-            item is None or isinstance(item, VerifyRequest) for item in body
-        ):
-            raise FrameError("malformed crypto verify body")
-        return self._resp(req_id, op, self._verify(body))
+    def _serve_req(self, cid: int, sock: socket.socket, payload: bytes) -> None:
+        """Decode one REQ, answer it.  The spans of one RPC
+        (``crypto.rpc.serve`` around ``decode``, ``wait``, ``reply``)
+        share ``span="<conn>:<req_id>"``, which the ``crypto.flush`` that
+        carried the job lists.  The id and the op are read from the
+        payload, so the two spans that are open by then take them as
+        notes, and ``stats`` requests are spanned like ``verify`` ones
+        (``op`` tells them apart)."""
+        span = self.metrics.span
+        with span("crypto.rpc.serve", bytes=len(payload)) as note_serve:
+            with span("crypto.rpc.decode", bytes=len(payload)) as note:
+                # DecodeError -> drop the connection
+                obj = serde.loads(payload, suite=self.suite)
+                if not isinstance(obj, tuple) or len(obj) != 3:
+                    raise FrameError("malformed crypto REQ")
+                req_id, op, body = obj
+                if type(req_id) is not int or type(op) is not str:
+                    raise FrameError("malformed crypto REQ header")
+                rpc = f"{cid}:{req_id}"
+                note(span=rpc)
+            if op == "stats":
+                rest: tuple = (self._stats_json(),)
+                note_serve(span=rpc, op=op)
+            elif op == "verify":
+                if not isinstance(body, tuple) or not all(
+                    item is None or isinstance(item, VerifyRequest)
+                    for item in body
+                ):
+                    raise FrameError("malformed crypto verify body")
+                note_serve(span=rpc, op=op, requests=len(body))
+                rest = self._verify(body, rpc)
+            else:
+                raise FrameError(f"unknown crypto RPC op {op!r}")
+            with span("crypto.rpc.reply", span=rpc):
+                sock.sendall(self._resp(req_id, op, rest))
 
-    def _verify(self, items: Tuple[Any, ...]) -> tuple:
+    def _verify(self, items: Tuple[Any, ...], rpc: str) -> tuple:
         # None placeholders (client-side unserializable junk) verify
         # False without touching the backend — the verdict every local
         # backend's request_well_formed gate would produce for them.
@@ -395,12 +419,13 @@ class CryptoRpcServer:
         ok = True
         flush_requests = flush_jobs = 0
         if real:
-            job = self.service.submit(real)
-            ok = (
-                job is not None
-                and job.done.wait(self.job_wait_s)
-                and job.results is not None
-            )
+            with self.metrics.span("crypto.rpc.wait", span=rpc):
+                job = self.service.submit(real, span=rpc)
+                ok = (
+                    job is not None
+                    and job.done.wait(self.job_wait_s)
+                    and job.results is not None
+                )
             if job is not None and not ok:
                 job.cancelled = True  # timed out: drop if still queued
             if ok:
@@ -888,9 +913,13 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     suite = _build_suite(args.suite)
-    backend = _build_backend(args.backend, suite)
+    # one Metrics per worker process: the backend's spans and counters
+    # and the service's come back through the one ``stats`` op
+    metrics = Metrics()
+    backend = _build_backend(args.backend, suite, metrics)
     service = CryptoPlaneService(
-        backend, window_s=args.window_s, max_batch=args.max_batch
+        backend, window_s=args.window_s, max_batch=args.max_batch,
+        metrics=metrics,
     )
     on_jax = args.backend == "tpu"
     server = CryptoRpcServer(

@@ -53,11 +53,15 @@ from hbbft_tpu.utils.metrics import Metrics
 class _Job:
     """One client's submitted batch: requests in, verdicts out."""
 
-    __slots__ = ("reqs", "results", "done", "cancelled",
+    __slots__ = ("reqs", "span", "results", "done", "cancelled",
                  "flush_requests", "flush_jobs")
 
-    def __init__(self, reqs: List[VerifyRequest]) -> None:
+    def __init__(self, reqs: List[VerifyRequest], span: str = "") -> None:
         self.reqs = reqs
+        # The submitter's id for this batch (an RPC's "<conn>:<req_id>"):
+        # the crypto.flush span lists the ids of the jobs it merged, so a
+        # trace ties each RPC's spans to the flush that answered it.
+        self.span = span
         self.results: Optional[List[bool]] = None  # None = failed/killed
         self.done = threading.Event()
         # Stamped by _flush: the size of the MERGED batch this job rode
@@ -89,10 +93,17 @@ class CryptoPlaneService:
 
     Metrics (exported via :meth:`export_metrics` into
     ``LocalCluster.merged_metrics``): ``crypto.flushes`` /
-    ``crypto.requests`` counters, ``crypto.flush`` timer (latency),
+    ``crypto.requests`` counters, ``crypto.flush`` span (the backend's
+    ``verify_batch``; args ``flush`` = its sequence number, ``requests``,
+    ``jobs``, ``spans`` = the merged jobs' ids, space-joined) and
+    ``crypto.window`` span (first pending job seen to the flush's start),
+    both :meth:`Metrics.span`: a timer each and, under an open profiler
+    session, an event each on that session's clock;
     ``crypto.batch_size`` summary (log-bucket histogram),
     ``crypto.queue_depth`` gauge, ``crypto.fallbacks`` (client-side,
     counted here so the cluster sees one total), ``crypto.flush_errors``.
+    Hand the backend the same ``metrics`` and its own spans and counters
+    (``crypto.tpu.*``) are exported with these.
     """
 
     def __init__(
@@ -115,6 +126,7 @@ class CryptoPlaneService:
         self._thread: Optional[threading.Thread] = None
         self._stop = False
         self._killed = False
+        self._flush_seq = 0  # worker thread only
         # batch-size distribution (requests per backend flush): the
         # log-bucket estimator bounds memory like the traffic plane's
         # latency clocks; re-published as the crypto.batch_size summary.
@@ -174,12 +186,15 @@ class CryptoPlaneService:
             j.done.set()  # results stay None -> client falls back
 
     # -- submission (any thread) ---------------------------------------
-    def submit(self, reqs: Sequence[VerifyRequest]) -> Optional[_Job]:
+    def submit(
+        self, reqs: Sequence[VerifyRequest], span: str = ""
+    ) -> Optional[_Job]:
         """Enqueue one batch; returns the job to wait on, or None when
         the service is dead (caller falls back immediately).  Lazily
         starts the worker so a cluster built before ``start()`` still
-        gets service semantics."""
-        job = _Job(list(reqs))
+        gets service semantics.  ``span`` is the caller's id for the
+        batch; the flush that carries it lists it."""
+        job = _Job(list(reqs), span)
         with self._cv:
             if self._killed or self._stop:
                 return None
@@ -201,23 +216,24 @@ class CryptoPlaneService:
                     self._cv.wait(timeout=0.2)
                 if self._stop:
                     return
-                # Hold the window open from the FIRST pending arrival:
-                # more nodes' flushes pile into the same device batch.
-                deadline = time.monotonic() + self.window_s
-                while (
-                    not self._stop
-                    and self._pending_reqs < self.max_batch
-                    and (remain := deadline - time.monotonic()) > 0
-                ):
-                    self._cv.wait(timeout=remain)
-                if self._stop:
-                    return
-                # Timed-out clients already re-verified locally; drop
-                # their abandoned jobs rather than flushing for nobody.
-                jobs = [j for j in self._jobs if not j.cancelled]
-                self._jobs = []
-                self._pending_reqs = 0
-                self.metrics.gauge("crypto.queue_depth", 0)
+                with self.metrics.span("crypto.window"):
+                    # Hold the window open from the FIRST pending arrival:
+                    # more nodes' flushes pile into the same device batch.
+                    deadline = time.monotonic() + self.window_s
+                    while (
+                        not self._stop
+                        and self._pending_reqs < self.max_batch
+                        and (remain := deadline - time.monotonic()) > 0
+                    ):
+                        self._cv.wait(timeout=remain)
+                    if self._stop:
+                        return
+                    # Timed-out clients already re-verified locally; drop
+                    # their abandoned jobs rather than flushing for nobody.
+                    jobs = [j for j in self._jobs if not j.cancelled]
+                    self._jobs = []
+                    self._pending_reqs = 0
+                    self.metrics.gauge("crypto.queue_depth", 0)
             if jobs:
                 self._flush(jobs)
 
@@ -230,8 +246,12 @@ class CryptoPlaneService:
                 requests=len(reqs), jobs=len(jobs), backend=backend,
             )
         ok = False
+        self._flush_seq += 1
         try:
-            with self.metrics.timer("crypto.flush"):
+            with self.metrics.span(
+                "crypto.flush", flush=self._flush_seq, requests=len(reqs),
+                jobs=len(jobs), spans=" ".join(j.span for j in jobs if j.span),
+            ):
                 results = self.backend.verify_batch(reqs)
             if len(results) != len(reqs):  # a broken backend is a crash
                 raise RuntimeError(

@@ -1,4 +1,4 @@
-"""Structured metrics: counters, timers, and JAX profiler traces.
+"""Structured metrics: counters, timers, and spans on the profiler's clock.
 
 Reference behavior: the reference has no metrics beyond ``log`` lines and
 the per-message CPU-time accounting of its simulation example (SURVEY.md
@@ -17,18 +17,22 @@ Usage::
     m.count("verify_requests", 12)
     print(m.report())
 
-``Metrics.trace(path)`` wraps ``jax.profiler.trace`` so a verify flush
-can be captured for TensorBoard without importing jax at module scope.
+``Metrics.span(name, **args)`` is ``timer(name)`` and, in a process that
+has already imported jax, a ``jax.profiler.TraceAnnotation`` of the same
+name around the same interval: whatever profiler session is open in the
+process gets the span on its own clock, beside the runtime's and the
+device's events.  This module never imports jax.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import defaultdict
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 
 @dataclass
@@ -63,6 +67,10 @@ class SummaryStats:
     count: int = 0
     total: float = 0.0
     quantiles: Dict[float, float] = field(default_factory=dict)
+
+
+def _no_note(**args: Any) -> None:
+    """What :meth:`Metrics.span` yields where no annotation is written."""
 
 
 @dataclass
@@ -122,15 +130,23 @@ class Metrics:
                 self.timers.setdefault(name, TimerStats()).add(dt)
 
     @contextmanager
-    def trace(self, logdir: str) -> Iterator[None]:
-        """JAX profiler capture (TensorBoard format); no-op without jax."""
-        try:
-            import jax
-        except ImportError:  # pragma: no cover
-            yield
+    def span(self, name: str, **args: Any) -> Iterator[Callable[..., None]]:
+        """:meth:`timer` plus, where jax is already imported, a
+        ``jax.profiler.TraceAnnotation(name, **args)`` around the same
+        interval.  An open profiler session records it (``args`` as the
+        event's stats); with none open it costs under a microsecond, so
+        there is no switch.  Yields ``note(**more)``: args that are known
+        only inside the span (a request id read from the payload being
+        decoded).  An arg's value must hold no comma: the annotation's
+        encoding splits there."""
+        jax = sys.modules.get("jax")
+        if jax is None:
+            with self.timer(name):
+                yield _no_note
             return
-        with jax.profiler.trace(logdir):
-            yield
+        with jax.profiler.TraceAnnotation(name, **args) as annotation:
+            with self.timer(name):
+                yield annotation.set_metadata
 
     def merge(self, other: "Metrics") -> None:
         # list() snapshots: ``other`` may belong to a live transport or

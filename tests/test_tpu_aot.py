@@ -153,14 +153,14 @@ def test_flush_programs_compile_for_v5e(n_shares, one_chip, cache_off):
     (n1, n2, nl), args = B.TpuBackend(suite)._scan_prep(reqs)
     assert (n1, n2, nl) == (n_shares, n_shares, 2)
     programs = [
-        (f"_scan_kernel({n1},{n2},{nl})", B._scan_kernel(n1, n2, nl), args)
+        (f"hbbft_scan_{n1}_{n2}_{nl}", B._scan_kernel(n1, n2, nl), args)
     ]
     if n_shares == 16:
         _, lhs, rhs = jax.eval_shape(B._scan_kernel(n1, n2, nl), *args)
         n_pairs = int(lhs[3].shape[0])
         assert n_pairs == 3
         programs.append(
-            (f"_pair_kernel({n_pairs})", B._pair_kernel(n_pairs), (lhs, rhs))
+            (f"hbbft_pair_{n_pairs}", B._pair_kernel(n_pairs), (lhs, rhs))
         )
     for name, kernel, shapes in programs:
         t0 = time.perf_counter()
@@ -168,6 +168,10 @@ def test_flush_programs_compile_for_v5e(n_shares, one_chip, cache_off):
         t1 = time.perf_counter()
         compiled = lowered.compile()
         t2 = time.perf_counter()
+        # the name a device trace's module carries (chipbench reads it)
+        assert lowered.as_text().split("\n", 1)[0].startswith(
+            f"module @jit_{name} "
+        )
         mem = compiled.memory_analysis()
         print(
             f"\nAOT v5e {name} x64={jax.config.jax_enable_x64}: "
