@@ -244,17 +244,27 @@ class TpuBackend(CryptoBackend):
     counters, each counter at its span's boundary so that the ``stats`` op
     reads what a trace reads.  One aggregate check is one device verdict:
     ``crypto.tpu.check`` (args ``rows``, ``depth``: 0 the flush's own,
-    +1 per bisection level) around ``crypto.tpu.scan_prep`` (``rows``,
-    ``n1``, ``n2``, ``legs``; inside it ``crypto.tpu.coefficients``, one
-    ``crypto.tpu.hash_to_g2`` (``bytes``) per call, ``crypto.tpu.pack``),
-    ``crypto.tpu.scan_dispatch``, ``crypto.tpu.pair_dispatch`` (``pairs``)
-    and ``crypto.tpu.verdict_sync`` (the host blocked on the device);
-    beside the checks ``crypto.tpu.well_formed`` (``requests``) and one
-    ``crypto.tpu.leaf`` per request that bisection hands to the oracle.
+    +1 per bisection level) around its ``crypto.tpu.scan_dispatch``,
+    ``crypto.tpu.pair_dispatch`` (``pairs``) and
+    ``crypto.tpu.verdict_sync`` (the host blocked on the device).  The
+    ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``; inside it
+    ``crypto.tpu.coefficients``, one ``crypto.tpu.hash_to_g2`` (``bytes``)
+    per call, ``crypto.tpu.pack``) before the dispatches is the check's
+    own: the flush's, or that of a bisection level's first group.  One
+    between ``pair_dispatch`` and ``verdict_sync`` is the NEXT group's of
+    the level, prepared while the device runs this check (its ``rows``
+    says whose; a level's last check holds none).  Beside the checks
+    ``crypto.tpu.well_formed`` (``requests``) and one ``crypto.tpu.leaf``
+    per request convicted by its own one-row check: a group of one whose
+    check fails is answered False on the device's word, as one that
+    passes is answered True (the coefficient is a unit mod r, so the
+    one-row check is the verification equation itself; no oracle call).
     Counters: ``crypto.tpu.checks``, ``crypto.tpu.checks_failed``,
     ``crypto.tpu.rows`` (requests summed over checks),
     ``crypto.tpu.rows_padded`` (bucket rows less real rows, G1 and G2
-    summed), ``crypto.tpu.hash_to_g2_calls``, ``crypto.tpu.leaves``.
+    summed), ``crypto.tpu.hash_to_g2_calls``, ``crypto.tpu.leaves``,
+    ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
+    check's ``pair_dispatch`` and its ``verdict_sync``).
     A flush of several chunks dispatches every chunk's scan before any
     verdict, so there ``scan_prep`` and ``scan_dispatch`` lie beside the
     checks, not inside them: a check is then the combined pair stage and
@@ -269,6 +279,9 @@ class TpuBackend(CryptoBackend):
     ) -> None:
         self.suite = suite or BLSSuite()
         self.metrics = metrics if metrics is not None else Metrics()
+        # The pure-Python oracle on the same suite: no flush calls it (a
+        # verdict is the device's); the stubbed-kernel harnesses of tests
+        # and chipbench/tests answer through it.
         self._eager = EagerBackend(self.suite)
         if shard is None:
             shard = os.environ.get("HBBFT_TPU_SHARD") == "1"
@@ -328,18 +341,31 @@ class TpuBackend(CryptoBackend):
                 g1_entries.append((c, (-ct.u).jac, l, 1))
         return g2_entries, g1_entries, rhs
 
-    def _aggregate_ok(self, reqs: Sequence[VerifyRequest], depth: int = 0) -> bool:
-        """One aggregate check of a whole flush or of a bisection's half:
-        scan, pair stage, verdict."""
+    def _aggregate_ok(
+        self,
+        reqs: Sequence[VerifyRequest],
+        depth: int = 0,
+        prepared=None,
+        ahead: Sequence[VerifyRequest] | None = None,
+    ):
+        """One aggregate check of a whole flush or of one of bisection's
+        groups: scan, pair stage, verdict.  ``prepared`` is this group's
+        :meth:`_scan_prep` where the check before it made it; ``ahead``
+        the requests of the group checked next, prepared here once both
+        programs are dispatched, while the device runs them.  Returns
+        (verdict, what was prepared ahead or None)."""
         with self.metrics.span("crypto.tpu.check", rows=len(reqs), depth=depth):
-            return self._verdict(
-                [self._scan_dev(reqs, alone=True)], len(reqs)
-            )
+            if prepared is None:
+                prepared = self._scan_prep(reqs)
+            ok_dev = self._check_parts([self._scan_dispatch(prepared, alone=True)])
+            if ahead is not None:
+                ahead = self._scan_prep(ahead)
+                self.metrics.count("crypto.tpu.prepared_ahead")
+            return self._verdict(ok_dev, len(reqs)), ahead
 
-    def _verdict(self, parts, rows: int) -> bool:
-        """The pair stage over ``parts`` and the host's wait for its
-        verdict; counts the check that this ends."""
-        ok_dev = self._check_parts(parts)
+    def _verdict(self, ok_dev, rows: int) -> bool:
+        """The host's wait for a dispatched pair stage's verdict; counts
+        the check that this ends."""
         with self.metrics.span("crypto.tpu.verdict_sync"):
             ok = bool(ok_dev)
         self.metrics.count("crypto.tpu.checks")
@@ -348,13 +374,18 @@ class TpuBackend(CryptoBackend):
             self.metrics.count("crypto.tpu.checks_failed")
         return ok
 
-    def _scan_dev(self, reqs: Sequence[VerifyRequest], alone: bool = False):
-        """Dispatch one chunk's SCAN kernel; returns (sub_ok, lhs, rhs)
-        device values WITHOUT forcing a host sync, so independent chunks
-        pipeline on device.  ``alone``: this chunk is the whole flush, so
-        its own pair count is the PAIR stage's (several chunks combine
-        into a bucket only :meth:`verify_batch` knows)."""
-        (n1, n2, nl), args = self._scan_prep(reqs)
+    def _scan_dev(self, reqs: Sequence[VerifyRequest]):
+        """Prepare and dispatch one chunk's SCAN kernel."""
+        return self._scan_dispatch(self._scan_prep(reqs))
+
+    def _scan_dispatch(self, prepared, alone: bool = False):
+        """Dispatch the SCAN kernel on one chunk's :meth:`_scan_prep`;
+        returns (sub_ok, lhs, rhs) device values WITHOUT forcing a host
+        sync, so independent chunks pipeline on device.  ``alone``: this
+        chunk is the whole check, so its own pair count is the PAIR
+        stage's (several chunks combine into a bucket only
+        :meth:`verify_batch` knows)."""
+        (n1, n2, nl), args = prepared
         if alone and self._mesh is None:
             _compile_pair_kernel_early(_pairs_bucket(1 + nl))
         with self.metrics.span("crypto.tpu.scan_dispatch"):
@@ -362,9 +393,10 @@ class TpuBackend(CryptoBackend):
 
     def _scan_prep(self, reqs: Sequence[VerifyRequest]):
         """Host prep for one chunk: returns ((n1, n2, nl), kernel args).
-        Split from :meth:`_scan_dev` so measurement tooling
-        (benchmarks/flush_roofline.py) can lower the cached kernel on
-        the exact production inputs."""
+        Split from :meth:`_scan_dispatch` so that bisection prepares a
+        group while the device checks the one before it, and measurement
+        tooling (benchmarks/flush_roofline.py) can lower the cached
+        kernel on the exact production inputs."""
         with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
             with self.metrics.span("crypto.tpu.coefficients"):
                 coeffs = _batch_coefficients(self.suite, reqs)
@@ -529,7 +561,8 @@ class TpuBackend(CryptoBackend):
         if not chunks:
             return out
         if len(chunks) == 1:
-            if self._aggregate_ok([reqs[i] for i in idxs]):
+            ok, _ = self._aggregate_ok([reqs[i] for i in idxs])
+            if ok:
                 for i in idxs:
                     out[i] = True
             else:
@@ -542,7 +575,7 @@ class TpuBackend(CryptoBackend):
 
         def check(parts, rows: int) -> bool:
             with self.metrics.span("crypto.tpu.check", rows=rows, depth=0):
-                return self._verdict(parts, rows)
+                return self._verdict(self._check_parts(parts), rows)
 
         # Fast path: ALL chunks' pairs through one batched Miller loop +
         # one final exponentiation (fixed pairing cost paid once per
@@ -566,21 +599,38 @@ class TpuBackend(CryptoBackend):
         out: List[bool],
         depth: int,
     ) -> None:
-        """Bisection fallback — the caller knows idxs' aggregate FAILED,
-        so split immediately and aggregate only the halves (``depth``:
-        how many splits lie above them)."""
-        if len(idxs) == 1:
-            self.metrics.count("crypto.tpu.leaves")
-            with self.metrics.span("crypto.tpu.leaf"):
-                out[idxs[0]] = self._eager.verify_batch([all_reqs[idxs[0]]])[0]
-            return
-        mid = len(idxs) // 2
-        for half in (idxs[:mid], idxs[mid:]):
-            if self._aggregate_ok([all_reqs[i] for i in half], depth):
-                for i in half:
-                    out[i] = True
-            else:
-                self._bisect(all_reqs, half, out, depth + 1)
+        """Bisection fallback — the caller knows idxs' aggregate FAILED
+        (``depth``: how many splits lie above its halves).
+
+        Level by level: a level is the halves of every group that failed
+        on the level above, checked in order.  Both halves of a failed
+        group are always checked, so while the device runs one group's
+        programs the host prepares the next one's arguments; only a
+        level's first group is prepared with the device idle, because the
+        level is not known before the last verdict of the one above.  A
+        group of one whose check fails is convicted by it (class
+        docstring): every verdict is the device's."""
+        failed = [idxs]
+        while failed:
+            groups: List[List[int]] = []
+            for g in failed:
+                if len(g) == 1:
+                    self.metrics.count("crypto.tpu.leaves")
+                    with self.metrics.span("crypto.tpu.leaf"):
+                        out[g[0]] = False
+                else:
+                    groups += [g[: len(g) // 2], g[len(g) // 2 :]]
+            batches = [[all_reqs[i] for i in g] for g in groups]
+            failed = []
+            prepared = None
+            for g, batch, ahead in zip(groups, batches, batches[1:] + [None]):
+                ok, prepared = self._aggregate_ok(batch, depth, prepared, ahead)
+                if ok:
+                    for i in g:
+                        out[i] = True
+                else:
+                    failed.append(g)
+            depth += 1
 
 
 class HybridBackend(CryptoBackend):

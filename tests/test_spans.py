@@ -3,13 +3,16 @@ profiler session on the CPU backend, the counters held to the spans, the
 ``stats`` op, the two programs' names.
 
 No flush program is compiled: the two kernels are stubs whose verdicts are
-scripted, so that one flush passes whole and one bisects down to a leaf.
+scripted, so that one flush passes whole, one bisects down to a leaf and one
+is the benchmark's 5 wrong of 16.  The oracle is patched to raise: every
+verdict is the (stub) device's.
 """
 
 import json
 import random
 import subprocess
 import sys
+import time
 from collections import Counter
 
 import pytest
@@ -96,22 +99,32 @@ def test_span_is_an_event_of_an_open_profiler_session():
 
 # -- the worker's span tree ---------------------------------------------------
 
-ROWS = 2          # requests a flush
 BUCKET = 16       # the smallest G1 and G2 bucket
+BYZ5 = (1, 6, 7, 12, 14)  # one of the 1536 sets chipbench's byz5of16 admits
 
-# verdicts of the pair stub, in order -> what the flush must count
+# ``verdicts``: the pair stub's answers in order; ``wrong``: the stub fails a
+# check whose group holds one of these indices -> what the flush must count
 SCRIPTS = {
     # one check, passes whole
     "whole": dict(
-        verdicts=[True], checks=1, failed=0, leaves=0, rows=2,
-        padded=2 * (BUCKET - 2), depths=[0],
+        n=2, verdicts=[True], answers=[True, True], checks=1, failed=0,
+        leaves=0, rows=2, padded=2 * (BUCKET - 2), depths=[0], ahead=0,
     ),
-    # the flush's check fails, the first half's too (its one request goes
-    # to the oracle: a leaf), the second half passes
+    # the flush's check fails, the first half's too (a group of one: its
+    # request is convicted by that check, a leaf), the second half passes
     "bisects_to_a_leaf": dict(
-        verdicts=[False, False, True], checks=3, failed=2, leaves=1,
-        rows=2 + 1 + 1, padded=2 * (BUCKET - 2) + 4 * (BUCKET - 1),
-        depths=[0, 1, 1],
+        n=2, verdicts=[False, False, True], answers=[False, True], checks=3,
+        failed=2, leaves=1, rows=2 + 1 + 1,
+        padded=2 * (BUCKET - 2) + 4 * (BUCKET - 1), depths=[0, 1, 1], ahead=1,
+    ),
+    # levels of 1, 2, 4, 6 and 8 checks; below the root every check but a
+    # level's first was prepared ahead
+    "byz5_of_16": dict(
+        n=16, wrong=BYZ5, answers=[i not in BYZ5 for i in range(16)],
+        checks=21, failed=1 + 2 + 3 + 4 + 5, leaves=5,
+        rows=16 + 2 * 8 + 4 * 4 + 6 * 2 + 8 * 1,
+        padded=2 * (21 * BUCKET - 68),
+        depths=[0] + [1] * 2 + [2] * 4 + [3] * 6 + [4] * 8, ahead=16,
     ),
 }
 
@@ -125,19 +138,28 @@ def requests():
         VerifyRequest.sig_share(
             pks.public_key_share(i), b"doc", sks.secret_key_share(i).sign(b"doc")
         )
-        for i in range(ROWS)
+        for i in range(16)
     ]
 
 
-@pytest.fixture(params=sorted(SCRIPTS))
-def flushed(request, requests, monkeypatch):
-    """One RPC through server, service and a ``TpuBackend`` on stubbed
-    kernels, under a profiler session: (script, metrics, spans)."""
-    script = SCRIPTS[request.param]
-    verdicts = list(script["verdicts"])
+def _index(req):
+    """Which signer's request this is (the RPC server decodes new objects)."""
+    return req.payload[0].to_bytes()
+
+
+def stub_kernels(monkeypatch, verdict):
+    """Replace the two programs; ``verdict(reqs)`` answers the pair stage
+    for the requests of the most recent ``_scan_prep`` before its dispatch.
+    Returns the list that every prepared group is appended to."""
+    prepared = []
+    honest_prep = B.TpuBackend._scan_prep
+
+    def scan_prep(self, reqs):
+        prepared.append(list(reqs))
+        return honest_prep(self, reqs)
 
     def fake_pair_kernel(n_pairs):
-        return lambda lhs, rhs: jnp.asarray(verdicts.pop(0))
+        return lambda lhs, rhs: jnp.asarray(verdict(prepared[-1]))
 
     def fake_scan_kernel(n1, n2, nl):
         return lambda *args: (
@@ -146,14 +168,43 @@ def flushed(request, requests, monkeypatch):
             dc.identity(dc.G2_OPS, (1 + nl,)),
         )
 
+    monkeypatch.setattr(B.TpuBackend, "_scan_prep", scan_prep)
     monkeypatch.setattr(B, "_pair_kernel", fake_pair_kernel)
     monkeypatch.setattr(B, "_scan_kernel", fake_scan_kernel)
     monkeypatch.setattr(B, "_compile_pair_kernel_early", lambda n_pairs: None)
+    return prepared
 
+
+def stubbed_backend(suite, metrics=None):
+    """A ``TpuBackend`` whose oracle raises: no verdict may come from it."""
+    backend = B.TpuBackend(suite, metrics=metrics)
+
+    def no_oracle(reqs):
+        raise AssertionError("the oracle was asked for a verdict")
+
+    backend._eager.verify_batch = no_oracle
+    return backend
+
+
+@pytest.fixture(params=sorted(SCRIPTS))
+def flushed(request, requests, monkeypatch):
+    """One RPC through server, service and a ``TpuBackend`` on stubbed
+    kernels, under a profiler session: (script, metrics, spans)."""
+    script = SCRIPTS[request.param]
     suite, reqs = requests
+    reqs = reqs[: script["n"]]
+    verdicts = list(script.get("verdicts", ()))
+    wrong = {_index(reqs[i]) for i in script.get("wrong", ())}
+
+    def verdict(group):
+        if "wrong" in script:
+            return not wrong & {_index(r) for r in group}
+        return verdicts.pop(0)
+
+    stub_kernels(monkeypatch, verdict)
     metrics = Metrics()
     service = CryptoPlaneService(
-        B.TpuBackend(suite, metrics=metrics), window_s=0.0, metrics=metrics
+        stubbed_backend(suite, metrics), window_s=0.0, metrics=metrics
     )
     server = CryptoRpcServer(service, suite).start()
     client = RpcServiceClient(
@@ -161,7 +212,13 @@ def flushed(request, requests, monkeypatch):
     )
     session = Session()
     try:
-        assert client.verify_batch(reqs) == [True] * ROWS
+        assert client.verify_batch(reqs) == script["answers"]
+        # the server's thread closes its reply and serve spans after the
+        # client has the answer: wait for them, or the session lacks them
+        deadline = time.monotonic() + 10.0
+        while "crypto.rpc.serve" not in metrics.timers:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
     finally:
         spans = session.spans()
         client.close()
@@ -180,6 +237,7 @@ def test_counters_equal_the_spans_they_sit_beside(flushed):
     assert names["crypto.tpu.check"] == counters["crypto.tpu.checks"] == script["checks"]
     assert counters["crypto.tpu.checks_failed"] == script["failed"]
     assert names["crypto.tpu.leaf"] == counters["crypto.tpu.leaves"] == script["leaves"]
+    assert counters["crypto.tpu.prepared_ahead"] == script["ahead"]
     assert counters["crypto.tpu.rows"] == script["rows"]
     assert counters["crypto.tpu.rows_padded"] == script["padded"]
     assert (
@@ -201,11 +259,40 @@ def test_counters_equal_the_spans_they_sit_beside(flushed):
     assert {(a["n1"], a["n2"], a["legs"]) for a in preps} == {(BUCKET, BUCKET, 2)}
 
 
+def test_a_check_holds_its_own_dispatches_and_the_next_groups_prep(flushed):
+    """One scan launch, one pair launch and one sync a check, in that
+    order; a ``scan_prep`` before them is the check's own, one between the
+    pair's dispatch and the sync is the next check's, prepared ahead."""
+    script, _, spans = flushed
+    checks = [s for s in spans if s[1] == "crypto.tpu.check"]
+    ahead = 0
+    for k, (_, _, start, end, args) in enumerate(checks):
+        inside = {}
+        for _, name, s, e, a in spans:
+            if name.startswith("crypto.tpu.") and start <= s and e <= end:
+                inside.setdefault(name[len("crypto.tpu."):], []).append((s, e, a))
+        (scan,), (pair,), (sync,) = (
+            inside[stage]
+            for stage in ("scan_dispatch", "pair_dispatch", "verdict_sync")
+        )
+        assert scan[1] <= pair[0] and pair[1] <= sync[0]
+        for s, e, a in inside.get("scan_prep", ()):
+            if e <= scan[0]:
+                assert a["rows"] == args["rows"]
+            else:
+                assert pair[1] <= s and e <= sync[0]
+                nxt = checks[k + 1][4]
+                assert a["rows"] == nxt["rows"] and nxt["depth"] == args["depth"]
+                ahead += 1
+    assert ahead == script["ahead"]
+
+
 def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
-    _, _, spans = flushed
+    script, _, spans = flushed
+    rows = script["n"]
     (flush,) = [s for s in spans if s[1] == "crypto.flush"]
     line, _, start, end, args = flush
-    assert args["requests"] == ROWS and args["jobs"] == 1 and args["flush"] == 1
+    assert args["requests"] == rows and args["jobs"] == 1 and args["flush"] == 1
     for other, name, s, e, _ in spans:
         if name.startswith("crypto.tpu."):
             assert other == line and start <= s and e <= end, name
@@ -233,7 +320,7 @@ def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
     assert {s[0] for s in rpc} != {line} and len({s[0] for s in rpc}) == 1
     assert {s[4]["span"] for s in rpc} == set(args["spans"].split()) == {"1:1"}
     (serve,) = by_name["crypto.rpc.serve"]
-    assert serve[4]["op"] == "verify" and serve[4]["requests"] == ROWS
+    assert serve[4]["op"] == "verify" and serve[4]["requests"] == rows
     assert serve[4]["bytes"] == by_name["crypto.rpc.decode"][0][4]["bytes"] > 0
     for s in rpc:
         assert serve[2] <= s[2] and s[3] <= serve[3]
@@ -241,6 +328,60 @@ def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
     (window,) = by_name["crypto.window"]
     assert wait[2] <= start and end <= wait[3]
     assert window[0] == line and window[3] <= start
+
+
+# -- bisection against the depth-first recursion it replaced -------------------
+
+def depth_first_checks(idxs, wrong, depth=1):
+    """The plain reference: the (depth, group) checks that the recursion
+    before the level sweep made below a failed ``idxs``: a failed group's
+    halves, the first half's subtree before the second half; a failed group
+    of one went to the oracle."""
+    if len(idxs) == 1:
+        return []
+    mid = len(idxs) // 2
+    out = []
+    for half in (idxs[:mid], idxs[mid:]):
+        out.append((depth, half))
+        if wrong & set(half):
+            out += depth_first_checks(half, wrong, depth + 1)
+    return out
+
+
+@pytest.mark.parametrize(
+    "n,wrong",
+    [
+        (16, ()), (16, (0,)), (16, (15,)), (16, (7, 8)), (16, BYZ5),
+        (16, tuple(range(16))), (5, (0, 2, 4)),
+    ],
+    ids=["none", "first", "last", "two_adjacent", "byz5", "all", "3_of_5"],
+)
+def test_bisection_answers_the_wrong_set_with_the_recursions_checks(
+    requests, monkeypatch, n, wrong
+):
+    suite, reqs = requests
+    reqs = reqs[:n]
+    where = {_index(r): i for i, r in enumerate(reqs)}
+    prepared = stub_kernels(
+        monkeypatch,
+        lambda group: not any(where[_index(r)] in wrong for r in group),
+    )
+    backend = stubbed_backend(suite)
+    assert backend.verify_batch(reqs) == [i not in wrong for i in range(n)]
+    everyone = list(range(n))
+    want = [(0, everyone)]
+    if wrong:
+        want += depth_first_checks(everyone, set(wrong))
+    # the same checks, a level at a time instead of a subtree at a time (the
+    # sort is stable: within a level, left to right as the recursion went)
+    by_level = sorted(want, key=lambda check: check[0])
+    got = [[where[_index(r)] for r in group] for group in prepared]
+    assert got == [group for _, group in by_level]
+    counters = backend.metrics.counters
+    assert counters["crypto.tpu.checks"] == len(want)
+    assert counters["crypto.tpu.leaves"] == len(wrong)
+    levels = {depth for depth, _ in want[1:]}
+    assert counters["crypto.tpu.prepared_ahead"] == len(want) - 1 - len(levels)
 
 
 def test_stats_op_of_an_eager_worker_keeps_its_shape():
@@ -253,14 +394,18 @@ def test_stats_op_of_an_eager_worker_keeps_its_shape():
         VerifyRequest.sig_share(
             pks.public_key_share(i), b"doc", sks.secret_key_share(i).sign(b"doc")
         )
-        for i in range(ROWS)
+        for i in range(2)
     ]
     with ServiceProcess(suite="scalar", backend="eager") as svc:
         client = RpcServiceClient(svc.addr, suite, fallback=None)
         try:
-            assert client.verify_batch(reqs) == [True] * ROWS
+            assert client.verify_batch(reqs) == [True, True]
         finally:
             client.close()
+        # the worker closes the reply and serve spans of that request after
+        # its answer is out; only a ``stats`` request, which would count
+        # itself, could ask whether it has
+        time.sleep(0.5)
         stats = fetch_stats(svc.addr, suite)
     assert set(stats) == {"counters", "gauges", "timers", "summaries"}
     assert not [k for group in stats.values() for k in group if ".tpu." in k]

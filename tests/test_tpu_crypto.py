@@ -272,6 +272,36 @@ def test_tpu_backend_isolates_bad_shares():
 
 
 @heavy_compile
+def test_a_wrong_share_alone_in_a_flush_is_convicted_by_the_device():
+    """A group of one is answered by its own one-row check: a valid share of
+    another key and the point at infinity, each alone in a flush, come back
+    False with the oracle out of reach, and the honest share True as ever."""
+    from hbbft_tpu.crypto.keys import SignatureShare
+
+    suite = BLSSuite()
+    sks = SecretKeySet.random(1, random.Random(79), suite)
+    pk = sks.public_keys().public_key_share(0)
+    msg = b"alone in a flush"
+    backend = TpuBackend(suite)
+
+    def no_oracle(reqs):
+        raise AssertionError("the oracle was asked for a verdict")
+
+    backend._eager.verify_batch = no_oracle
+    for share, want in [
+        (sks.secret_key_share(1).sign(msg), False),             # another key's
+        (SignatureShare(suite.g2_identity(), suite), False),    # infinity
+        (sks.secret_key_share(0).sign(msg), True),
+    ]:
+        got = backend.verify_batch([VerifyRequest.sig_share(pk, msg, share)])
+        assert got == [want]
+    counters = backend.metrics.counters
+    assert counters["crypto.tpu.checks"] == 3
+    assert counters["crypto.tpu.checks_failed"] == counters["crypto.tpu.leaves"] == 2
+    assert counters["crypto.tpu.prepared_ahead"] == 0
+
+
+@heavy_compile
 def test_device_subgroup_check_and_rejection():
     """TpuBackend rejects a share forged from a non-subgroup point (the
     host does only structural checks — the membership test lives in the
