@@ -29,14 +29,16 @@ scalar field drawn from the seed; signer ``i`` holds ``poly(i + 1)``.
 
 Everything is computed with the benchmark's own plain arithmetic
 (chipbench/reference) and wrapped into the program's request types; the wire
-bytes the plain reference verifies are kept beside each request.
+bytes the plain reference verifies are kept beside each request, as kind
+``sig_share`` takes them: ``(pk_bytes, document, share_bytes)``.
 """
 
 from __future__ import annotations
 
 import random
-from typing import Any, Dict, List, NamedTuple, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Sequence
 
+from chipbench.generators import Flush
 from chipbench.reference import curve as C
 from chipbench.reference import verify as V
 from chipbench.reference.fields import R
@@ -48,25 +50,18 @@ class Keys(NamedTuple):
     pk_bytes: List[bytes]
 
 
-class Flush(NamedTuple):
-    """One ``verify_batch`` call: the requests, the verdicts the
-    construction expects, and each request's wire form for the reference."""
-
-    requests: List[Any]
-    expected: List[bool]
-    wire: List[Tuple[bytes, bytes, bytes]]
-    documents: int
+def poly_at(coeffs: Sequence[int], x: int) -> int:
+    """The key polynomial (lowest coefficient first) at ``x``, mod r."""
+    acc = 0
+    for c in reversed(coeffs):
+        acc = (acc * x + c) % R
+    return acc
 
 
 def make_keys(config: Dict[str, Any], params: Dict[str, Any], seed: int) -> Keys:
     rng = random.Random(f"chipbench keys {seed}")
     coeffs = [rng.randrange(R) for _ in range(int(config["threshold"]) + 1)]
-    secrets = []
-    for i in range(int(params["requests"])):
-        x, acc = i + 1, 0
-        for c in reversed(coeffs):
-            acc = (acc * x + c) % R
-        secrets.append(acc)
+    secrets = [poly_at(coeffs, i + 1) for i in range(int(params["requests"]))]
     pk_jac = [V.public_share(s) for s in secrets]
     return Keys(secrets, pk_jac, [V.g1_to_bytes(p) for p in pk_jac])
 
@@ -142,4 +137,4 @@ def make_flush(
         )
         expected.append(kind is None)
         wire.append((keys.pk_bytes[i], doc, V.g2_to_bytes(share)))
-    return Flush(requests, expected, wire, 1)
+    return Flush(requests, expected, wire, ["sig_share"] * n)

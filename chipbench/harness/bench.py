@@ -31,6 +31,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from chipbench.harness import stats as hstats
+from chipbench.harness import work
 from chipbench.harness.reduce_trace import find_trace
 from chipbench.harness.worker import DEFAULT_ENTRY, REPO_ROOT, Worker
 
@@ -95,7 +96,9 @@ PROBE = -1     # index of the probe flush
 
 def _build_one(args: Tuple[str, Dict, Dict, int, int, Any]):
     """One flush from the seed and the plain reference's verdict on each of
-    its requests, from their wire bytes."""
+    its requests, from their wire bytes, by the verifier of the request's
+    own kind (``chipbench/kinds/<kind>.py``)."""
+    from chipbench import kinds
     from chipbench.reference.verify import Reference
 
     generator, config, params, seed, index, keys = args
@@ -103,7 +106,10 @@ def _build_one(args: Tuple[str, Dict, Dict, int, int, Any]):
     flush = mod.make_flush(config, params, seed, index, keys)
     t = time.perf_counter()
     reference = Reference()
-    verdicts = [reference.verify(*wire) for wire in flush.wire]
+    verdicts = [
+        kinds.load(kind).verify(reference, *wire)
+        for kind, wire in zip(flush.kinds, flush.wire)
+    ]
     return flush, verdicts, time.perf_counter() - t
 
 
@@ -431,7 +437,7 @@ def judge(
         for i in built
         for e, v in zip(prep.get(i).expected, prep.reference(i))
     )
-    due = n_req * len(every)
+    due = sum(len(prep.get(index).requests) for index, _ in every)
     compared = {
         "answers_differing_from_reference": {"value": vs_reference, "limit": 0},
         "answers_differing_from_construction": {"value": vs_construction, "limit": 0},
@@ -529,8 +535,7 @@ def layer_metrics(
         "trace": reduced,
         "trace_cut": dev["cut"],
         "host": host_seen,
-        "documents_per_flush": prep.flushes[1].documents,
-        "document_bytes": len(prep.flushes[1].wire[0][1]),
+        "work": work.compose(prep.flushes[1].kinds, prep.flushes[1].wire),
         "notes": notes,
     }
     metrics: Dict[str, Dict[str, Any]] = {}

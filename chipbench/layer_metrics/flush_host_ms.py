@@ -1,6 +1,6 @@
 """Backend host work: the worker's flush time that the device was not busy
 ((``crypto.flush`` total - device busy time) / traced flushes): host prep,
-dispatch, bisection's oracle leaves.  Where the device's window was closed
+dispatch, bisection's bookkeeping.  Where the device's window was closed
 inside a flush, the flushes are the host-only window's and the device time is
 ``device_busy_ms``'s estimate for a whole flush."""
 

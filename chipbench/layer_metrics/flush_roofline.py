@@ -1,5 +1,6 @@
 """Kernels: the least time the chip could take for a flush's traffic over
-the device time it took (chipbench/harness/work.py, peaks.py).
+the device time it took (chipbench/harness/work.py, peaks.py), the traffic
+being the composition of the flush that was built (``obs["work"]``).
 
 Only where every request is valid: a faulty round's re-flushes are device
 time that the answer's least work does not hold, so the share would read
@@ -16,11 +17,7 @@ def read(obs):
         return None
     if int(params.get("wrong", 0)):
         return None
-    least = work.least_seconds(
-        obs["config"]["share_kind"], int(params["requests"]),
-        obs["documents_per_flush"], obs["document_bytes"],
-        peaks.peaks_for(obs["device_kind"]),
-    )
+    least = work.least_seconds(obs["work"], peaks.peaks_for(obs["device_kind"]))
     obs["notes"]["roofline_bound"] = least["bound"]
     obs["notes"]["roofline_least_s"] = least["seconds"]
     return least["seconds"] / (trace["busy_s"] / obs["flushes"]) * 100.0

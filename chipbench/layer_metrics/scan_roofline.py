@@ -1,15 +1,11 @@
 """Kernels: the least time the chip could take for the scan program's part
-of a flush's traffic (per share a scalar multiplication in G1 and in G2 and
-a G2 subgroup check: ``work.PER_SHARE``) over ``scan_ms``.  With
-``pair_roofline``'s it is ``work.fq_muls`` split in two; same conversion to
-int8 operations, same peak.  Only where every request is valid, as
-``flush_roofline``."""
+of a flush's traffic (every request's scalar multiplications and subgroup
+checks by its kind: ``work.scan_fq_muls`` of the flush that was built) over
+``scan_ms``.  With ``pair_roofline``'s it is ``work.fq_muls`` split in two;
+same conversion to int8 operations, same peak.  Only where every request is
+valid, as ``flush_roofline``."""
 
 from chipbench.harness import peaks, reduce_spans, work
-
-
-def least_fq_muls(kind, requests):
-    return requests * work.PER_SHARE[kind]
 
 
 def share(obs, fq_muls, device_ms):
@@ -21,13 +17,9 @@ def share(obs, fq_muls, device_ms):
 
 
 def read(obs):
-    params = obs["traffic"]["params"]
-    if int(params.get("wrong", 0)):
+    if int(obs["traffic"]["params"].get("wrong", 0)):
         return None
     scan_ms = reduce_spans.module_ms(obs, "scan")
     if scan_ms is None:
         return None
-    return share(
-        obs, least_fq_muls(obs["config"]["share_kind"], int(params["requests"])),
-        scan_ms,
-    )
+    return share(obs, work.scan_fq_muls(obs["work"].requests), scan_ms)

@@ -25,13 +25,19 @@ def bench():
 @pytest.fixture(scope="session")
 def tiny_bench(bench):
     """The real metrics and the real ``coin16`` configuration over traffic
-    small enough for the pure-Python ``eager`` worker."""
+    small enough for the pure-Python ``eager`` worker, and a decrypt phase
+    (``hb4``) that is a configuration file, a traffic file and these
+    entries: what a PR that adds ``hb16.decrypt`` brings, and no more."""
     tiny = dict(bench)
     tiny["paths"] = ["."]
-    tiny["configs"] = [{"name": "coin16", "file": "../../configs/coin16.json"}]
+    tiny["configs"] = [
+        {"name": "coin16", "file": "../../configs/coin16.json"},
+        {"name": "hb4", "file": "configs/hb4.json"},
+    ]
     tiny["workloads"] = [
         {"name": "tiny.clean", "config": "coin16", "traffic": "tiny_clean", "chips": 1},
         {"name": "tiny.byz", "config": "coin16", "traffic": "tiny_byz", "chips": 1},
+        {"name": "hb4.decrypt", "config": "hb4", "traffic": "tiny_decrypt", "chips": 1},
     ]
     for group in ("end_to_end", "per_layer"):
         tiny[group] = [

@@ -4,8 +4,9 @@
 ``eager`` backend that the worker is about to build and then runs the
 benchmark's worker entry unchanged:
 
-* ``control``: the plain reference put in the program's place, with its
-  Miller loop cut to the top 32 of 63 steps: the configuration's "full
+* ``control``: the plain reference put in the program's place, every
+  request judged by its own kind's verifier (``chipbench/kinds``), with the
+  Miller loops cut to the top 32 of 63 steps: the configuration's "full
   Miller loop" guarantee broken, the step that would tempt a later PR;
 * ``flip``: one answer of every flush altered where it is produced;
 * ``accept``: verification skipped, every answer True.
@@ -20,6 +21,7 @@ sys.path.insert(0, ROOT)
 
 from hbbft_tpu.crypto import backend as program_backend  # noqa: E402
 
+from chipbench import kinds  # noqa: E402
 from chipbench.harness import worker_entry  # noqa: E402
 from chipbench.reference.verify import Reference  # noqa: E402
 
@@ -33,8 +35,7 @@ def patch(mode: str) -> None:
 
         def verify_batch(self, reqs):
             return [
-                reference.verify(r.payload[0].to_bytes(), r.payload[1],
-                                 r.payload[2].to_bytes())
+                kinds.load(r.kind).verify(reference, *kinds.load(r.kind).wire_of(r))
                 for r in reqs
             ]
     elif mode == "flip":

@@ -2,6 +2,7 @@
 
 import pytest
 
+from chipbench import kinds
 from chipbench.harness import peaks, stats, work
 from chipbench.layer_metrics import (
     device_busy_ms,
@@ -16,20 +17,89 @@ from chipbench.layer_metrics import (
 def test_fq_muls_fixed_values():
     # per share 1593 (G1) + 3888 (G2) + 1159 (subgroup) = 6640; two Miller
     # loops 2268 + 2 * 4432 = 11132; final exponentiation 8458
-    assert work.PER_SHARE["sig_share"] == 6640
-    assert work.fq_muls("sig_share", 16, 1) == 16 * 6640 + 11132 + 8458 == 125830
-    assert work.fq_muls("sig_share", 2048, 1) == 13618310
+    assert kinds.load("sig_share").SCAN_FQ_MULS == 6640
+    assert work.fq_muls({"sig_share": 16}, 2) == 16 * 6640 + 11132 + 8458 == 125830
+    assert work.fq_muls({"sig_share": 2048}, 2) == 13618310
     # a second document is one more Miller loop, nothing else
-    assert work.fq_muls("sig_share", 16, 2) - work.fq_muls("sig_share", 16, 1) == 4432
-    with pytest.raises(KeyError):
-        work.fq_muls("dec_share", 16, 1)
+    assert work.fq_muls({"sig_share": 16}, 3) - work.fq_muls({"sig_share": 16}, 2) == 4432
+    with pytest.raises(KeyError, match="chipbench/kinds/key_share.py"):
+        work.fq_muls({"key_share": 16}, 2)
     with pytest.raises(ValueError):
-        work.fq_muls("sig_share", 0, 1)
+        work.fq_muls({"sig_share": 0}, 2)
+    with pytest.raises(ValueError):
+        work.fq_muls({"sig_share": 16}, 1)
+
+
+def test_fq_muls_of_the_decrypt_phase_fixed_values():
+    # G1 subgroup check: two chains of 63 doublings at 7, 5 mixed additions
+    # at 11 and 5 general ones at 16, one product for the endomorphism
+    assert work.G1_SUBGROUP_CHECK == 2 * 63 * 7 + 5 * 11 + 5 * 16 + 1 == 1018
+    # a decryption share: two G1 scalar multiplications and that check
+    assert kinds.load("dec_share").SCAN_FQ_MULS == 2 * 1593 + 1018 == 4204
+    # a ciphertext check: a signature share's work and the G1 check of U
+    assert kinds.load("ciphertext").SCAN_FQ_MULS == 6640 + 1018 == 7658
+    # one ciphertext check: the generator's pair and its hash's
+    assert work.fq_muls({"ciphertext": 1}, 2) == 7658 + 11132 + 8458 == 27248
+    # 15 shares on one ciphertext: H(U, V) and W, no generator pair
+    assert work.fq_muls({"dec_share": 15}, 2) == 15 * 4204 + 11132 + 8458 == 82650
+    # both in one flush: the generator's, the hash's (shared) and W's
+    assert work.fq_muls({"ciphertext": 1, "dec_share": 15}, 3) == (
+        7658 + 15 * 4204 + 2268 + 3 * 4432 + 8458
+    ) == 94740
+
+
+U, V, W, W2 = b"u" * 97, b"v" * 40, b"w" * 193, b"x" * 193
+
+
+def _shares(n, u=U, v=V, w=W):
+    return ["dec_share"] * n, [
+        (bytes([i]) * 97, u, v, w, bytes([100 + i]) * 97) for i in range(n)
+    ]
+
+
+@pytest.mark.parametrize(
+    "kinds_,wire,requests,pairs,wire_bytes",
+    [
+        # 16 signature shares on one document: the document is sent once
+        (["sig_share"] * 16, [(bytes([i]) * 97, b"d" * 40, b"s" * 193) for i in range(16)],
+         {"sig_share": 16}, 2, 16 * 290 + 40),
+        # on two documents: one more pair
+        (["sig_share"] * 4, [(b"p" * 97, b"d%d" % (i % 2), b"s" * 193) for i in range(4)],
+         {"sig_share": 4}, 3, 4 * 290 + 2 * 2),
+        (["ciphertext"], [(U, V, W)], {"ciphertext": 1}, 2, 97 + 40 + 193),
+        # a decryption share carries its whole ciphertext, every time
+        (*_shares(15), {"dec_share": 15}, 2, 15 * (97 + 97 + 97 + 40 + 193)),
+        # the check's W is the shares' W: generator, H(U, V), W
+        (["ciphertext"] + _shares(15)[0], [(U, V, W)] + _shares(15)[1],
+         {"ciphertext": 1, "dec_share": 15}, 3, 330 + 15 * 524),
+        # a check sent with another's W shares the hash's pair alone
+        (["ciphertext"] + _shares(3)[0], [(U, V, W2)] + _shares(3)[1],
+         {"ciphertext": 1, "dec_share": 3}, 3, 330 + 3 * 524),
+        # shares on two ciphertexts: two hashes, two Ws
+        (_shares(2)[0] * 2, _shares(2)[1] + _shares(2, u=b"t" * 97, w=W2)[1],
+         {"dec_share": 4}, 4, 4 * 524),
+        # a coin round and a decrypt phase in one flush
+        (["sig_share"] + _shares(2)[0], [(b"p" * 97, b"doc", b"s" * 193)] + _shares(2)[1],
+         {"sig_share": 1, "dec_share": 2}, 4, 290 + 3 + 2 * 524),
+    ],
+)
+def test_a_flushs_composition_and_its_two_parts(kinds_, wire, requests, pairs, wire_bytes):
+    flush = work.compose(kinds_, wire)
+    assert flush == work.Composition(requests, pairs, wire_bytes)
+    # the scan program's part and the pair program's sum to the whole, exactly
+    assert (
+        work.scan_fq_muls(flush.requests) + work.pair_fq_muls(flush.pairs)
+        == work.fq_muls(flush.requests, flush.pairs)
+    )
+    assert work.scan_fq_muls(flush.requests) == sum(
+        n * kinds.load(k).SCAN_FQ_MULS for k, n in requests.items()
+    )
+    assert work.pair_fq_muls(pairs) == 2268 + pairs * 4432 + 8458
 
 
 def test_least_seconds_names_its_bound():
     v5e = peaks.peaks_for("TPU v5 lite")
-    least = work.least_seconds("sig_share", 16, 1, 40, v5e)
+    least = work.least_seconds(work.Composition({"sig_share": 16}, 2, 16 * 290 + 40), v5e)
     assert least["bound"] == "compute_int8"
     assert least["seconds"] == pytest.approx(125830 * 13824 / 393e12)
     assert least["memory_s"] == pytest.approx((16 * 290 + 40) / 819e9)
@@ -54,12 +124,12 @@ def test_quantile_interpolates_between_ranks():
 
 def _obs(**over):
     obs = {
-        "config": {"share_kind": "sig_share"},
+        "config": {},
         "traffic": {"params": {"requests": 16, "wrong": 0}},
         "device_kind": "TPU v5 lite",
         "flushes": 4, "client_s": 1.0, "worker_flush_s": 0.8, "worker_flushes": 4,
         "trace": {"busy_s": 0.2, "launches": 8}, "trace_cut": False, "host": None,
-        "documents_per_flush": 1, "document_bytes": 40, "notes": {},
+        "work": work.Composition({"sig_share": 16}, 2, 16 * 290 + 40), "notes": {},
     }
     obs.update(over)
     return obs

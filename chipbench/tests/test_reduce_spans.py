@@ -15,7 +15,7 @@ MS = 1e6  # ns
 
 NEW_READERS = [
     "scan_ms", "pair_ms", "scan_roofline", "pair_roofline", "host_prep_ms",
-    "hash_to_g2_ms", "oracle_leaf_ms", "checks_per_flush", "rpc_server_decode_ms",
+    "hash_to_g2_ms", "checks_per_flush", "rpc_server_decode_ms",
 ]
 
 # what the one whole flush of the fixture holds: name -> (ms, count)
@@ -164,8 +164,8 @@ def test_reader_returns_none_without_a_trace(name):
     obs = {
         "trace": None, "host": None, "trace_cut": False, "notes": {},
         "traffic": {"params": {"requests": 16, "wrong": 0}},
-        "config": {"share_kind": "sig_share"}, "device_kind": None,
-        "documents_per_flush": 1,
+        "config": {}, "device_kind": None,
+        "work": work.Composition({"sig_share": 16}, 2, 16 * 290 + 40),
     }
     assert reader.read(obs) is None
     assert obs["notes"] == {}
@@ -188,8 +188,8 @@ def test_readers_on_the_cached_reduction(profile, monkeypatch, tmp_path):
     obs = {
         "trace": {"busy_s": 1.0}, "host": None, "trace_cut": False, "notes": {},
         "traffic": {"params": {"requests": 16, "wrong": 0}},
-        "config": {"share_kind": "sig_share"}, "device_kind": "TPU v5 lite",
-        "documents_per_flush": 1,
+        "config": {}, "device_kind": "TPU v5 lite",
+        "work": work.Composition({"sig_share": 16}, 2, 16 * 290 + 40),
     }
 
     def read(name):
@@ -199,13 +199,19 @@ def test_readers_on_the_cached_reduction(profile, monkeypatch, tmp_path):
     assert read("pair_ms") == pytest.approx(2.0)
     assert read("host_prep_ms") == pytest.approx(3.5)
     assert read("hash_to_g2_ms") == pytest.approx(1.5)
-    assert read("oracle_leaf_ms") is None  # no leaf in this flush: not 0
     assert read("checks_per_flush") == 1
     assert read("rpc_server_decode_ms") == pytest.approx(0.3)
     peak = 393e12
-    scan_least = 16 * work.PER_SHARE["sig_share"] * work.INT8_OPS_PER_FQ_MUL / peak
+    scan_least = 16 * 6640 * work.INT8_OPS_PER_FQ_MUL / peak
     assert read("scan_roofline") == pytest.approx(scan_least / 3e-3 * 100)
-    assert 0 < read("pair_roofline") < 100
+    pair_least = (125830 - 16 * 6640) * work.INT8_OPS_PER_FQ_MUL / peak
+    assert read("pair_roofline") == pytest.approx(pair_least / 2e-3 * 100)
+    # a decrypt phase's flush reads its own composition, not the coin's
+    obs["work"] = work.Composition({"ciphertext": 1, "dec_share": 15}, 3, 8190)
+    scan_least = (7658 + 15 * 4204) * work.INT8_OPS_PER_FQ_MUL / peak
+    assert read("scan_roofline") == pytest.approx(scan_least / 3e-3 * 100)
+    pair_least = (94740 - 7658 - 15 * 4204) * work.INT8_OPS_PER_FQ_MUL / peak
+    assert read("pair_roofline") == pytest.approx(pair_least / 2e-3 * 100)
     assert obs["notes"]["spans_from"] == "device_window"
     assert obs["notes"]["reduce_spans_s"] == 0.5
     assert obs["notes"]["spans_per_flush"]["crypto.flush"]["count"] == 1
@@ -213,14 +219,3 @@ def test_readers_on_the_cached_reduction(profile, monkeypatch, tmp_path):
     # a faulty round's share would read the bisection, not the kernels
     obs["traffic"]["params"]["wrong"] = 5
     assert read("scan_roofline") is None and read("pair_roofline") is None
-
-
-@pytest.mark.parametrize("requests,documents", [(16, 1), (2048, 1), (256, 16)])
-def test_scan_and_pair_least_work_sum_to_the_flushs(requests, documents):
-    from chipbench.layer_metrics import pair_roofline, scan_roofline
-
-    assert (
-        scan_roofline.least_fq_muls("sig_share", requests)
-        + pair_roofline.least_fq_muls(documents)
-        == work.fq_muls("sig_share", requests, documents)
-    )

@@ -7,7 +7,8 @@ draft-irtf-cfrg-pairing-friendly-curves, section 4.2.1, typed in here and
 not imported) and to the algebra that defines a pairing: a map that is
 bilinear and not degenerate on the two groups of prime order r IS the
 pairing up to a fixed power coprime to r, and every such power gives the same
-verdict on ``e(pk, H(doc)) == e(g1, sig)``.
+verdict on ``e(pk, H(doc)) == e(g1, sig)``, on
+``e(share, H(U, V)) == e(pk, W)`` and on ``e(g1, W) == e(U, H(U, V))``.
 """
 
 import random
@@ -89,8 +90,128 @@ def test_a_share_off_the_torsion_off_the_curve_or_at_infinity_is_refused():
     assert C.g2_on_curve(*C.jac_to_affine(C.FQ2_OPS, stray))
     assert not C.in_subgroup_slow(C.FQ2_OPS, stray)
     pk = V.g1_to_bytes(V.public_share(5))
-    assert reference.verify(pk, b"doc", V.g2_to_bytes(stray)) is False
-    assert reference.verify(pk, b"doc", bytes(193)) is False        # identity
-    assert reference.verify(pk, b"doc", b"\x01" + bytes(192)) is False  # off the curve
+    assert reference.sig_share(pk, b"doc", V.g2_to_bytes(stray)) is False
+    assert reference.sig_share(pk, b"doc", bytes(193)) is False        # identity
+    assert reference.sig_share(pk, b"doc", b"\x01" + bytes(192)) is False  # off the curve
     good = V.g2_to_bytes(V.sign(5, C.hash_to_g2(b"doc")))
-    assert reference.verify(pk, b"doc", good) is True
+    assert reference.sig_share(pk, b"doc", good) is True
+
+
+# -- threshold decryption: a degree-2 key set, five signers ------------------
+
+COEFFS = (0x1234567, 0x89ABCDE, 0xF012345)
+MESSAGE = b"a proposal of thirty-seven bytes, say"
+
+
+def _secret(x: int) -> int:
+    return (COEFFS[0] + COEFFS[1] * x + COEFFS[2] * x * x) % R
+
+
+def _phase():
+    """(ciphertext, [(pk_bytes, share point)] of signers 0..4)."""
+    ct = V.encrypt(V.public_share(_secret(0)), MESSAGE, 0xABCDEF0123456789)
+    signers = [
+        (V.g1_to_bytes(V.public_share(_secret(i + 1))),
+         V.decryption_share(_secret(i + 1), ct.u))
+        for i in range(5)
+    ]
+    return ct, signers
+
+
+def _cofactor_torsion_point() -> C.Jac:
+    """A point of E(Fq) whose order divides the cofactor: ``[r]`` of the
+    first point of the curve found from x = 1 upwards that this leaves
+    standing."""
+    x = 1
+    while True:
+        rhs = (x * x * x + 4) % P
+        y = pow(rhs, (P + 1) // 4, P)  # p = 3 mod 4
+        if y * y % P == rhs:
+            t = C.jac_mul(C.FQ_OPS, (x, y, 1), R)
+            if not C.jac_is_identity(C.FQ_OPS, t):
+                return t
+        x += 1
+
+
+def test_a_decryption_share_verifies_and_threshold_plus_one_decrypt():
+    reference = V.Reference()
+    ct, signers = _phase()
+    assert ct.v != MESSAGE and len(ct.v) == len(MESSAGE)
+    assert reference.ciphertext(ct.u_bytes, ct.v, ct.w_bytes) is True
+    for pk, share in signers:
+        assert reference.dec_share(
+            pk, ct.u_bytes, ct.v, ct.w_bytes, V.g1_to_bytes(share)
+        ) is True
+    # Lagrange in the exponent, from any three of the five
+    for indices in ((0, 1, 2), (4, 2, 0), (1, 3, 4)):
+        shares = [signers[i][1] for i in indices]
+        assert V.combine_decryption_shares(indices, shares, ct.v) == MESSAGE
+    # two shares, or three of which one is another signer's, do not
+    assert V.combine_decryption_shares(
+        (0, 1), [signers[0][1], signers[1][1]], ct.v
+    ) != MESSAGE
+    assert V.combine_decryption_shares(
+        (0, 1, 2), [signers[0][1], signers[1][1], signers[3][1]], ct.v
+    ) != MESSAGE
+
+
+def test_wrong_decryption_shares_are_refused_and_which_check_refuses():
+    reference = V.Reference()
+    ct, signers = _phase()
+    pk, share = signers[0]
+    args = (pk, ct.u_bytes, ct.v, ct.w_bytes)
+    # a valid share of the next key: every point is sound, the equation fails
+    next_share = V.g1_to_bytes(signers[1][1])
+    assert reference.g1(next_share) is not None
+    assert reference.dec_share(*args, next_share) is False
+    # the point at infinity does not decode to a point
+    assert reference.g1(bytes(97)) is None
+    assert reference.dec_share(*args, bytes(97)) is False
+    assert reference.dec_share(*args, b"\x01" + bytes(96)) is False  # off the curve
+    # a valid share plus a point of the cofactor's torsion: on the curve,
+    # and it SATISFIES the pairing equation (the pairing is trivial on the
+    # cofactor's torsion), so only [r]P == O refuses it
+    torsion = _cofactor_torsion_point()
+    assert C.jac_is_identity(C.FQ_OPS, C.jac_mul(C.FQ_OPS, torsion, C.H1))
+    stray = C.jac_add(C.FQ_OPS, share, torsion)
+    stray_aff = C.jac_to_affine(C.FQ_OPS, stray)
+    assert C.g1_on_curve(*stray_aff)
+    assert not C.in_subgroup_slow(C.FQ_OPS, stray)
+    h = reference.hashed(V.ciphertext_hash_input(ct.u_bytes, ct.v))
+    w = reference.g2(ct.w_bytes)
+    assert reference.pairings_equal(stray_aff, h, reference.g1(pk), w) is True
+    assert reference.g1(V.g1_to_bytes(stray)) is None
+    assert reference.dec_share(*args, V.g1_to_bytes(stray)) is False
+    # the same share under a cut Miller loop: the control's knob reaches
+    # this verifier too
+    good = V.g1_to_bytes(share)
+    assert reference.dec_share(*args, good) is True
+    assert V.Reference(miller_bits=32).dec_share(*args, good) is False
+
+
+def test_a_ciphertext_with_anothers_w_is_refused():
+    reference = V.Reference()
+    ct, signers = _phase()
+    other = V.encrypt(V.public_share(_secret(0)), MESSAGE, 0x1122334455667788)
+    assert reference.ciphertext(other.u_bytes, other.v, other.w_bytes) is True
+    assert reference.g2(other.w_bytes) is not None
+    assert reference.ciphertext(ct.u_bytes, ct.v, other.w_bytes) is False
+    # and a share checked against that W is refused with it
+    pk, share = signers[2]
+    assert reference.dec_share(
+        pk, ct.u_bytes, ct.v, other.w_bytes, V.g1_to_bytes(share)
+    ) is False
+    # V is part of what is hashed: one flipped bit of it and W no longer fits
+    flipped = bytes([ct.v[0] ^ 1]) + ct.v[1:]
+    assert reference.ciphertext(ct.u_bytes, flipped, ct.w_bytes) is False
+    assert reference.ciphertext(bytes(97), ct.v, ct.w_bytes) is False
+    assert V.Reference(miller_bits=32).ciphertext(ct.u_bytes, ct.v, ct.w_bytes) is False
+
+
+def test_the_hash_input_frames_every_part_behind_its_length():
+    u, v = b"\x01" + bytes(range(96)), b"payload"
+    assert V.ciphertext_hash_input(u, v) == (
+        (2).to_bytes(8, "big") + b"ct" + (97).to_bytes(8, "big") + u
+        + (7).to_bytes(8, "big") + v
+    )
+    assert V.ciphertext_hash_input(u, b"") != V.ciphertext_hash_input(u + b"", b"\x00")

@@ -24,7 +24,7 @@ def _run(tiny_bench, workload, seed, trace=False, entry=None, mode=None, **kw):
         kw["worker_entry"] = entry
         kw["worker_entry_args"] = [mode]
     rc = run_cell(
-        tiny_bench, workload, seed, 2.0, trace, t0=time.perf_counter(),
+        tiny_bench, workload, seed, 0.5, trace, t0=time.perf_counter(),
         root=DATA, out=out, err=err, **kw,
     )
     lines = out.getvalue().strip().splitlines()
@@ -65,23 +65,61 @@ def test_no_device_metric_without_a_tpu(tiny_bench):
     assert set(line["metrics"]) == {"rpc_overhead_ms", "worker_flush_ms"}
 
 
+def test_a_decrypt_cell_is_files_and_entries(tiny_bench):
+    """``hb4.decrypt`` is a configuration file, a traffic file and entries
+    of ``tiny_bench``: a ciphertext check and decryption shares, one of them
+    wrong, go through the harness, each judged by its own kind's verifier,
+    with no code of their own."""
+    rc, line, err = _run(tiny_bench, "hb4.decrypt", 2**31 + 29)
+    assert rc == 0, err
+    assert line["correct"] is True, line["compared"]
+    assert line["failed"] == 0
+    assert line["attempted"] == 3 * line["run"]["flushes"] > 0
+    # the window's answers, the warm-up's and the probe's
+    checked = line["compared"]["answers_checked_by_reference"]
+    assert checked["value"] == checked["at_least"] == line["attempted"] + 3 + 3
+    assert all(
+        entry["value"] == 0
+        for entry in line["compared"].values() if "limit" in entry
+    )
+
+
 @pytest.mark.parametrize(
     "workload,mode",
     [
         ("tiny.clean", "control"),  # the Miller loop cut short
         ("tiny.byz", "control"),
+        ("hb4.decrypt", "control"),
         ("tiny.clean", "flip"),     # an answer altered where it is produced
         ("tiny.byz", "flip"),
+        ("hb4.decrypt", "flip"),
         ("tiny.clean", "accept"),   # verification skipped: the probe shows it
         ("tiny.byz", "accept"),
+        ("hb4.decrypt", "accept"),
     ],
 )
 def test_a_broken_timed_path_is_not_correct(tiny_bench, workload, mode):
     rc, line, _ = _run(tiny_bench, workload, 11, entry=BROKEN_ENTRY, mode=mode)
     assert rc == 0
     assert line["correct"] is False
-    differing = (
-        line["compared"]["answers_differing_from_reference"]["value"]
-        + line["compared"]["answers_differing_from_construction"]["value"]
+    compared = {k: v["value"] for k, v in line["compared"].items()}
+    # the construction still agrees with the reference: the fault is the path's
+    assert compared["construction_differing_from_reference"] == 0
+    assert compared["requests_not_answered_by_chip_path"] == 0
+    assert compared["answers_differing_from_reference"] > 0
+    assert (
+        compared["answers_differing_from_construction"]
+        == compared["answers_differing_from_reference"]
     )
-    assert differing > 0
+    run = line["run"]
+    if mode == "accept":
+        # every wrong request of the set-up and the window was let through,
+        # and nothing else differs
+        wrong = {"tiny.clean": 1, "tiny.byz": 2, "hb4.decrypt": 2}  # the probe's
+        per_flush = {"tiny.byz": 1, "hb4.decrypt": 1}.get(workload, 0)
+        assert compared["answers_differing_from_reference"] == (
+            wrong[workload] + per_flush * (1 + run["flushes"])
+        )
+    if mode == "flip":
+        # one answer of every flush: the warm-up's, the probe's, the window's
+        assert compared["answers_differing_from_reference"] == 2 + run["flushes"]
