@@ -38,6 +38,7 @@ from __future__ import annotations
 import os
 import threading
 import warnings
+from collections import Counter
 from functools import lru_cache
 from typing import Any, Dict, List, Sequence, Tuple
 
@@ -57,24 +58,14 @@ from hbbft_tpu.crypto.backend import (
 )
 from hbbft_tpu.crypto.bls import curve as ocurve
 from hbbft_tpu.crypto.bls.suite import BLSSuite
+from hbbft_tpu.crypto.flush_shapes import pairs_bucket as _pairs_bucket
+from hbbft_tpu.crypto.flush_shapes import scan_shape as _scan_shape
 from hbbft_tpu.crypto.tpu import curve as dcurve
 from hbbft_tpu.crypto.tpu import pairing as dpairing
 from hbbft_tpu.utils import canonical_bytes
 from hbbft_tpu.utils.metrics import Metrics
 
 NBITS = 128  # RLC coefficient width
-
-
-def _bucket(n: int, floor: int = 16) -> int:
-    """Round up to a power of two (with a floor) to bound recompiles.
-
-    The floor matters for bisection: all small sub-batches pad to the
-    same shape and reuse one compiled kernel instead of compiling a
-    fresh kernel per subset size."""
-    b = floor
-    while b < n:
-        b *= 2
-    return b
 
 
 @lru_cache(maxsize=32)
@@ -200,24 +191,10 @@ def _compile_pair_kernel_early(n_pairs: int) -> None:
     thread.start()
 
 
-def _pairs_bucket(n: int) -> int:
-    """Pair-count bucket: exact for small counts, multiples of 8 above.
-
-    Small flushes (one chunk: 1 + n_legs = 3/5/9 pairs) keep their exact
-    size — on the 1-core virtual-CPU test platform every padded pair is
-    a real 63-step Miller loop per execution (CLAUDE.md: the floor-8
-    experiment made the suite strictly worse).  Multi-chunk combines pad
-    to a multiple of 8 so the compile count stays bounded; padded pairs
-    are identity pairs (factor 1 via the skip mask) and on TPU their
-    cost rides the already-batched lanes.
-    """
-    return n if n <= 9 else (n + 7) // 8 * 8
-
-
 def _shard_mesh(max_devices: int = 16):
     """Data-parallel mesh over the largest power-of-two device prefix.
 
-    Capped at the kernel's minimum batch bucket (floor 16 in ``_bucket``)
+    Capped at the kernel's minimum batch bucket (floor 16 in ``flush_shapes.bucket``)
     so the batch axis is always divisible by the mesh — a 32-way mesh
     over a 16-row bucket would make ``device_put`` raise on every small
     flush.
@@ -248,8 +225,9 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.pair_dispatch`` (``pairs``) and
     ``crypto.tpu.verdict_sync`` (the host blocked on the device).  The
     ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``; inside it
-    ``crypto.tpu.coefficients``, one ``crypto.tpu.hash_to_g2`` (``bytes``)
-    per call, ``crypto.tpu.pack``) before the dispatches is the check's
+    ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs`` with one
+    ``crypto.tpu.hash_to_g2`` (``bytes``) per call inside it,
+    ``crypto.tpu.pack``) before the dispatches is the check's
     own: the flush's, or that of a bisection level's first group.  One
     between ``pair_dispatch`` and ``verdict_sync`` is the NEXT group's of
     the level, prepared while the device runs this check (its ``rows``
@@ -261,10 +239,14 @@ class TpuBackend(CryptoBackend):
     one-row check is the verification equation itself; no oracle call).
     Counters: ``crypto.tpu.checks``, ``crypto.tpu.checks_failed``,
     ``crypto.tpu.rows`` (requests summed over checks),
-    ``crypto.tpu.rows_padded`` (bucket rows less real rows, G1 and G2
-    summed), ``crypto.tpu.hash_to_g2_calls``, ``crypto.tpu.leaves``,
-    ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
-    check's ``pair_dispatch`` and its ``verdict_sync``).
+    ``crypto.tpu.g1_rows`` and ``crypto.tpu.g2_rows`` (real rows of every
+    ``scan_prep``), ``crypto.tpu.rows_padded`` (bucket rows less real
+    rows, G1 and G2 summed), ``crypto.tpu.hash_to_g2_calls``,
+    ``crypto.tpu.leaves``, ``crypto.tpu.prepared_ahead`` (``scan_prep``s
+    that ran between a check's ``pair_dispatch`` and its
+    ``verdict_sync``), ``crypto.tpu.requests.<kind>`` (well-formed
+    requests that entered a flush, by kind; once a flush, not again in
+    its groups).
     A flush of several chunks dispatches every chunk's scan before any
     verdict, so there ``scan_prep`` and ``scan_dispatch`` lie beside the
     checks, not inside them: a check is then the combined pair stage and
@@ -400,19 +382,12 @@ class TpuBackend(CryptoBackend):
         with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
             with self.metrics.span("crypto.tpu.coefficients"):
                 coeffs = _batch_coefficients(self.suite, reqs)
-            g2e, g1e, rhs = self._build_legs(reqs, coeffs)
-            n1 = _bucket(max(len(g1e), 1))
-            n2 = _bucket(max(len(g2e), 1))
-            # Legs become pairing-product pairs (a Miller loop each, even
-            # when identity-padded), so keep their floor LOW: on the 1-core
-            # virtual-CPU test platform every padded leg costs real
-            # execution minutes across the suite (a floor-8 experiment
-            # tripled warm suite time).  The cost side — one ~7-min cold
-            # compile per distinct legs bucket (2/4/8 under bisection) — is
-            # paid once and covered by benchmarks/warm_crypto_cache.py +
-            # the persistent .jax_cache.
-            nl = _bucket(max(len(rhs), 1), floor=2)
+            with self.metrics.span("crypto.tpu.build_legs"):
+                g2e, g1e, rhs = self._build_legs(reqs, coeffs)
+            n1, n2, nl = _scan_shape(reqs, len(g1e), len(g2e), len(rhs))
             note(n1=n1, n2=n2, legs=nl)
+            self.metrics.count("crypto.tpu.g1_rows", len(g1e))
+            self.metrics.count("crypto.tpu.g2_rows", len(g2e))
             self.metrics.count(
                 "crypto.tpu.rows_padded", n1 - len(g1e) + n2 - len(g2e)
             )
@@ -557,6 +532,8 @@ class TpuBackend(CryptoBackend):
                 for i, r in enumerate(reqs)
                 if request_well_formed(self.suite, r, subgroup=False)
             ]
+        for kind, n in Counter(reqs[i].kind for i in idxs).items():
+            self.metrics.count("crypto.tpu.requests." + kind, n)
         chunks = [idxs[s : s + self.CHUNK] for s in range(0, len(idxs), self.CHUNK)]
         if not chunks:
             return out

@@ -9,6 +9,7 @@ verdict is the (stub) device's.
 """
 
 import json
+import os
 import random
 import subprocess
 import sys
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from hbbft_tpu.crypto.backend import VerifyRequest
+from hbbft_tpu.crypto import flush_shapes
 from hbbft_tpu.crypto.bls.suite import BLSSuite
 from hbbft_tpu.crypto.keys import SecretKeySet
 from hbbft_tpu.crypto.suite import ScalarSuite
@@ -34,6 +36,8 @@ from hbbft_tpu.cryptoplane.proc_service import (
 )
 from hbbft_tpu.cryptoplane.service import CryptoPlaneService
 from hbbft_tpu.utils.metrics import Metrics
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class Session:
@@ -147,11 +151,14 @@ def _index(req):
     return req.payload[0].to_bytes()
 
 
-def stub_kernels(monkeypatch, verdict):
+def stub_kernels(monkeypatch, verdict, launched=None):
     """Replace the two programs; ``verdict(reqs)`` answers the pair stage
     for the requests of the most recent ``_scan_prep`` before its dispatch.
-    Returns the list that every prepared group is appended to."""
+    Returns the list that every prepared group is appended to; ``launched``
+    takes the program each call asks for: ``("scan", n1, n2, legs)``,
+    ``("pair", pairs)``."""
     prepared = []
+    launched = [] if launched is None else launched
     honest_prep = B.TpuBackend._scan_prep
 
     def scan_prep(self, reqs):
@@ -159,9 +166,11 @@ def stub_kernels(monkeypatch, verdict):
         return honest_prep(self, reqs)
 
     def fake_pair_kernel(n_pairs):
+        launched.append(("pair", n_pairs))
         return lambda lhs, rhs: jnp.asarray(verdict(prepared[-1]))
 
     def fake_scan_kernel(n1, n2, nl):
+        launched.append(("scan", n1, n2, nl))
         return lambda *args: (
             jnp.asarray(True),
             dc.identity(dc.G1_OPS, (1 + nl,)),
@@ -240,14 +249,19 @@ def test_counters_equal_the_spans_they_sit_beside(flushed):
     assert counters["crypto.tpu.prepared_ahead"] == script["ahead"]
     assert counters["crypto.tpu.rows"] == script["rows"]
     assert counters["crypto.tpu.rows_padded"] == script["padded"]
+    # a signature share is one G1 row (its key share) and one G2 row
+    assert counters["crypto.tpu.g1_rows"] == counters["crypto.tpu.g2_rows"] == script["rows"]
+    assert {k: v for k, v in counters.items() if ".requests." in k} == {
+        "crypto.tpu.requests.sig_share": script["n"]
+    }
     assert (
         names["crypto.tpu.hash_to_g2"]
         == counters["crypto.tpu.hash_to_g2_calls"]
         == script["rows"]
     )
     # every check is one of each of its stages
-    for stage in ("scan_prep", "coefficients", "pack", "scan_dispatch",
-                  "pair_dispatch", "verdict_sync"):
+    for stage in ("scan_prep", "coefficients", "build_legs", "pack",
+                  "scan_dispatch", "pair_dispatch", "verdict_sync"):
         assert names["crypto.tpu." + stage] == script["checks"], stage
     # and the timers the ``stats`` op exports count the same
     for name, n in names.items():
@@ -303,7 +317,8 @@ def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
     for parent, children in [
         ("crypto.tpu.check", ["scan_prep", "scan_dispatch", "pair_dispatch",
                               "verdict_sync"]),
-        ("crypto.tpu.scan_prep", ["coefficients", "hash_to_g2", "pack"]),
+        ("crypto.tpu.scan_prep", ["coefficients", "build_legs", "pack"]),
+        ("crypto.tpu.build_legs", ["hash_to_g2"]),
     ]:
         for child in children:
             for _, _, s, e, _ in by_name["crypto.tpu." + child]:
@@ -382,6 +397,169 @@ def test_bisection_answers_the_wrong_set_with_the_recursions_checks(
     assert counters["crypto.tpu.leaves"] == len(wrong)
     levels = {depth for depth, _ in want[1:]}
     assert counters["crypto.tpu.prepared_ahead"] == len(want) - 1 - len(levels)
+
+
+# -- the shapes a flush and its groups ask for ---------------------------------
+
+@pytest.fixture(scope="module")
+def decrypt_requests(requests):
+    """One ciphertext's decrypt phase at N = 16: the ciphertext check, then
+    the 15 other validators' decryption shares on it."""
+    suite, _ = requests
+    rng = random.Random(30)
+    sks = SecretKeySet.random(1, rng, suite)
+    pks = sks.public_keys()
+    ct = pks.public_key().encrypt(bytes(range(250)), rng)
+    return [VerifyRequest.ciphertext(ct)] + [
+        VerifyRequest.dec_share(
+            pks.public_key_share(i), ct, sks.secret_key_share(i).decryption_share(ct)
+        )
+        for i in range(15)
+    ]
+
+
+def _seeded(n, k, seed=30):
+    return tuple(sorted(random.Random(seed).sample(range(n), k)))
+
+
+# name -> (kind, requests, wrong positions).  ``dec``: that many shares on one
+# ciphertext; ``check+dec``: the ciphertext check, then that many shares.
+SHAPE_CASES = {
+    "dec_1": ("dec", 1, ()),
+    "dec_8": ("dec", 8, ()),
+    "dec_15": ("dec", 15, ()),
+    "check_and_15": ("check+dec", 15, ()),
+    "dec_1_wrong": ("dec", 1, (0,)),
+    "dec_8_bisected": ("dec", 8, _seeded(8, 2)),
+    "dec_15_bisected": ("dec", 15, _seeded(15, 3)),
+    "check_and_15_bisected": ("check+dec", 15, (0,) + tuple(1 + i for i in _seeded(15, 2))),
+    "sig_2": ("sig", 2, ()),
+    "sig_16": ("sig", 16, ()),
+    "sig_16_byz5": ("sig", 16, BYZ5),
+    "sig_40": ("sig", 40, ()),
+    "sig_40_bisected": ("sig", 40, _seeded(40, 2)),
+}
+# ``crypto.tpu.rows_padded`` of the coin's flushes, as before the floor
+# counted requests
+PADDED = {
+    "sig_2": SCRIPTS["whole"]["padded"], "sig_16": 0,
+    "sig_16_byz5": SCRIPTS["byz5_of_16"]["padded"],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHAPE_CASES))
+def test_a_flush_and_every_group_of_it_ask_for_one_scan_program(
+    requests, decrypt_requests, monkeypatch, case
+):
+    """A decrypt burst of up to 16 requests and every group bisection makes
+    of it, down to a lone share or the lone check: ``scan(32,16,2)`` and
+    ``pair(3)`` and nothing else.  The coin's flushes keep the programs and
+    the padding they had; a flush above the floor buckets as before."""
+    kind, n, wrong = SHAPE_CASES[case]
+    suite, sig_reqs = requests
+    if kind == "sig":
+        reqs = [sig_reqs[i % 16] for i in range(n)]
+    else:
+        reqs = decrypt_requests[0 if kind == "check+dec" else 1:][: n + (kind == "check+dec")]
+    # 40 shares are of 16 signers: an object each, to tell them by position
+    reqs = [VerifyRequest(r.kind, r.payload) for r in reqs]
+    bad = {id(reqs[i]) for i in wrong}
+    launched = []
+    prepared = stub_kernels(
+        monkeypatch, lambda group: not bad & {id(r) for r in group}, launched
+    )
+    backend = stubbed_backend(suite)
+    assert backend.verify_batch(reqs) == [i not in wrong for i in range(len(reqs))]
+    scans = [shape[1:] for shape in launched if shape[0] == "scan"]
+    assert len(scans) == len(prepared) == backend.metrics.counters["crypto.tpu.checks"]
+    assert {shape for shape in launched if shape[0] == "pair"} == {("pair", 3)}
+    if kind == "sig":
+        # one G1 and one G2 row a share: the bucket with the floor of 16 rows
+        want = [(flush_shapes.bucket(len(g)),) * 2 + (2,) for g in prepared]
+        if n <= 16:
+            assert set(want) == {(16, 16, 2)}
+            assert backend.metrics.counters["crypto.tpu.rows_padded"] == PADDED[case]
+        else:
+            assert want[0] == (64, 64, 2)
+            assert all(w == (16, 16, 2) for w, g in zip(want, prepared) if len(g) <= 16)
+    else:
+        want = [(32, 16, 2)] * len(prepared)
+    assert scans == want
+    if len(wrong) and n > 1:
+        # bisection went down to groups of one, the lone check among them
+        lone = [g[0].kind for g in prepared if len(g) == 1]
+        assert len(lone) >= len(wrong)
+        assert ("ciphertext" in lone) == (kind == "check+dec")
+
+
+def test_the_floor_is_read_off_the_requests_and_nothing_else(decrypt_requests, requests):
+    """No option, environment variable or argument steers the shape."""
+    import inspect
+
+    assert list(inspect.signature(B.TpuBackend.__init__).parameters) == [
+        "self", "suite", "shard", "metrics",
+    ]
+    assert list(inspect.signature(B.TpuBackend._scan_prep).parameters) == ["self", "reqs"]
+    assert list(inspect.signature(flush_shapes.scan_shape).parameters) == [
+        "reqs", "g1_rows", "g2_rows", "legs",
+    ]
+    assert B._scan_shape is flush_shapes.scan_shape
+    assert B._pairs_bucket is flush_shapes.pairs_bucket
+    assert not hasattr(B, "_bucket")  # the buckets are the shape module's alone
+    _, sig_reqs = requests
+    check, share = decrypt_requests[:2]
+    g1_floor = flush_shapes.g1_floor
+    assert g1_floor(sig_reqs) == g1_floor(sig_reqs[:1]) == 16
+    assert g1_floor([share]) == g1_floor([check]) == 32
+    assert g1_floor(decrypt_requests) == g1_floor(sig_reqs + [share]) == 32
+    # the same rows land in another program by the requests' kinds alone
+    assert flush_shapes.scan_shape([share], 2, 0, 2) == (32, 16, 2)
+    assert flush_shapes.scan_shape(sig_reqs[:2], 2, 2, 1) == (16, 16, 2)
+    # and importing the rule brings no jax with it
+    code = "import sys, hbbft_tpu.crypto.flush_shapes; sys.exit('jax' in sys.modules)"
+    assert subprocess.run([sys.executable, "-c", code], cwd=ROOT).returncode == 0
+
+
+def test_the_row_and_request_counters_of_a_decrypt_flush(decrypt_requests, requests, monkeypatch):
+    """``g1_rows``, ``g2_rows`` and ``rows_padded`` are what the groups'
+    requests bring by kind, ``requests.<kind>`` what entered the flush:
+    once, not again in its groups, and not what the filter refused."""
+    suite, _ = requests
+    share = decrypt_requests[1]
+    malformed = VerifyRequest.dec_share(share.payload[0], share.payload[1], "junk")
+    reqs = decrypt_requests + [malformed]
+    wrong = (0, 5, 11)
+    bad = {id(reqs[i]) for i in wrong}
+    prepared = stub_kernels(monkeypatch, lambda group: not bad & {id(r) for r in group})
+    metrics = Metrics()
+    backend = stubbed_backend(suite, metrics)
+    session = Session()
+    try:
+        got = backend.verify_batch(reqs)
+    finally:
+        spans = session.spans()
+    assert got == [i not in wrong for i in range(16)] + [False]
+    counters = metrics.counters
+    assert {k: v for k, v in counters.items() if ".requests." in k} == {
+        "crypto.tpu.requests.ciphertext": 1, "crypto.tpu.requests.dec_share": 15,
+    }
+    by_kind = [Counter(r.kind for r in group) for group in prepared]
+    g1 = [2 * c["dec_share"] + c["ciphertext"] for c in by_kind]
+    g2 = [c["ciphertext"] for c in by_kind]
+    assert counters["crypto.tpu.g1_rows"] == sum(g1)
+    assert counters["crypto.tpu.g2_rows"] == sum(g2) > 1
+    assert counters["crypto.tpu.rows_padded"] == sum(32 - a + 16 - b for a, b in zip(g1, g2))
+    preps = [a for _, name, _, _, a in spans if name == "crypto.tpu.scan_prep"]
+    assert [a["rows"] for a in preps] == [len(group) for group in prepared]
+    assert {(a["n1"], a["n2"], a["legs"]) for a in preps} == {(32, 16, 2)}
+    assert counters["crypto.tpu.rows"] == sum(a["rows"] for a in preps)
+    legs = [(s, e) for _, name, s, e, _ in spans if name == "crypto.tpu.build_legs"]
+    assert len(legs) == len(preps) == counters["crypto.tpu.checks"]
+    hashes = [(s, e) for _, name, s, e, _ in spans if name == "crypto.tpu.hash_to_g2"]
+    assert len(hashes) == counters["crypto.tpu.hash_to_g2_calls"] == sum(
+        c["dec_share"] + c["ciphertext"] for c in by_kind
+    )
+    assert all(any(ls <= s and e <= le for ls, le in legs) for s, e in hashes)
 
 
 def test_stats_op_of_an_eager_worker_keeps_its_shape():

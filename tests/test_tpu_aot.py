@@ -140,22 +140,51 @@ def _sig_share_reqs(n):
     ]
 
 
+def _dec_share_reqs(n):
+    """``n`` decryption shares of distinct signers on one ciphertext with a
+    proposal of 4000 bytes: the flush of the benchmark's ``hb16.decrypt``."""
+    from hbbft_tpu.crypto.backend import VerifyRequest
+    from hbbft_tpu.crypto.bls.suite import BLSSuite
+    from hbbft_tpu.crypto.keys import SecretKeySet
+
+    suite = BLSSuite()
+    rng = random.Random(7)
+    sks = SecretKeySet.random(2, rng, suite)
+    pks = sks.public_keys()
+    ct = pks.public_key().encrypt(rng.randbytes(4000), rng)
+    return suite, [
+        VerifyRequest.dec_share(
+            pks.public_key_share(i), ct, sks.secret_key_share(i).decryption_share(ct)
+        )
+        for i in range(n)
+    ]
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("n_shares", [16, ROWS])
-def test_flush_programs_compile_for_v5e(n_shares, one_chip, cache_off):
-    """The three programs of chip_smoke.py: ``_scan_kernel(16,16,2)``,
-    ``_scan_kernel(2048,2048,2)`` and (once) ``_pair_kernel(3)``.
+@pytest.mark.parametrize(
+    "make,n_requests,shape",
+    [
+        (_sig_share_reqs, 16, (16, 16, 2)),
+        (_sig_share_reqs, ROWS, (ROWS, ROWS, 2)),
+        (_dec_share_reqs, 15, (32, 16, 2)),
+    ],
+    ids=["sig_share_16", f"sig_share_{ROWS}", "dec_share_15"],
+)
+def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache_off):
+    """The programs of chip_smoke.py and of the benchmark's cells:
+    ``_scan_kernel(16,16,2)``, ``_scan_kernel(2048,2048,2)``, the decrypt
+    burst's ``_scan_kernel(32,16,2)`` and (once) ``_pair_kernel(3)``.
     Prints seconds and ``memory_analysis()`` for each (run with ``-s``);
     with ``JAX_ENABLE_X64=0`` it compiles what the worker compiles."""
     from hbbft_tpu.crypto.tpu import backend as B
 
-    suite, reqs = _sig_share_reqs(n_shares)
+    suite, reqs = make(n_requests)
     (n1, n2, nl), args = B.TpuBackend(suite)._scan_prep(reqs)
-    assert (n1, n2, nl) == (n_shares, n_shares, 2)
+    assert (n1, n2, nl) == shape
     programs = [
         (f"hbbft_scan_{n1}_{n2}_{nl}", B._scan_kernel(n1, n2, nl), args)
     ]
-    if n_shares == 16:
+    if shape == (16, 16, 2):
         _, lhs, rhs = jax.eval_shape(B._scan_kernel(n1, n2, nl), *args)
         n_pairs = int(lhs[3].shape[0])
         assert n_pairs == 3
