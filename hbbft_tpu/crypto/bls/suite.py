@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import functools
+import threading
 from typing import Any, Optional, Sequence, Tuple
 
 from hbbft_tpu.crypto.bls import curve as C
@@ -15,6 +17,14 @@ class _PointElem:
 
     Wraps a Jacobian point; affine form (for serialization/equality) is
     computed lazily and cached.
+
+    An element may be shared: the decode memo (``_decode_validated``)
+    hands the same object to every request that carries the same bytes.
+    That is sound because ``jac`` is a tuple that nothing assigns after
+    ``__init__``, and ``_affine``, ``_bytes`` and ``_subgroup_ok`` are
+    memos of values that ``jac`` determines (``batch_affine`` only fills
+    ``_affine``); pickling drops all three.  Keep it so: no method may
+    change the point an element stands for.
     """
 
     __slots__ = ("jac", "_affine", "_bytes", "_subgroup_ok")
@@ -176,17 +186,17 @@ class BLSSuite(Suite):
         """Decode the 97-byte affine encoding; full membership validation
         (coordinate range, on-curve, r-torsion) — decoded elements come
         from committed-but-attacker-authored bytes and go straight into
-        pairing checks, so the wire policy of :meth:`is_g1` applies."""
-        elem = G1Elem(_jac_from_bytes(data, fq2=False))
-        if not self.is_g1(elem):
-            raise ValueError("not a valid G1 element")
-        return elem
+        pairing checks, so the wire policy of :meth:`is_g1` applies.
+        Validation is a function of the bytes alone, so it is made once
+        per distinct encoding (``_decode_validated``): a later decode of
+        the same bytes returns the element that passed."""
+        return _decode_point(data, fq2=False)
 
     def g2_from_bytes(self, data: bytes) -> G2Elem:
-        elem = G2Elem(_jac_from_bytes(data, fq2=True))
-        if not self.is_g2(elem):
-            raise ValueError("not a valid G2 element")
-        return elem
+        return _decode_point(data, fq2=True)
+
+    def decode_tally(self) -> Tuple[int, int]:
+        return _tally.points, _tally.points - _tally.misses
 
     def hash_to_g2(self, data: bytes) -> G2Elem:
         return G2Elem(C.hash_to_g2(bytes(data)))
@@ -241,6 +251,51 @@ class BLSSuite(Suite):
                     ops.mul(x, zi2),
                     ops.mul(y, ops.mul(zi2, z_inv)),
                 )
+
+
+# The decode memo's bound.  An N = 104 epoch's key shares (104 G1), its
+# ciphertexts (104 U and 104 W) and a few flushes of fresh shares (104
+# each) are well under 1000 entries; 4096 leaves fresh shares, which enter
+# and are never asked for again, several flushes before they push out a
+# key share, which every flush touches and least-recently-used therefore
+# keeps.  An element is roughly 1 KB (six 381-bit ints, the bytes, the
+# wrapper), so the memo stays under 5 MB.
+_DECODE_MEMO_SIZE = 4096
+
+
+class _DecodeTally(threading.local):
+    """This thread's decodes: all, and those that had to validate."""
+
+    points = 0
+    misses = 0
+
+
+_tally = _DecodeTally()
+
+
+def _decode_point(data: Any, fq2: bool) -> Any:
+    _tally.points += 1
+    if not isinstance(data, bytes):  # unhashable input must not reach the memo
+        raise ValueError("bad point encoding")
+    return _decode_validated(fq2, data)
+
+
+@functools.lru_cache(maxsize=_DECODE_MEMO_SIZE)
+def _decode_validated(fq2: bool, data: bytes) -> _PointElem:
+    """The element these bytes encode, after the full membership check.
+    Process-wide, bounded, least-recently-used; bytes that fail raise
+    every time and are never stored (``lru_cache`` keeps no exception)."""
+    _tally.misses += 1
+    jac = _jac_from_bytes(data, fq2)  # coordinates in range: is_g1's first test
+    if fq2:
+        elem, ops, on_curve = G2Elem(jac), C.FQ2_OPS, C.g2_on_curve_jac
+    else:
+        elem, ops, on_curve = G1Elem(jac), C.FQ_OPS, C.g1_on_curve_jac
+    if not _on_curve_and_torsion(ops, elem, on_curve, True):
+        raise ValueError(f"not a valid G{2 if fq2 else 1} element")
+    # canonical by _jac_from_bytes' checks: to_bytes() need not convert
+    elem._bytes = data
+    return elem
 
 
 def _jac_from_bytes(data: Any, fq2: bool) -> C.Jac:

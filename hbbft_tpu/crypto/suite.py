@@ -96,6 +96,15 @@ class Suite(abc.ABC):
     def g2_from_bytes(self, data: bytes) -> Any:
         """Decode (and fully validate) a wire-sourced G2 element."""
 
+    def decode_tally(self) -> Tuple[int, int]:
+        """``(points, hits)`` of the calling thread's ``g1_from_bytes`` /
+        ``g2_from_bytes`` calls so far: how many it made, and how many
+        were answered by a memo of bytes already validated.  One decode's
+        share is the difference across it (the crypto-plane RPC server
+        takes it around a frame's ``serde.loads``).  A suite that keeps
+        no memo counts none."""
+        return 0, 0
+
     # -- pairing ------------------------------------------------------
     @abc.abstractmethod
     def pairing_product_is_one(self, pairs: Sequence[Tuple[Any, Any]]) -> bool:

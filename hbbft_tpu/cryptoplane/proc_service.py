@@ -381,19 +381,28 @@ class CryptoRpcServer:
         carried the job lists.  The id and the op are read from the
         payload, so the two spans that are open by then take them as
         notes, and ``stats`` requests are spanned like ``verify`` ones
-        (``op`` tells them apart)."""
+        (``op`` tells them apart).  ``decode`` also notes the frame's
+        group elements and how many of them the suite's memo of validated
+        bytes answered (``points``, ``hits``: this thread's tally across
+        ``serde.loads``, so connections do not mix), and the counters
+        ``crypto.rpc.decode_points`` / ``decode_point_hits`` sum them."""
         span = self.metrics.span
         with span("crypto.rpc.serve", bytes=len(payload)) as note_serve:
             with span("crypto.rpc.decode", bytes=len(payload)) as note:
+                points0, hits0 = self.suite.decode_tally()
                 # DecodeError -> drop the connection
                 obj = serde.loads(payload, suite=self.suite)
+                points1, hits1 = self.suite.decode_tally()
+                points, hits = points1 - points0, hits1 - hits0
                 if not isinstance(obj, tuple) or len(obj) != 3:
                     raise FrameError("malformed crypto REQ")
                 req_id, op, body = obj
                 if type(req_id) is not int or type(op) is not str:
                     raise FrameError("malformed crypto REQ header")
                 rpc = f"{cid}:{req_id}"
-                note(span=rpc)
+                note(span=rpc, points=points, hits=hits)
+            self.metrics.count("crypto.rpc.decode_points", points)
+            self.metrics.count("crypto.rpc.decode_point_hits", hits)
             if op == "stats":
                 rest: tuple = (self._stats_json(),)
                 note_serve(span=rpc, op=op)

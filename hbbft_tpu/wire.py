@@ -45,17 +45,24 @@ registry; suites validate structure/on-curve/subgroup in
 ``g1_from_bytes``/``g2_from_bytes``.
 
 Subgroup-check policy (CLAUDE.md invariant: wire-sourced points MUST get
-subgroup checks somewhere): decode does the FULL check, even though the
-threshold-decrypt path's verify backend re-checks, because the same
-``Ciphertext`` type also reaches ``SecretKey.decrypt`` (DKG rows), where
-``ct.u`` is multiplied by a long-term secret with no backend pass — a
-torsion component there is the classic invalid-point key-leak.  Cost
-context: serde decode handles O(N) committed payloads per epoch; the
-O(N^2) share-verification hot loop never crosses this codec (shares are
-in-process message objects), so this does not reintroduce round 1's
-host-side flush bottleneck.  If decode ever shows up in profiles, the
-fast x-based membership tests (Scott 2021: phi/psi endomorphism checks)
-cut the torsion cost ~2-4x before any batching is needed.
+subgroup checks somewhere): decode does the FULL check (range, on-curve,
+r-torsion), even though the threshold-decrypt path's verify backend
+re-checks, because the same ``Ciphertext`` type also reaches
+``SecretKey.decrypt`` (DKG rows), where ``ct.u`` is multiplied by a
+long-term secret with no backend pass — a torsion component there is the
+classic invalid-point key-leak.  Cost, as it stands: this codec's main
+user is the crypto-plane RPC (``vreq`` below), whose server decodes every
+request of every flush, so the share-verification hot loop DOES cross it:
+a ``dec_share`` request carries its whole ciphertext, a burst of 15 names
+the same ``U`` and ``W`` 15 times, and every flush names the era's key
+shares again.  The torsion test is already the endomorphism one (Scott
+2021; ``suite._on_curve_and_torsion``), about 1 ms a G1 and 1.4 ms a G2
+point in Python integers.  So the suite makes the full check once per
+distinct bytes: ``g1_from_bytes``/``g2_from_bytes`` remember the element
+that passed, by (group, bytes), in one bounded least-recently-used memo
+(``crypto/bls/suite.py::_decode_validated``); bytes that fail raise on
+every decode and are never stored.  A decoded element may therefore be
+shared between requests; elements are immutable in value.
 """
 
 from __future__ import annotations

@@ -2,7 +2,9 @@
 threshold scheme + protocols running over the real curve (small N).
 """
 
+import functools
 import random
+import threading
 
 import pytest
 
@@ -10,6 +12,7 @@ from hbbft_tpu.crypto.backend import BatchedBackend, EagerBackend, VerifyRequest
 from hbbft_tpu.crypto.bls import BLSSuite
 from hbbft_tpu.crypto.bls import curve as C
 from hbbft_tpu.crypto.bls import fields as F
+from hbbft_tpu.crypto.bls import suite as bls_suite
 from hbbft_tpu.crypto.keys import SecretKeySet
 from hbbft_tpu.net import NetBuilder
 from hbbft_tpu.protocols.threshold_sign import ThresholdSign
@@ -249,3 +252,144 @@ def test_endo_matches_suite_membership(suite):
     assert not suite.is_g2(bad)
     good = suite.g2_generator() * rng.randrange(1, F.R)
     assert suite.is_g2(good)
+
+
+# -- the decode memo: a point's bytes are validated once ----------------------
+
+GROUPS = ("g1", "g2")
+
+
+def _decoder(suite, group):
+    return suite.g1_from_bytes if group == "g1" else suite.g2_from_bytes
+
+
+def _valid_bytes(suite, group, k):
+    gen = suite.g1_generator() if group == "g1" else suite.g2_generator()
+    return (gen * k).to_bytes()
+
+
+def _encode(group, aff):
+    cls = bls_suite.G1Elem if group == "g1" else bls_suite.G2Elem
+    return cls((aff[0], aff[1], cls.ops.one)).to_bytes()
+
+
+def _off_torsion(suite, group):
+    """A valid point plus a point of the cofactor's torsion: on the curve,
+    outside the r-torsion (chipbench/tests/test_reference_anchor.py builds
+    the G1 one the same way)."""
+    if group == "g1":
+        ops, gen, sample = C.FQ_OPS, C.G1_GEN, _sample_e_fq(random.Random(17))
+    else:
+        ops, gen, sample = C.FQ2_OPS, C.G2_GEN, C._twist_sample_point()
+    torsion = C.jac_mul(ops, sample, F.R)
+    assert not C.jac_is_identity(ops, torsion)
+    stray = C.jac_add(ops, C.jac_mul(ops, gen, 31337), torsion)
+    assert (C.g1_on_curve_jac if group == "g1" else C.g2_on_curve_jac)(stray)
+    return _encode(group, C.jac_to_affine(ops, stray))
+
+
+def _bad_bytes(suite, group, kind):
+    good = bytearray(_valid_bytes(suite, group, 424242))
+    if kind == "off_curve":
+        good[-1] ^= 1  # y's lowest bit
+        return bytes(good)
+    if kind == "off_torsion":
+        return _off_torsion(suite, group)
+    if kind == "noncanonical_identity":
+        return b"\x00" + b"\x01" * (len(good) - 1)
+    assert kind == "out_of_range"
+    good[1:49] = F.P.to_bytes(48, "big")  # x = p: not a residue's name
+    return bytes(good)
+
+
+def _points_and_hits(suite, before=(0, 0)):
+    points, hits = suite.decode_tally()
+    return points - before[0], hits - before[1]
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_decode_memo_second_decode_is_a_hit(suite, group):
+    memo = bls_suite._decode_validated
+    memo.cache_clear()
+    decode = _decoder(suite, group)
+    data = _valid_bytes(suite, group, 99991)
+    t0 = suite.decode_tally()
+    first = decode(data)
+    assert _points_and_hits(suite, t0) == (1, 0)
+    assert memo.cache_info().currsize == 1
+    again = decode(bytes(bytearray(data)))  # equal bytes, another object
+    assert _points_and_hits(suite, t0) == (2, 1)
+    assert again is first and again == first
+    assert first._subgroup_ok and first.to_bytes() == data
+    # the other group's memo entries are its own: equal-looking input of
+    # the wrong length is refused, not answered from this entry
+    with pytest.raises(ValueError):
+        _decoder(suite, "g2" if group == "g1" else "g1")(data)
+    # a tally is the thread's own
+    seen = []
+    t = threading.Thread(target=lambda: seen.append(suite.decode_tally()))
+    t.start()
+    t.join()
+    assert seen == [(0, 0)]
+
+
+@pytest.mark.parametrize(
+    "kind", ["off_curve", "off_torsion", "noncanonical_identity", "out_of_range"]
+)
+@pytest.mark.parametrize("group", GROUPS)
+def test_decode_memo_refuses_bad_bytes_every_time(suite, group, kind):
+    memo = bls_suite._decode_validated
+    decode = _decoder(suite, group)
+    decode(_valid_bytes(suite, group, 5))  # a valid neighbour is cached
+    size = memo.cache_info().currsize
+    bad = _bad_bytes(suite, group, kind)
+    t0 = suite.decode_tally()
+    for _ in range(3):
+        with pytest.raises(ValueError):
+            decode(bad)
+    assert _points_and_hits(suite, t0) == (3, 0)
+    assert memo.cache_info().currsize == size
+    assert decode(_valid_bytes(suite, group, 5)) == (
+        suite.g1_generator() if group == "g1" else suite.g2_generator()
+    ) * 5
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_decode_refuses_what_is_not_bytes(suite, group):
+    data = _valid_bytes(suite, group, 3)
+    for junk in (bytearray(data), memoryview(data), data.hex(), None, 7):
+        with pytest.raises(ValueError):
+            _decoder(suite, group)(junk)
+
+
+@pytest.mark.parametrize("group", GROUPS)
+def test_decode_memo_is_bounded_and_keeps_what_is_touched(
+    suite, group, monkeypatch
+):
+    """Least-recently-used under a bound: a key share that every flush
+    touches outlives floods of fresh points that are never asked for
+    again.  The mechanism is held at a bound of 8; the bound that ships
+    is read off the memo itself."""
+    memo = bls_suite._decode_validated
+    assert memo.cache_info().maxsize == bls_suite._DECODE_MEMO_SIZE == 4096
+    small = functools.lru_cache(maxsize=8)(memo.__wrapped__)
+    monkeypatch.setattr(bls_suite, "_decode_validated", small)
+    decode = _decoder(suite, group)
+    key = _valid_bytes(suite, group, 1009)
+    decode(key)
+    fresh = iter(range(2000, 2100))
+    for flood in range(3):
+        for _ in range(7):
+            decode(_valid_bytes(suite, group, next(fresh)))
+            assert small.cache_info().currsize <= 8
+        t0 = suite.decode_tally()
+        decode(key)
+        assert _points_and_hits(suite, t0) == (1, 1), f"flood {flood}"
+    assert small.cache_info().currsize == 8
+    # untouched through a flood of the bound's size, it goes
+    for _ in range(8):
+        decode(_valid_bytes(suite, group, next(fresh)))
+    t0 = suite.decode_tally()
+    decode(key)
+    assert _points_and_hits(suite, t0) == (1, 0)
+    assert small.cache_info().currsize == 8

@@ -186,6 +186,55 @@ def test_stats_rpc_and_parse_addr():
             parse_addr(bad_spec)
 
 
+def _bls_fixture():
+    from hbbft_tpu.crypto.bls import BLSSuite
+
+    suite = BLSSuite()
+    rng = random.Random(6)
+    sks = SecretKeySet.random(1, rng, suite)
+    pks = sks.public_keys()
+    reqs = [
+        VerifyRequest.sig_share(
+            pks.public_key_share(i), b"doc", sks.secret_key_share(i).sign(b"doc")
+        )
+        for i in range(2)
+    ]
+    return suite, reqs
+
+
+@pytest.mark.parametrize("suite_name", ["scalar", "bls"])
+def test_stats_op_carries_decode_point_counters(suite_name):
+    """``crypto.rpc.decode_points`` / ``decode_point_hits``: the frames'
+    group elements and how many the suite's memo of validated bytes
+    answered.  The scalar suite keeps no memo and counts none."""
+    if suite_name == "scalar":
+        suite, good, _ = _scalar_fixture()
+        reqs, want = [good, good], (0, 0)
+    else:
+        from hbbft_tpu.crypto.bls import suite as bls_suite
+
+        bls_suite._decode_validated.cache_clear()
+        suite, reqs = _bls_fixture()
+        # two calls of 2 keys + 2 shares: all new, then all seen
+        want = (8, 4)
+    server = _server(suite)
+    try:
+        addr = (server.host, server.port)
+        cli = RpcServiceClient(addr, suite, BatchedBackend(suite))
+        assert cli.verify_batch(reqs) == [True, True]
+        assert cli.verify_batch(reqs) == [True, True]
+        assert cli.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0
+        counters = fetch_stats(addr, suite)["counters"]
+        assert (
+            counters["crypto.rpc.decode_points"],
+            counters["crypto.rpc.decode_point_hits"],
+        ) == want
+        assert counters["crypto.rpc.served_requests"] == 4
+        assert server.metrics.timers["crypto.rpc.decode"].count == 3
+    finally:
+        server.stop()
+
+
 # ---------------------------------------------------------------------------
 # framing fuzz: garbage must kill connections, never the plane
 # ---------------------------------------------------------------------------
@@ -205,13 +254,36 @@ def _poisoned(sock: socket.socket) -> bool:
         return True
 
 
+def _dial_past_hello(server, suite) -> socket.socket:
+    """A raw connection on which the HELLO exchange is done."""
+    s = _dial_raw(server)
+    s.sendall(
+        encode_frame(
+            KIND_CRYPTO_HELLO, serde.dumps((1, suite.name)), kinds=CRYPTO_KINDS
+        )
+    )
+    dec = FrameDecoder(kinds=CRYPTO_KINDS)
+    while dec.next_frame() is None:
+        dec.feed(s.recv(4096))
+    return s
+
+
+def _await_bad_frames(server, n: int) -> int:
+    deadline = time.monotonic() + 5
+    while (
+        server.metrics.counters.get("crypto.rpc.bad_frames", 0) < n
+        and time.monotonic() < deadline
+    ):
+        time.sleep(0.01)
+    return server.metrics.counters["crypto.rpc.bad_frames"]
+
+
 def test_server_survives_corrupt_frames():
     """Each corruption mode kills ITS connection; the listener and the
     service live on, and a well-behaved client still verifies."""
     suite, good, _ = _scalar_fixture()
     server = _server(suite)
     try:
-        hello = serde.dumps((1, suite.name))
         attacks = []
 
         # raw garbage (fails CRC / length slicing)
@@ -227,11 +299,7 @@ def test_server_survives_corrupt_frames():
         s.sendall((1 << 30).to_bytes(4, "big") + b"\x00" * 16)
         attacks.append(s)
         # valid HELLO then a REQ whose payload is not serde
-        s = _dial_raw(server)
-        s.sendall(encode_frame(KIND_CRYPTO_HELLO, hello, kinds=CRYPTO_KINDS))
-        dec = FrameDecoder(kinds=CRYPTO_KINDS)
-        while dec.next_frame() is None:
-            dec.feed(s.recv(4096))
+        s = _dial_past_hello(server, suite)
         s.sendall(
             encode_frame(KIND_CRYPTO_REQ, b"\x99not-serde",
                          kinds=CRYPTO_KINDS)
@@ -254,18 +322,49 @@ def test_server_survives_corrupt_frames():
         for s in attacks:
             assert _poisoned(s)
             s.close()
-        deadline = time.monotonic() + 5
-        while (
-            server.metrics.counters.get("crypto.rpc.bad_frames", 0) < 4
-            and time.monotonic() < deadline
-        ):
-            time.sleep(0.01)
-        assert server.metrics.counters["crypto.rpc.bad_frames"] >= 4
+        assert _await_bad_frames(server, 4) >= 4
 
         cli = RpcServiceClient(
             (server.host, server.port), suite, BatchedBackend(suite)
         )
         assert cli.verify_batch([good]) == [True]
+        assert cli.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0
+    finally:
+        server.stop()
+
+
+def test_corrupt_point_drops_connection_beside_cached_neighbours():
+    """A frame with one bad point is refused whole, every time, although
+    the memo holds its valid neighbours (and would answer them): the
+    connection goes, ``crypto.rpc.bad_frames`` counts it, the memo does
+    not grow, and the plane serves on."""
+    from hbbft_tpu.crypto.bls import suite as bls_suite
+
+    bls_suite._decode_validated.cache_clear()
+    suite, reqs = _bls_fixture()
+    server = _server(suite)
+    try:
+        addr = (server.host, server.port)
+        cli = RpcServiceClient(addr, suite, BatchedBackend(suite))
+        assert cli.verify_batch(reqs) == [True, True]
+        size = bls_suite._decode_validated.cache_info().currsize
+        assert size == 4
+        body = bytearray(serde.dumps((7, "verify", tuple(reqs))))
+        share = reqs[1].payload[2].g2.to_bytes()
+        body[body.index(share) + len(share) - 1] ^= 1  # off the curve
+        for attempt in (1, 2):
+            s = _dial_past_hello(server, suite)
+            s.sendall(
+                encode_frame(KIND_CRYPTO_REQ, bytes(body), kinds=CRYPTO_KINDS)
+            )
+            assert _poisoned(s)
+            s.close()
+            assert _await_bad_frames(server, attempt) == attempt
+            assert bls_suite._decode_validated.cache_info().currsize == size
+        counters = fetch_stats(addr, suite)["counters"]
+        # the refused frames' points are not counted: no decode finished
+        assert counters["crypto.rpc.decode_points"] == 4
+        assert cli.verify_batch(reqs) == [True, True]
         assert cli.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0
     finally:
         server.stop()

@@ -435,6 +435,99 @@ def test_bls_off_curve_point_rejected(rng):
     assert serde.try_loads(bytes(data)) is None
 
 
+def _bls_decrypt_frame(seed, n=15, payload=400):
+    """One crypto-plane REQ body as ``RpcServiceClient`` sends it: ``n``
+    ``dec_share`` requests on one fresh ciphertext, under one key set."""
+    from hbbft_tpu.crypto.backend import VerifyRequest
+    from hbbft_tpu.crypto.bls.suite import BLSSuite
+    from hbbft_tpu.crypto.keys import SecretKeySet
+
+    suite = BLSSuite()
+    sks = SecretKeySet.random(2, random.Random(77), suite)  # the era's keys
+    pks = sks.public_keys()
+    rng = random.Random(seed)
+    ct = pks.public_key().encrypt(rng.randbytes(payload), rng)
+    reqs = tuple(
+        VerifyRequest.dec_share(
+            pks.public_key_share(i), ct,
+            sks.secret_key_share(i).decryption_share(ct),
+        )
+        for i in range(n)
+    )
+    return suite, reqs, serde.dumps((seed, "verify", reqs))
+
+
+def _pure_loads(data, suite=None):
+    """The recursive decoder, whatever ``serde.loads`` would choose."""
+    r = serde._Reader(data, None if suite is None else suite.name)
+    obj = serde._decode(r, 0)
+    if r.pos != len(r.data):
+        raise DecodeError("trailing bytes")
+    return obj
+
+
+@pytest.mark.parametrize("decoder", ["loads", "recursive"])
+def test_bls_decrypt_frame_validates_u_and_w_once(decoder):
+    """A burst of 15 ``dec_share`` carries its ciphertext 15 times: 60
+    points, of which ``U`` and ``W`` are validated once (28 hits in a cold
+    memo) and, once an earlier frame has brought the key shares, 17 are
+    new: ``U``, ``W`` and the 15 shares (43 hits).  Both decoders (the
+    native scan's builder where an engine is loaded, the recursive one)
+    decode through the suite's two methods."""
+    from hbbft_tpu.crypto.bls import suite as bls_suite
+
+    loads = serde.loads if decoder == "loads" else _pure_loads
+    bls_suite._decode_validated.cache_clear()
+    for seed, want_hits in ((1, 28), (2, 43), (3, 43)):
+        suite, reqs, frame = _bls_decrypt_frame(seed)
+        points0, hits0 = suite.decode_tally()
+        req_id, op, body = loads(frame, suite=suite)
+        points, hits = suite.decode_tally()
+        assert (points - points0, hits - hits0) == (60, want_hits)
+        assert (req_id, op) == (seed, "verify") and body == reqs
+        ct = body[0].payload[1]
+        assert all(r.payload[1].u is ct.u and r.payload[1].w is ct.w for r in body)
+    assert bls_suite._decode_validated.cache_info().currsize == 15 + 3 * 17
+
+
+def test_bls_eight_threads_decode_the_same_frame_and_agree():
+    import sys
+    import threading
+
+    from hbbft_tpu.crypto.bls import suite as bls_suite
+
+    bls_suite._decode_validated.cache_clear()
+    suite, reqs, frame = _bls_decrypt_frame(9, n=4)
+    start = threading.Barrier(8)
+    got, tallies = [None] * 8, [None] * 8
+
+    def work(k):
+        start.wait()
+        got[k] = serde.loads(frame, suite=suite)
+        tallies[k] = suite.decode_tally()  # this thread's alone
+
+    threads = [threading.Thread(target=work, args=(k,)) for k in range(8)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # switch inside the memo's misses
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert all(g == (9, "verify", reqs) for g in got)
+    assert all(points == 16 for points, _ in tallies)
+    # 4 keys + 4 shares + U + W, however the threads raced for them
+    assert bls_suite._decode_validated.cache_info().currsize == 10
+    bad = bytearray(frame)  # a bad point among cached ones
+    bad[bad.index(reqs[3].payload[2].g1.to_bytes()) + 96] ^= 1
+    with pytest.raises(DecodeError):
+        serde.loads(bytes(bad), suite=suite)
+    assert bls_suite._decode_validated.cache_info().currsize == 10
+
+
 def test_scalar_ct_serde_cache_matches_recursive_encoder():
     """The pre-rendered `_serde_cache` memo the native KEM attaches must
     be byte-identical to what the recursive encoder emits — a wrong
@@ -492,12 +585,7 @@ def test_native_scan_decode_matches_pure_decoder():
         sk.public_key(),
     ]
 
-    def pure_loads(data):
-        r = serde._Reader(data, None)
-        obj = serde._decode(r, 0)
-        if r.pos != len(r.data):
-            raise serde.DecodeError("trailing bytes")
-        return obj
+    pure_loads = _pure_loads
 
     encodings = []
     for obj in samples:
@@ -555,12 +643,7 @@ def test_depth_and_memo_boundaries_match_both_paths():
     from hbbft_tpu.crypto.suite import ScalarSuite
     from hbbft_tpu.utils import serde
 
-    def pure_loads(data):
-        r = serde._Reader(data, None)
-        obj = serde._decode(r, 0)
-        if r.pos != len(r.data):
-            raise serde.DecodeError("trailing bytes")
-        return obj
+    pure_loads = _pure_loads
 
     def nested(depth):
         return b"\x06\x00\x00\x00\x01" * depth + b"\x00"

@@ -302,7 +302,7 @@ def test_a_check_holds_its_own_dispatches_and_the_next_groups_prep(flushed):
 
 
 def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
-    script, _, spans = flushed
+    script, metrics, spans = flushed
     rows = script["n"]
     (flush,) = [s for s in spans if s[1] == "crypto.flush"]
     line, _, start, end, args = flush
@@ -336,7 +336,13 @@ def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
     assert {s[4]["span"] for s in rpc} == set(args["spans"].split()) == {"1:1"}
     (serve,) = by_name["crypto.rpc.serve"]
     assert serve[4]["op"] == "verify" and serve[4]["requests"] == rows
-    assert serve[4]["bytes"] == by_name["crypto.rpc.decode"][0][4]["bytes"] > 0
+    (decode,) = by_name["crypto.rpc.decode"]
+    assert serve[4]["bytes"] == decode[4]["bytes"] > 0
+    # a key share and a share a request; how many the memo of validated
+    # bytes answered depends on what this process decoded before
+    assert decode[4]["points"] == 2 * rows >= decode[4]["hits"] >= 0
+    assert metrics.counters["crypto.rpc.decode_points"] == decode[4]["points"]
+    assert metrics.counters["crypto.rpc.decode_point_hits"] == decode[4]["hits"]
     for s in rpc:
         assert serve[2] <= s[2] and s[3] <= serve[3]
     (wait,) = by_name["crypto.rpc.wait"]
