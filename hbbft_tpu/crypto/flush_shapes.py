@@ -52,12 +52,11 @@ def scan_shape(
     that a group of ``reqs`` runs in, whose legs hold that many real rows.
 
     Legs become pairing-product pairs (a Miller loop each, even when
-    identity-padded), so keep their floor LOW: on the 1-core virtual-CPU
-    test platform every padded leg costs real execution minutes across
-    the suite (a floor-8 experiment tripled warm suite time).  The cost
-    side — one ~7-min cold compile per distinct legs bucket (2/4/8 under
-    bisection) — is paid once and covered by
-    benchmarks/warm_crypto_cache.py + the persistent .jax_cache."""
+    identity-padded), so their floor is low: 2.  The other side of it is
+    one scan program, a cold compile of minutes, per distinct legs
+    bucket (2/4/8 under bisection of a flush on several documents),
+    which benchmarks/warm_crypto_cache.py and the persistent cache cover
+    for the CPU test tier."""
     return (
         bucket(max(g1_rows, 1), floor=g1_floor(reqs)),
         bucket(max(g2_rows, 1)),
@@ -70,11 +69,9 @@ def pairs_bucket(n: int) -> int:
     counts, multiples of 8 above.
 
     Small flushes (one chunk: 1 + n_legs = 3/5/9 pairs) keep their exact
-    size — on the 1-core virtual-CPU test platform every padded pair is
-    a real 63-step Miller loop per execution (CLAUDE.md: the floor-8
-    experiment made the suite strictly worse).  Multi-chunk combines pad
-    to a multiple of 8 so the compile count stays bounded; padded pairs
-    are identity pairs (factor 1 via the skip mask) and on TPU their
-    cost rides the already-batched lanes.
+    size: every padded pair is a real 63-step Miller loop per execution.
+    Multi-chunk combines pad to a multiple of 8 so the compile count
+    stays bounded; padded pairs are identity pairs (factor 1 via the
+    skip mask).
     """
     return n if n <= 9 else (n + 7) // 8 * 8

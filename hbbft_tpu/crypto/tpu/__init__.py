@@ -1,11 +1,10 @@
-"""Accelerator crypto plane: the RLC flush kernel and its backends.
+"""Accelerator crypto plane: the RLC flush kernel and its backend.
 
-Import surface for callers (benchmarks, embedders): ``TpuBackend`` —
-the device flush; ``HybridBackend`` — size-routed host/device with
-dead-device failover.  Submodules (``curve``, ``fq``, ``fq2``,
-``pairing``) are the kernel internals.
+Import surface for callers (the crypto-plane worker, embedders):
+``TpuBackend`` — the device flush.  Submodules (``curve``, ``fq``,
+``fq2``, ``pairing``) are the kernel internals.
 """
 
-from hbbft_tpu.crypto.tpu.backend import HybridBackend, TpuBackend
+from hbbft_tpu.crypto.tpu.backend import TpuBackend
 
-__all__ = ["HybridBackend", "TpuBackend"]
+__all__ = ["TpuBackend"]

@@ -12,9 +12,7 @@ the heavy group algebra runs on the accelerator in ONE jitted kernel:
   doubling chain — the subgroup (r-torsion) check for wire-sourced
   points runs on device as the standard phi/psi endomorphism tests
   (``bls.curve.g1_in_subgroup`` notes), batched, instead of as
-  per-request Python scalar-mults on the host (which cost more than
-  the entire device flush: BASELINE.md round-1 measurements); the
-  endomorphism form halves the scan vs the round-2 ``[r-1]P`` chain,
+  per-request Python scalar-mults on the host,
 * per-leg sums are masked tree reductions,
 * the 1 + L pairing-product legs run through the batched Miller loop and
   one shared final exponentiation.
@@ -22,12 +20,12 @@ the heavy group algebra runs on the accelerator in ONE jitted kernel:
 Kernel shapes are bucketed to powers of two so recompilation is bounded;
 compiled kernels are cached per (n_g1, n_g2, n_legs) bucket.
 
-Multi-chip: with ``shard=True`` (or ``HBBFT_TPU_SHARD=1``) and more than
-one visible device, the batch axis is laid out over a data-parallel
-``jax.sharding.Mesh`` — the scalar-mul scans run fully parallel per
-shard and XLA inserts the collectives for the tree reductions (SURVEY.md
-§2 parallelism note: batching over the share dimension IS this
-framework's parallelism axis).
+Multi-chip: with ``shard=True`` and more than one visible device, the
+batch axis is laid out over a data-parallel ``jax.sharding.Mesh`` — the
+scalar-mul scans run fully parallel per shard and XLA inserts the
+collectives for the tree reductions (SURVEY.md §2 parallelism note:
+batching over the share dimension IS this framework's parallelism
+axis).  It has run on virtual CPU devices only; no chip run has used it.
 
 Replaces the per-share CPU pairing checks of upstream
 ``threshold_crypto`` (``src/lib.rs`` verify paths; SURVEY.md §2 #14).
@@ -35,9 +33,7 @@ Replaces the per-share CPU pairing checks of upstream
 
 from __future__ import annotations
 
-import os
 import threading
-import warnings
 from collections import Counter
 from functools import lru_cache
 from typing import Any, Dict, List, Sequence, Tuple
@@ -49,7 +45,6 @@ import numpy as np
 from hbbft_tpu.crypto.backend import (
     DEC_SHARE,
     SIG_SHARE,
-    BatchedBackend,
     CryptoBackend,
     EagerBackend,
     VerifyRequest,
@@ -79,13 +74,10 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
       generator leg; rhs G2 points (n_legs) each G1 leg sum pairs with;
       the G1 generator.
     Returns (sub_ok, lhs, rhs): the aggregate subgroup verdict for every
-    masked wire-sourced point (batched r-torsion on device — a Python
-    subgroup check per request costs more than the whole device flush),
-    and the (1 + n_legs) pairing pairs this chunk contributes.  The
-    pairing itself is the separate :func:`_pair_kernel` stage so several
-    chunks' pairs can share ONE batched Miller loop + final
-    exponentiation (round-5 fixed-cost amortization; the stage split is
-    also what the per-stage timing in BASELINE.md measures).
+    masked wire-sourced point (batched r-torsion on device), and the
+    (1 + n_legs) pairing pairs this chunk contributes.  The pairing
+    itself is the separate :func:`_pair_kernel` stage so several chunks'
+    pairs can share ONE batched Miller loop + final exponentiation.
 
     The jitted function is named ``hbbft_scan_<n_g1>_<n_g2>_<n_legs>``, so
     a device trace's module is ``jit_hbbft_scan_...`` whatever a refactor
@@ -98,12 +90,11 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         g1_pts, g1_bits, g1_chk, seg,
         g2_pts, g2_bits_s, g2_bits_q, g2_chk, rhs_g2, gen_pt,
     ):
-        # Round-4 scans (dcurve "static-endo flush scans" notes): G1 is
+        # The scans (dcurve "static-endo flush scans" notes): G1 is
         # one LSB-first shared-doubling scan with the [x^2]P check-chain
         # adds unrolled at x^2's 17 static set bits; G2 splits each RLC
         # coefficient as c = q·|x| + s against the psi endomorphism —
-        # a 65-step two-scalar scan (~60% fewer Fq2 ops than the shared
-        # 128-step scan of rounds 2-3).  Soundness: the psi(Q) = [x]Q
+        # a 65-step two-scalar scan.  Soundness: the psi(Q) = [x]Q
         # identity the decomposition relies on IS the subgroup check
         # verified in this same kernel (fail-closed; see dcurve notes).
         # Equivalence + soundness pinned in tests/test_bls.py and
@@ -213,8 +204,8 @@ def _shard_mesh(max_devices: int = 16):
 class TpuBackend(CryptoBackend):
     """RLC batch verification with the group algebra on the accelerator.
 
-    ``shard=True`` (or env ``HBBFT_TPU_SHARD=1``) lays the batch axis
-    over all visible devices data-parallel; default is single-device.
+    ``shard=True`` lays the batch axis over all visible devices
+    data-parallel; default is single-device.
 
     ``metrics`` takes the backend's spans (:meth:`Metrics.span`: a timer
     each and, under an open profiler session, an event on its clock) and
@@ -256,7 +247,7 @@ class TpuBackend(CryptoBackend):
     def __init__(
         self,
         suite: BLSSuite | None = None,
-        shard: bool | None = None,
+        shard: bool = False,
         metrics: Metrics | None = None,
     ) -> None:
         self.suite = suite or BLSSuite()
@@ -265,8 +256,6 @@ class TpuBackend(CryptoBackend):
         # verdict is the device's); the stubbed-kernel harnesses of tests
         # and chipbench/tests answer through it.
         self._eager = EagerBackend(self.suite)
-        if shard is None:
-            shard = os.environ.get("HBBFT_TPU_SHARD") == "1"
         self._mesh = _shard_mesh() if shard else None
 
     # -- leg construction (host, cheap): mirrors backend._rlc_pairs ----
@@ -356,10 +345,6 @@ class TpuBackend(CryptoBackend):
             self.metrics.count("crypto.tpu.checks_failed")
         return ok
 
-    def _scan_dev(self, reqs: Sequence[VerifyRequest]):
-        """Prepare and dispatch one chunk's SCAN kernel."""
-        return self._scan_dispatch(self._scan_prep(reqs))
-
     def _scan_dispatch(self, prepared, alone: bool = False):
         """Dispatch the SCAN kernel on one chunk's :meth:`_scan_prep`;
         returns (sub_ok, lhs, rhs) device values WITHOUT forcing a host
@@ -376,9 +361,7 @@ class TpuBackend(CryptoBackend):
     def _scan_prep(self, reqs: Sequence[VerifyRequest]):
         """Host prep for one chunk: returns ((n1, n2, nl), kernel args).
         Split from :meth:`_scan_dispatch` so that bisection prepares a
-        group while the device checks the one before it, and measurement
-        tooling (benchmarks/flush_roofline.py) can lower the cached
-        kernel on the exact production inputs."""
+        group while the device checks the one before it."""
         with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
             with self.metrics.span("crypto.tpu.coefficients"):
                 coeffs = _batch_coefficients(self.suite, reqs)
@@ -502,22 +485,12 @@ class TpuBackend(CryptoBackend):
 
     # -- public API ----------------------------------------------------
 
-    # Per-flush device sweet spot (measured on the chip, BASELINE.md
-    # round-4 battery): giant flushes split into chunks, each with its
-    # own Fiat-Shamir coefficients, because per-row scan cost grows
-    # with the bucket's working set (HBM pressure).  The round-4 kernel
-    # moved the optimum from 4096 to 2048 (10240 shares: 1516/s at
-    # 2048-chunks vs 1085/s at 4096 — the smaller bucket's per-row win
-    # now outweighs the extra fixed pairing stages).  HBBFT_TPU_CHUNK
-    # overrides for re-tuning.
-    try:
-        CHUNK = max(1, int(os.environ.get("HBBFT_TPU_CHUNK", "2048")))
-    except ValueError:
-        warnings.warn(
-            "HBBFT_TPU_CHUNK is not an integer; falling back to 2048",
-            stacklevel=1,
-        )
-        CHUNK = 2048
+    # Rows of one scan program: a larger flush is split into chunks of
+    # this many requests, each with its own Fiat-Shamir coefficients
+    # (:meth:`_check_parts` has why their pairs may still share one pair
+    # stage).  No chip run under this repo's benchmark has sized it; the
+    # cell that will is ``sign10k.chunk`` (PERF.md section 7 item 2).
+    CHUNK = 2048
 
     def verify_batch(self, reqs: Sequence[VerifyRequest]) -> List[bool]:
         reqs = list(reqs)
@@ -548,7 +521,10 @@ class TpuBackend(CryptoBackend):
         # Dispatch every chunk's SCAN kernel before syncing on anything:
         # jax dispatch is async, so the device pipelines the chunks and
         # the host pays one round-trip total instead of one per chunk.
-        scans = [self._scan_dev([reqs[i] for i in c]) for c in chunks]
+        scans = [
+            self._scan_dispatch(self._scan_prep([reqs[i] for i in c]))
+            for c in chunks
+        ]
 
         def check(parts, rows: int) -> bool:
             with self.metrics.span("crypto.tpu.check", rows=rows, depth=0):
@@ -608,67 +584,3 @@ class TpuBackend(CryptoBackend):
                 else:
                     failed.append(g)
             depth += 1
-
-
-class HybridBackend(CryptoBackend):
-    """Route each flush to the cheaper plane, fail over off-device.
-
-    * Flushes with at least ``min_device_batch`` requests go to
-      :class:`TpuBackend`; smaller ones go to the host
-      :class:`~hbbft_tpu.crypto.backend.BatchedBackend` — small flushes
-      are latency-dominated either way, and keeping them host-side
-      avoids paying a fresh ~10-min XLA compile for every rare small
-      shape bucket (measured, BASELINE.md round-3 battery).
-    * If no accelerator platform is reachable at construction, every
-      flush rides the host path — protocols keep running, just without
-      the device plane.
-
-    Verdict-identical to both constituents by construction: every
-    backend implements the same RLC/bisection semantics (pinned by
-    tests/test_tpu_crypto.py + the backend-equivalence drive).
-    """
-
-    # Pass as ``device=`` to force host-only mode regardless of platform
-    # (None means auto-detect, so it cannot express "no device").
-    NO_DEVICE: Any = object()
-
-    def __init__(
-        self,
-        suite: BLSSuite | None = None,
-        min_device_batch: int = 64,
-        device: CryptoBackend | None = None,
-        host: CryptoBackend | None = None,
-    ) -> None:
-        self.suite = suite or BLSSuite()
-        self.min_device_batch = min_device_batch
-        self.host = host or BatchedBackend(self.suite)
-        if device is HybridBackend.NO_DEVICE:
-            self.device: CryptoBackend | None = None
-        elif device is not None:
-            self.device = device
-        else:
-            try:
-                ok = jax.default_backend() not in ("", "cpu")
-            except Exception:
-                ok = False
-            self.device = TpuBackend(self.suite) if ok else None
-
-    def verify_batch(self, reqs: Sequence[VerifyRequest]) -> List[bool]:
-        reqs = list(reqs)
-        if self.device is not None and len(reqs) >= self.min_device_batch:
-            try:
-                return self.device.verify_batch(reqs)
-            except Exception as exc:
-                # Device died mid-run — serve this and every later
-                # flush from the host plane.
-                # Verdict-identical by construction, so the failover is
-                # invisible to the protocol; warn so a genuine device
-                # bug or OOM isn't silently masked by the degradation.
-                warnings.warn(
-                    "HybridBackend: device flush failed, failing over to "
-                    f"host for the rest of the run: {exc!r}",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
-                self.device = None
-        return self.host.verify_batch(reqs)

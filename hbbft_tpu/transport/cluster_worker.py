@@ -144,6 +144,23 @@ def batches_digest(batches: List[Any], upto: int) -> str:
     return digest.hexdigest()[:16]
 
 
+def contribution_digests(batches: List[Any], upto: int) -> List[Dict[str, str]]:
+    """Per epoch of the digest window, ``{proposer: digest}`` of each
+    contribution it committed.  A proposer's contribution is a function
+    of its seed and its own queue alone, so where it was in every
+    earlier subset it is the same bytes in every run; WHICH proposers an
+    epoch's subset holds (n - f at least) is the network's timing."""
+
+    def digest(b: Any, proposer: Any, contribution: Any) -> str:
+        data = serde.dumps((b.era, b.epoch, proposer, contribution))
+        return hashlib.sha256(data).hexdigest()[:16]
+
+    return [
+        {str(p): digest(b, p, c) for p, c in b.contributions}
+        for b in batches[:upto]
+    ]
+
+
 def _read_peer_map(n: int) -> Dict[int, Tuple[str, int]]:
     """Block for the parent's one-line address map on stdin."""
     line = sys.stdin.readline()
@@ -431,6 +448,9 @@ def main(argv=None) -> int:
             # intra-run identical, but the digest differs from a
             # full-participation run)
             "epoch_contribs": [len(b.contributions) for b in batches[:upto]],
+            # what the cross-arm comparison that scheduling cannot move
+            # reads (tests/test_cryptoplane_proc.py)
+            "epoch_contrib_shas": contribution_digests(batches, upto),
             "faults": len(getattr(node, "faults", ()))
             or m.counters.get("cluster.protocol_faults", 0),
             "msgs_handled": m.counters.get("cluster.msgs_handled", 0),

@@ -678,39 +678,65 @@ def test_service_process_sigkill_fallback_and_reattach(impl):
 # ---------------------------------------------------------------------------
 
 
-def test_proc_cluster_service_arm_identity_and_amortization():
-    """ProcCluster's service arm commits the same stream as its inline
-    arm, every worker's flushes rode the ONE service process, and the
-    service's flush counters show cross-node merging."""
-    _lib_or_skip()
+def _presubmit_arm(crypto: str):
+    """One N=4 presubmit run of three epochs.  Returns the workers'
+    summaries, the service's counters (None inline) and the committed
+    window as ``[{proposer: contribution digest}]``, after the claims that
+    hold in every run: all four processes committed one stream, and every
+    epoch's subset holds at least n - f = 3 proposers."""
     with ProcCluster(
         n=4, seed=0, impl="native", epochs=3, drive="presubmit",
-        timeout_s=90.0, crypto="service-proc",
+        timeout_s=90.0, crypto=crypto,
     ) as pc:
         sums = pc.join(timeout_s=120.0)
         assert all(s is not None for s in sums.values()), sums
         shas = pc.shas()
         assert len(set(shas.values())) == 1, shas
-        for i, s in sums.items():
-            rpc = s.get("crypto_rpc")
-            assert rpc and rpc["calls"] > 0, (i, s)
-            assert rpc["fallbacks"] == 0, (i, s)
-            # every flush response carries the merged size; with 4
-            # clients the merged total can only exceed this node's own
-            assert rpc["merged_requests"] >= rpc["requests"], (i, s)
-        stats = pc.crypto_service.stats()["counters"]
-        assert stats["crypto.flushes"] > 0
-        assert stats["crypto.requests"] > stats["crypto.flushes"], stats
-        ref_sha = shas[0]
+        window = sums[0]["epoch_contrib_shas"]
+        assert all(s["epoch_contrib_shas"] == window for s in sums.values()), sums
+        assert len(window) == 3 and all(len(e) >= 3 for e in window), window
+        stats = (
+            pc.crypto_service.stats()["counters"]
+            if crypto == "service-proc" else None
+        )
+        return sums, stats, window
 
-    with ProcCluster(
-        n=4, seed=0, impl="native", epochs=3, drive="presubmit",
-        timeout_s=90.0, crypto="inline",
-    ) as pc:
-        sums = pc.join(timeout_s=120.0)
-        assert all(s is not None for s in sums.values()), sums
-        inline_shas = set(pc.shas().values())
-        assert inline_shas == {ref_sha}, (inline_shas, ref_sha)
+
+def test_proc_cluster_service_arm_identity_and_amortization():
+    """ProcCluster's service arm commits what its inline arm commits,
+    every worker's flushes rode the ONE service process, and the
+    service's flush counters show cross-node merging.
+
+    Across two runs the digest of the whole stream is the network's
+    timing (a third of the runs alone, one epoch's subset misses one
+    proposer's broadcast, and that proposer then draws its later
+    proposals from a queue that still holds the missed ones), so the
+    arms are compared on what no schedule moves: a proposer's
+    contribution is a function of its seed and its own queue, hence the
+    same bytes in both arms in every epoch up to the first whose subset
+    lacks it in either arm.  Each arm's epoch 0 holds three proposers of
+    four or more, so it always compares two or more."""
+    _lib_or_skip()
+    sums, stats, served = _presubmit_arm("service-proc")
+    for i, s in sums.items():
+        rpc = s.get("crypto_rpc")
+        assert rpc and rpc["calls"] > 0, (i, s)
+        assert rpc["fallbacks"] == 0, (i, s)
+        # every flush response carries the merged size; with 4
+        # clients the merged total can only exceed this node's own
+        assert rpc["merged_requests"] >= rpc["requests"], (i, s)
+    assert stats["crypto.flushes"] > 0
+    assert stats["crypto.requests"] > stats["crypto.flushes"], stats
+
+    _, _, inline = _presubmit_arm("inline")
+    compared = 0
+    for proposer in map(str, range(4)):
+        for a, b in zip(served, inline):
+            if proposer not in a or proposer not in b:
+                break
+            assert a[proposer] == b[proposer], (proposer, served, inline)
+            compared += 1
+    assert compared >= 2, (served, inline)
 
 
 def test_proc_cluster_service_kill_drill():

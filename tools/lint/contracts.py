@@ -476,7 +476,7 @@ _C_KNOB_RE = re.compile(r'"(HBBFT_TPU_[A-Z0-9_]+)"')
 # registry + rule sources name knobs) and tests/test_lint.py (mutation
 # fixtures) are excluded — they are the checker, not the checked.
 _PY_SCAN_ROOTS = ("hbbft_tpu", "benchmarks", "tests", "tools", "examples")
-_PY_SCAN_EXTRA = ("bench.py",)
+_PY_SCAN_EXTRA = ("chip_smoke.py", "__graft_entry__.py")
 _SKIP_DIRS = {"__pycache__", "build", ".jax_cache", ".git"}
 
 

@@ -49,15 +49,6 @@ KNOBS: Dict[str, Knob] = {
             "`hbe_create`.",
         ),
         _k(
-            "HBBFT_TPU_CHUNK",
-            "2048",
-            "crypto/tpu backend (`TpuBackend`)",
-            "Flush-kernel chunk rows.  Re-tune only with a fresh sweep: "
-            "the round-4 kernel moved the optimum from 4096 to 2048 "
-            "(BASELINE.md round 4); bigger buckets pay HBM pressure, "
-            "smaller ones pay fixed pairing cost per chunk.",
-        ),
-        _k(
             "HBBFT_TPU_COIN_RLC",
             "1 (on)",
             "native engine + TS/TD protocols",
@@ -153,14 +144,6 @@ KNOBS: Dict[str, Knob] = {
             "sendmsg/vectored gather path.  Perf-neutral at N=16 thread "
             "mode on this box (BASELINE.md round 14) — it exists for "
             "A/B honesty, not as a tuning lever here.",
-        ),
-        _k(
-            "HBBFT_TPU_SHARD",
-            "unset (off)",
-            "crypto/tpu backend",
-            "`1` shards the flush batch axis across all visible "
-            "devices (virtual-CPU mesh or real chips).  Compiles a "
-            "separate sharded flush pipeline — budget a cold compile.",
         ),
         _k(
             "HBBFT_TPU_SIMD",
