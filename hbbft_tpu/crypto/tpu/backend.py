@@ -3,7 +3,8 @@
 Same random-linear-combination batch verification as
 :class:`hbbft_tpu.crypto.backend.BatchedBackend` — identical Fiat-Shamir
 coefficients, identical leg algebra, bisection fallback on failure — but
-the heavy group algebra runs on the accelerator in ONE jitted kernel:
+the heavy group algebra runs on the accelerator, in a scan program and a
+pair program with a join of a few rows between them:
 
 * every share/key/ciphertext point is scaled by its 128-bit RLC
   coefficient with a batched LSB-first double-and-add scan that
@@ -62,6 +63,10 @@ from hbbft_tpu.utils.metrics import Metrics
 
 NBITS = 128  # RLC coefficient width
 
+# The points at infinity as oracle Jacobian tuples: what pads a bucket.
+_IDENT1 = (1, 1, 0)
+_IDENT2 = ((1, 0), (1, 0), (0, 0))
+
 
 @lru_cache(maxsize=32)
 def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
@@ -71,11 +76,15 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
       g1 pts (n_g1 batched G1 Jacobian+flag), g1 bits (n_g1, ENDO_NBITS
       = 128; the RLC coefficient), g1 subgroup-check mask (n_g1,), g1
       leg one-hot (n_legs, n_g1); g2 pts / bits / mask (n_g2 …) — the
-      generator leg; rhs G2 points (n_legs) each G1 leg sum pairs with;
-      the G1 generator.
-    Returns (sub_ok, lhs, rhs): the aggregate subgroup verdict for every
-    masked wire-sourced point (batched r-torsion on device), and the
-    (1 + n_legs) pairing pairs this chunk contributes.  The pairing
+      generator leg; the G1 generator.
+    Returns (sub_ok, lhs, gen_leg): the aggregate subgroup verdict for
+    every masked wire-sourced point (batched r-torsion on device), the
+    (1 + n_legs) left-hand G1 points of the pairing pairs this chunk
+    contributes (the generator, then each leg's sum) and, as one row, the
+    G2 sum the generator pairs with.  The G2 points the leg sums pair
+    with are not arguments: nothing here computes on them, so the host
+    hashes them while this program runs (:meth:`TpuBackend._rhs_prep`) and
+    :func:`_join_kernel` puts them behind ``gen_leg``.  The pairing
     itself is the separate :func:`_pair_kernel` stage so several chunks'
     pairs can share ONE batched Miller loop + final exponentiation.
 
@@ -88,7 +97,7 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
 
     def run(
         g1_pts, g1_bits, g1_chk, seg,
-        g2_pts, g2_bits_s, g2_bits_q, g2_chk, rhs_g2, gen_pt,
+        g2_pts, g2_bits_s, g2_bits_q, g2_chk, gen_pt,
     ):
         # The scans (dcurve "static-endo flush scans" notes): G1 is
         # one LSB-first shared-doubling scan with the [x^2]P check-chain
@@ -120,14 +129,12 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
                     dcurve.identity(dcurve.G1_OPS, (n_g1,)), dcurve.G1_OPS,
                 )
                 leg_sums.append(dcurve.tree_sum(dcurve.G1_OPS, masked))
-        # Pair list: (gen, gen_leg) + (leg_sum_l, rhs_l).
+        # Pair list: (gen, gen_leg) + (leg_sum_l, rhs_l), the rhs_l joined
+        # on later.
         lhs = tuple(
             jnp.stack([gen_pt[c]] + [p[c] for p in leg_sums]) for c in range(4)
         )
-        rhs = tuple(
-            jnp.concatenate([jnp.stack([gen_leg[c]]), rhs_g2[c]]) for c in range(4)
-        )
-        return sub_ok, lhs, rhs
+        return sub_ok, lhs, tuple(jnp.stack([gen_leg[c]]) for c in range(4))
 
     run.__name__ = run.__qualname__ = f"hbbft_scan_{n_g1}_{n_g2}_{n_legs}"
     return jax.jit(run)
@@ -145,6 +152,37 @@ def _pair_kernel(n_pairs: int):
         return dpairing.pairing_product_is_one(lhs, rhs)
 
     run.__name__ = run.__qualname__ = f"hbbft_pair_{n_pairs}"
+    return jax.jit(run)
+
+
+@lru_cache(maxsize=32)
+def _join_kernel(n_pairs: int):
+    """Compiled JOIN: one or more chunks' scan outputs and right-hand
+    points as the PAIR stage's two arguments of ``n_pairs`` rows.  A
+    chunk's pairs are (generator, its ``gen_leg``) and (leg sum l, right-
+    hand point l); chunks follow one another and identity pairs pad to the
+    bucket.  Of a chunk's ``gen_leg`` the FIRST row is taken: a stubbed
+    scan may return more.  Data movement only, all four coordinates in one
+    launch; named ``hbbft_join_<n_pairs>`` (a trace's module
+    ``jit_hbbft_join_...``: neither a scan nor a pair module)."""
+
+    def run(lhs_parts, gen_legs, rhs_parts):
+        lhs = [list(coords) for coords in zip(*lhs_parts)]
+        rhs = [[] for _ in range(4)]
+        for gen_leg, pts in zip(gen_legs, rhs_parts):
+            for c in range(4):
+                rhs[c] += [gen_leg[c][:1], pts[c]]
+        pad = n_pairs - sum(int(p[3].shape[0]) for p in lhs_parts)
+        if pad:
+            for side, ops in ((lhs, dcurve.G1_OPS), (rhs, dcurve.G2_OPS)):
+                for c, x in enumerate(dcurve.identity(ops, (pad,))):
+                    side[c].append(x)
+        return (
+            tuple(jnp.concatenate(xs) for xs in lhs),
+            tuple(jnp.concatenate(xs) for xs in rhs),
+        )
+
+    run.__name__ = run.__qualname__ = f"hbbft_join_{n_pairs}"
     return jax.jit(run)
 
 
@@ -212,14 +250,21 @@ class TpuBackend(CryptoBackend):
     counters, each counter at its span's boundary so that the ``stats`` op
     reads what a trace reads.  One aggregate check is one device verdict:
     ``crypto.tpu.check`` (args ``rows``, ``depth``: 0 the flush's own,
-    +1 per bisection level) around its ``crypto.tpu.scan_dispatch``,
-    ``crypto.tpu.pair_dispatch`` (``pairs``) and
-    ``crypto.tpu.verdict_sync`` (the host blocked on the device).  The
+    +1 per bisection level) around, in this order, its
+    ``crypto.tpu.scan_dispatch``, ``crypto.tpu.rhs_prep`` (``legs``,
+    ``hashed``), ``crypto.tpu.pair_dispatch`` (``pairs``: the join program,
+    then the pair program) and ``crypto.tpu.verdict_sync`` (the host blocked
+    on the device).  The scan program reads none of the G2 points its leg
+    sums pair with, so it is dispatched before they exist: ``rhs_prep``
+    hashes each distinct leg that brings no point (one
+    ``crypto.tpu.hash_to_g2`` (``bytes``) a document or ciphertext, however
+    many requests share it; a ciphertext's ``W`` comes ready) and puts the
+    points on the device while the scan runs.  The
     ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``; inside it
-    ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs`` with one
-    ``crypto.tpu.hash_to_g2`` (``bytes``) per call inside it,
-    ``crypto.tpu.pack``) before the dispatches is the check's
-    own: the flush's, or that of a bisection level's first group.  One
+    ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs``,
+    ``crypto.tpu.pack``: everything the scan program reads) before the
+    dispatches is the check's own: the flush's, or that of a bisection
+    level's first group.  One
     between ``pair_dispatch`` and ``verdict_sync`` is the NEXT group's of
     the level, prepared while the device runs this check (its ``rows``
     says whose; a level's last check holds none).  Beside the checks
@@ -233,15 +278,17 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.g1_rows`` and ``crypto.tpu.g2_rows`` (real rows of every
     ``scan_prep``), ``crypto.tpu.rows_padded`` (bucket rows less real
     rows, G1 and G2 summed), ``crypto.tpu.hash_to_g2_calls``,
-    ``crypto.tpu.leaves``, ``crypto.tpu.prepared_ahead`` (``scan_prep``s
-    that ran between a check's ``pair_dispatch`` and its
-    ``verdict_sync``), ``crypto.tpu.requests.<kind>`` (well-formed
-    requests that entered a flush, by kind; once a flush, not again in
-    its groups).
-    A flush of several chunks dispatches every chunk's scan before any
-    verdict, so there ``scan_prep`` and ``scan_dispatch`` lie beside the
-    checks, not inside them: a check is then the combined pair stage and
-    its sync, and after a failure one more per chunk.
+    ``crypto.tpu.rhs_hashed`` (legs hashed in an ``rhs_prep``, so after
+    their group's scan was dispatched), ``crypto.tpu.leaves``,
+    ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
+    check's ``pair_dispatch`` and its ``verdict_sync``),
+    ``crypto.tpu.requests.<kind>`` (well-formed requests that entered a
+    flush, by kind; once a flush, not again in its groups).
+    A flush of several chunks dispatches every chunk's scan, then makes
+    every chunk's ``rhs_prep``, before any verdict, so there ``scan_prep``,
+    ``scan_dispatch`` and ``rhs_prep`` lie beside the checks, not inside
+    them: a check is then the combined pair stage and its sync, and after a
+    failure one more per chunk.
     """
 
     def __init__(
@@ -261,11 +308,15 @@ class TpuBackend(CryptoBackend):
     # -- leg construction (host, cheap): mirrors backend._rlc_pairs ----
 
     def _build_legs(self, reqs: Sequence[VerifyRequest], coeffs: Sequence[int]):
-        """Returns (g2_entries, g1_entries, rhs_points).
+        """Returns (g2_entries, g1_entries, rhs).
 
         g2_entries: list of (scalar, oracle G2 jac, check) summed against
         the G1 generator.  g1_entries: (scalar, oracle G1 jac, leg_id,
-        check).  rhs_points[leg_id]: oracle G2 jac each G1 leg pairs with.
+        check).  rhs[leg_id]: the G2 point each G1 leg pairs with, once a
+        distinct leg: an oracle G2 jac where the request brings it (a
+        ciphertext's ``W``), else the ``bytes`` whose hash to G2 it is (a
+        document, a ciphertext's hash input).  Nothing is hashed here:
+        :meth:`_rhs_points` does that once the scan is dispatched.
         ``check`` = 1 marks wire-sourced points that need the device-side
         r-torsion check (shares, ciphertext points); locally-derived
         points (public-key shares, hash-to-curve outputs) are exempt.
@@ -275,42 +326,43 @@ class TpuBackend(CryptoBackend):
         rhs: List[Any] = []
         leg_of: Dict[bytes, int] = {}
 
-        def leg(key: bytes, point_jac: Any) -> int:
+        def leg(key: bytes, point: Any) -> int:
             if key not in leg_of:
                 leg_of[key] = len(rhs)
-                rhs.append(point_jac)
+                rhs.append(point)
             return leg_of[key]
-
-        def hash_to_g2(data: bytes) -> Any:
-            self.metrics.count("crypto.tpu.hash_to_g2_calls")
-            with self.metrics.span("crypto.tpu.hash_to_g2", bytes=len(data)):
-                return self.suite.hash_to_g2(data).jac
 
         for r, c in zip(reqs, coeffs):
             if r.kind == SIG_SHARE:
                 pk, msg, share = r.payload
                 g2_entries.append((c, share.g2.jac, 1))
-                l = leg(canonical_bytes(b"m", msg), hash_to_g2(msg))
+                l = leg(canonical_bytes(b"m", msg), bytes(msg))
                 g1_entries.append((c, (-pk.g1).jac, l, 0))
             elif r.kind == DEC_SHARE:
                 pk, ct, share = r.payload
-                l = leg(
-                    canonical_bytes(b"c", ct.hash_input()),
-                    hash_to_g2(ct.hash_input()),
-                )
+                l = leg(canonical_bytes(b"c", ct.hash_input()), ct.hash_input())
                 g1_entries.append((c, share.g1.jac, l, 1))
                 lw = leg(canonical_bytes(b"w", ct.w.to_bytes()), ct.w.jac)
                 g1_entries.append((c, (-pk.g1).jac, lw, 0))
             else:
                 (ct,) = r.payload
                 g2_entries.append((c, ct.w.jac, 1))
-                l = leg(
-                    canonical_bytes(b"c", ct.hash_input()),
-                    hash_to_g2(ct.hash_input()),
-                )
+                l = leg(canonical_bytes(b"c", ct.hash_input()), ct.hash_input())
                 # -U is in the subgroup iff U is.
                 g1_entries.append((c, (-ct.u).jac, l, 1))
         return g2_entries, g1_entries, rhs
+
+    def _rhs_points(self, rhs: Sequence[Any]) -> List[Any]:
+        """:meth:`_build_legs`'s ``rhs`` with every deferred leg hashed:
+        oracle G2 jacs, one a leg."""
+        points = []
+        for point in rhs:
+            if isinstance(point, bytes):
+                self.metrics.count("crypto.tpu.hash_to_g2_calls")
+                with self.metrics.span("crypto.tpu.hash_to_g2", bytes=len(point)):
+                    point = self.suite.hash_to_g2(point).jac
+            points.append(point)
+        return points
 
     def _aggregate_ok(
         self,
@@ -320,15 +372,16 @@ class TpuBackend(CryptoBackend):
         ahead: Sequence[VerifyRequest] | None = None,
     ):
         """One aggregate check of a whole flush or of one of bisection's
-        groups: scan, pair stage, verdict.  ``prepared`` is this group's
-        :meth:`_scan_prep` where the check before it made it; ``ahead``
-        the requests of the group checked next, prepared here once both
-        programs are dispatched, while the device runs them.  Returns
-        (verdict, what was prepared ahead or None)."""
+        groups: scan, right-hand points, pair stage, verdict.  ``prepared``
+        is this group's :meth:`_scan_prep` where the check before it made
+        it; ``ahead`` the requests of the group checked next, prepared
+        here once both programs are dispatched, while the device runs
+        them.  Returns (verdict, what was prepared ahead or None)."""
         with self.metrics.span("crypto.tpu.check", rows=len(reqs), depth=depth):
             if prepared is None:
                 prepared = self._scan_prep(reqs)
-            ok_dev = self._check_parts([self._scan_dispatch(prepared, alone=True)])
+            scan = self._scan_dispatch(prepared, alone=True)
+            ok_dev = self._check_parts([self._rhs_prep(prepared, scan)])
             if ahead is not None:
                 ahead = self._scan_prep(ahead)
                 self.metrics.count("crypto.tpu.prepared_ahead")
@@ -347,21 +400,42 @@ class TpuBackend(CryptoBackend):
 
     def _scan_dispatch(self, prepared, alone: bool = False):
         """Dispatch the SCAN kernel on one chunk's :meth:`_scan_prep`;
-        returns (sub_ok, lhs, rhs) device values WITHOUT forcing a host
-        sync, so independent chunks pipeline on device.  ``alone``: this
+        returns (sub_ok, lhs, gen_leg) device values WITHOUT forcing a host
+        sync, so independent chunks pipeline on device and the host goes
+        on to :meth:`_rhs_prep` under the running program.  ``alone``: this
         chunk is the whole check, so its own pair count is the PAIR
         stage's (several chunks combine into a bucket only
         :meth:`verify_batch` knows)."""
-        (n1, n2, nl), args = prepared
+        (n1, n2, nl), args, _ = prepared
         if alone and self._mesh is None:
             _compile_pair_kernel_early(_pairs_bucket(1 + nl))
         with self.metrics.span("crypto.tpu.scan_dispatch"):
             return _scan_kernel(n1, n2, nl)(*args)
 
+    def _rhs_prep(self, prepared, scan):
+        """The host's work under a dispatched scan: hash the chunk's
+        deferred legs (:meth:`_rhs_points`) and put its ``nl`` right-hand
+        G2 points on the device.  Returns the chunk's part for
+        :meth:`_check_parts`: (sub_ok, lhs, gen_leg, rhs points)."""
+        (_, _, nl), _, rhs = prepared
+        hashed = sum(isinstance(point, bytes) for point in rhs)
+        with self.metrics.span("crypto.tpu.rhs_prep", legs=len(rhs), hashed=hashed):
+            points = self._rhs_points(rhs)
+            self.metrics.count("crypto.tpu.rhs_hashed", hashed)
+            pts = dcurve.g2_to_dev(points + [_IDENT2] * (nl - len(points)))
+            if self._mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as PS
+
+                repl = NamedSharding(self._mesh, PS())
+                pts = tuple(jax.device_put(c, repl) for c in pts)
+        return (*scan, pts)
+
     def _scan_prep(self, reqs: Sequence[VerifyRequest]):
-        """Host prep for one chunk: returns ((n1, n2, nl), kernel args).
-        Split from :meth:`_scan_dispatch` so that bisection prepares a
-        group while the device checks the one before it."""
+        """Host prep for one chunk, everything the scan program reads:
+        returns ((n1, n2, nl), kernel args, the legs' right-hand points as
+        :meth:`_build_legs` gives them, for :meth:`_rhs_prep`).  Split from
+        :meth:`_scan_dispatch` so that bisection prepares a group while
+        the device checks the one before it."""
         with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
             with self.metrics.span("crypto.tpu.coefficients"):
                 coeffs = _batch_coefficients(self.suite, reqs)
@@ -375,16 +449,14 @@ class TpuBackend(CryptoBackend):
                 "crypto.tpu.rows_padded", n1 - len(g1e) + n2 - len(g2e)
             )
             with self.metrics.span("crypto.tpu.pack"):
-                args = self._pack(g1e, g2e, rhs, n1, n2, nl)
-        return (n1, n2, nl), args
+                args = self._pack(g1e, g2e, n1, n2, nl)
+        return (n1, n2, nl), args, rhs
 
-    def _pack(self, g1e, g2e, rhs, n1: int, n2: int, nl: int):
+    def _pack(self, g1e, g2e, n1: int, n2: int, nl: int):
         """The legs as the SCAN kernel's arguments: limbs, bit planes and
         masks, padded to the buckets and put on the device."""
-        ident1 = (1, 1, 0)
-        ident2 = ((1, 0), (1, 0), (0, 0))
         g1_pts = dcurve.g1_to_dev(
-            [p for _, p, _, _ in g1e] + [ident1] * (n1 - len(g1e))
+            [p for _, p, _, _ in g1e] + [_IDENT1] * (n1 - len(g1e))
         )
         g1_bits = dcurve.scalars_to_bits_lsb(
             [s for s, _, _, _ in g1e] + [0] * (n1 - len(g1e)), dcurve.ENDO_NBITS
@@ -395,7 +467,7 @@ class TpuBackend(CryptoBackend):
             seg[l, i] = 1
             g1_chk[i] = chk
         g2_pts = dcurve.g2_to_dev(
-            [p for _, p, _ in g2e] + [ident2] * (n2 - len(g2e))
+            [p for _, p, _ in g2e] + [_IDENT2] * (n2 - len(g2e))
         )
         sq = [dcurve.decompose_g2_scalar(s) for s, _, _ in g2e]
         sq += [(0, 0)] * (n2 - len(g2e))
@@ -408,7 +480,6 @@ class TpuBackend(CryptoBackend):
         g2_chk = np.zeros(n2, dtype=np.int32)
         for i, (_, _, chk) in enumerate(g2e):
             g2_chk[i] = chk
-        rhs_pts = dcurve.g2_to_dev(rhs + [ident2] * (nl - len(rhs)))
         gen_pt = dcurve.g1_to_dev([ocurve.G1_GEN])
         gen_pt = tuple(x[0] for x in gen_pt)
         g1_chk = jnp.asarray(g1_chk)
@@ -432,17 +503,17 @@ class TpuBackend(CryptoBackend):
             g1_chk = put(g1_chk, batch)
             g2_chk = put(g2_chk, batch)
             seg = put(seg, seg_sh)
-            rhs_pts = tuple(put(c, repl) for c in rhs_pts)
             gen_pt = tuple(put(c, repl) for c in gen_pt)
         return (
             g1_pts, g1_bits, g1_chk, seg,
-            g2_pts, g2_bits_s, g2_bits_q, g2_chk, rhs_pts, gen_pt,
+            g2_pts, g2_bits_s, g2_bits_q, g2_chk, gen_pt,
         )
 
     def _check_parts(self, parts) -> Any:
-        """Combine one or more chunks' (sub_ok, lhs, rhs) scan outputs
-        into a single device verdict: batched Miller loop over ALL pairs
-        + ONE final exponentiation, AND of every chunk's subgroup bit.
+        """Combine one or more chunks' parts (:meth:`_rhs_prep`: sub_ok,
+        lhs, gen_leg, rhs points) into a single device verdict: the JOIN
+        program lines their pairs up, then a batched Miller loop over ALL
+        pairs + ONE final exponentiation, AND of every chunk's subgroup bit.
 
         Soundness of the cross-chunk product check: each chunk is an RLC
         with Fiat-Shamir coefficients committed to that chunk's request
@@ -457,24 +528,9 @@ class TpuBackend(CryptoBackend):
         n = sum(int(p[1][3].shape[0]) for p in parts)
         b = _pairs_bucket(n)
         with self.metrics.span("crypto.tpu.pair_dispatch", pairs=b):
-            if len(parts) == 1:
-                lhs, rhs = parts[0][1], parts[0][2]
-            else:
-                lhs = tuple(
-                    jnp.concatenate([p[1][c] for p in parts]) for c in range(4)
-                )
-                rhs = tuple(
-                    jnp.concatenate([p[2][c] for p in parts]) for c in range(4)
-                )
-            if b > n:
-                pad1 = dcurve.identity(dcurve.G1_OPS, (b - n,))
-                pad2 = dcurve.identity(dcurve.G2_OPS, (b - n,))
-                lhs = tuple(
-                    jnp.concatenate([lhs[c], pad1[c]]) for c in range(4)
-                )
-                rhs = tuple(
-                    jnp.concatenate([rhs[c], pad2[c]]) for c in range(4)
-                )
+            lhs, rhs = _join_kernel(b)(
+                [p[1] for p in parts], [p[2] for p in parts], [p[3] for p in parts]
+            )
             early = _EARLY_PAIR_COMPILES.get(b)
             if early is not None:
                 early.join()
@@ -521,10 +577,12 @@ class TpuBackend(CryptoBackend):
         # Dispatch every chunk's SCAN kernel before syncing on anything:
         # jax dispatch is async, so the device pipelines the chunks and
         # the host pays one round-trip total instead of one per chunk.
-        scans = [
-            self._scan_dispatch(self._scan_prep([reqs[i] for i in c]))
-            for c in chunks
-        ]
+        # Then hash every chunk's legs, under the running scans.
+        dispatched = []
+        for c in chunks:
+            prepared = self._scan_prep([reqs[i] for i in c])
+            dispatched.append((prepared, self._scan_dispatch(prepared)))
+        scans = [self._rhs_prep(prepared, scan) for prepared, scan in dispatched]
 
         def check(parts, rows: int) -> bool:
             with self.metrics.span("crypto.tpu.check", rows=rows, depth=0):

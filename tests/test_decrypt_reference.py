@@ -4,8 +4,9 @@ a small size on the CPU.
 ``chipbench``'s ``decrypt_flushes`` flushes of its tests' deployment ``hb4``
 (a ciphertext check and two decryption shares, one request wrong) go through
 ``TpuBackend.verify_batch`` with the two flush programs replaced by a host
-evaluation of the legs that ``_build_legs`` made for the group being checked:
-the oracle's scalar multiplications, its r-torsion check on every marked row
+evaluation of the legs that ``_build_legs`` made for the group being checked,
+their right-hand points as ``_rhs_points`` resolved them under the dispatched
+scan: the oracle's scalar multiplications, its r-torsion check on every marked row
 and its product of pairings.  So leg construction for ``dec_share`` and
 ``ciphertext``, the floor, bisection and the verdict logic under test are
 the program's own, and no XLA flush program is compiled.  Every answer is
@@ -88,16 +89,27 @@ def legs_hold(g2e, g1e, rhs):
 
 def host_kernels(monkeypatch):
     """Replace the two programs by :func:`legs_hold` on the legs of the most
-    recent ``_build_legs`` (a group prepared ahead is built after the pair
-    stage of the check before it was called, so at a pair stage's call the
-    most recent legs are its own).  Returns the shapes asked for."""
+    recent ``_build_legs``, with the right-hand points of the most recent
+    ``_rhs_points`` (a group prepared ahead is built after the pair stage of
+    the check before it was called, and its points are resolved after its own
+    scan's dispatch, so at a pair stage's call both are its own).  Returns the
+    shapes asked for."""
     legs = []
     launched = []
     honest = B.TpuBackend._build_legs
+    honest_points = B.TpuBackend._rhs_points
 
     def build_legs(self, reqs, coeffs):
         legs[:] = honest(self, reqs, coeffs)
+        # nothing is hashed before the scan is dispatched
+        assert {type(p) for p in legs[2]} <= {bytes, tuple}
         return tuple(legs)
+
+    def rhs_points(self, rhs):
+        assert rhs is legs[2]
+        legs[2] = honest_points(self, rhs)
+        assert all(isinstance(p, tuple) for p in legs[2])
+        return legs[2]
 
     def scan_kernel(n1, n2, nl):
         launched.append((n1, n2, nl))
@@ -111,6 +123,7 @@ def host_kernels(monkeypatch):
         return lambda lhs, rhs: jnp.asarray(legs_hold(*legs))
 
     monkeypatch.setattr(B.TpuBackend, "_build_legs", build_legs)
+    monkeypatch.setattr(B.TpuBackend, "_rhs_points", rhs_points)
     monkeypatch.setattr(B, "_scan_kernel", scan_kernel)
     monkeypatch.setattr(B, "_pair_kernel", pair_kernel)
     monkeypatch.setattr(B, "_compile_pair_kernel_early", lambda n_pairs: None)

@@ -254,14 +254,16 @@ def test_counters_equal_the_spans_they_sit_beside(flushed):
     assert {k: v for k, v in counters.items() if ".requests." in k} == {
         "crypto.tpu.requests.sig_share": script["n"]
     }
+    # one document a check, hashed once however many shares sign it
     assert (
         names["crypto.tpu.hash_to_g2"]
         == counters["crypto.tpu.hash_to_g2_calls"]
-        == script["rows"]
+        == counters["crypto.tpu.rhs_hashed"]
+        == script["checks"]
     )
     # every check is one of each of its stages
     for stage in ("scan_prep", "coefficients", "build_legs", "pack",
-                  "scan_dispatch", "pair_dispatch", "verdict_sync"):
+                  "scan_dispatch", "rhs_prep", "pair_dispatch", "verdict_sync"):
         assert names["crypto.tpu." + stage] == script["checks"], stage
     # and the timers the ``stats`` op exports count the same
     for name, n in names.items():
@@ -274,9 +276,10 @@ def test_counters_equal_the_spans_they_sit_beside(flushed):
 
 
 def test_a_check_holds_its_own_dispatches_and_the_next_groups_prep(flushed):
-    """One scan launch, one pair launch and one sync a check, in that
-    order; a ``scan_prep`` before them is the check's own, one between the
-    pair's dispatch and the sync is the next check's, prepared ahead."""
+    """One scan launch, the right-hand points, one pair launch and one sync
+    a check, in that order; a ``scan_prep`` before them is the check's own,
+    one between the pair's dispatch and the sync is the next check's,
+    prepared ahead."""
     script, _, spans = flushed
     checks = [s for s in spans if s[1] == "crypto.tpu.check"]
     ahead = 0
@@ -285,11 +288,14 @@ def test_a_check_holds_its_own_dispatches_and_the_next_groups_prep(flushed):
         for _, name, s, e, a in spans:
             if name.startswith("crypto.tpu.") and start <= s and e <= end:
                 inside.setdefault(name[len("crypto.tpu."):], []).append((s, e, a))
-        (scan,), (pair,), (sync,) = (
+        (scan,), (rhs,), (pair,), (sync,) = (
             inside[stage]
-            for stage in ("scan_dispatch", "pair_dispatch", "verdict_sync")
+            for stage in ("scan_dispatch", "rhs_prep", "pair_dispatch", "verdict_sync")
         )
-        assert scan[1] <= pair[0] and pair[1] <= sync[0]
+        assert scan[1] <= rhs[0] and rhs[1] <= pair[0] and pair[1] <= sync[0]
+        (hashed,) = inside["hash_to_g2"]
+        assert rhs[0] <= hashed[0] and hashed[1] <= rhs[1]
+        assert rhs[2] == {"legs": 1, "hashed": 1}
         for s, e, a in inside.get("scan_prep", ()):
             if e <= scan[0]:
                 assert a["rows"] == args["rows"]
@@ -313,12 +319,13 @@ def test_spans_nest_on_the_flush_line_and_rpcs_carry_the_flushs_id(flushed):
     by_name = {}
     for s in spans:
         by_name.setdefault(s[1], []).append(s)
-    # a check holds its stages, scan_prep holds its three parts
+    # a check holds its stages, scan_prep holds its three parts, the hash
+    # lies in rhs_prep
     for parent, children in [
-        ("crypto.tpu.check", ["scan_prep", "scan_dispatch", "pair_dispatch",
-                              "verdict_sync"]),
+        ("crypto.tpu.check", ["scan_prep", "scan_dispatch", "rhs_prep",
+                              "pair_dispatch", "verdict_sync"]),
         ("crypto.tpu.scan_prep", ["coefficients", "build_legs", "pack"]),
-        ("crypto.tpu.build_legs", ["hash_to_g2"]),
+        ("crypto.tpu.rhs_prep", ["hash_to_g2"]),
     ]:
         for child in children:
             for _, _, s, e, _ in by_name["crypto.tpu." + child]:
@@ -561,11 +568,144 @@ def test_the_row_and_request_counters_of_a_decrypt_flush(decrypt_requests, reque
     assert counters["crypto.tpu.rows"] == sum(a["rows"] for a in preps)
     legs = [(s, e) for _, name, s, e, _ in spans if name == "crypto.tpu.build_legs"]
     assert len(legs) == len(preps) == counters["crypto.tpu.checks"]
+    # every group is on the one ciphertext: its hash input hashed once a
+    # group under the group's scan; ``W`` comes ready wherever a share does
+    rhs = [(s, e, a) for _, name, s, e, a in spans if name == "crypto.tpu.rhs_prep"]
+    assert [a for _, _, a in rhs] == [
+        {"legs": 1 + bool(c["dec_share"]), "hashed": 1} for c in by_kind
+    ]
     hashes = [(s, e) for _, name, s, e, _ in spans if name == "crypto.tpu.hash_to_g2"]
-    assert len(hashes) == counters["crypto.tpu.hash_to_g2_calls"] == sum(
-        c["dec_share"] + c["ciphertext"] for c in by_kind
+    assert (
+        len(hashes) == counters["crypto.tpu.hash_to_g2_calls"]
+        == counters["crypto.tpu.rhs_hashed"] == len(prepared)
     )
-    assert all(any(ls <= s and e <= le for ls, le in legs) for s, e in hashes)
+    assert all(any(rs <= s and e <= re for rs, re, _ in rhs) for s, e in hashes)
+    assert not any(ls <= s and e <= le for ls, le in legs for s, e in hashes)
+
+
+# -- the hash to G2 runs under the dispatched scan ------------------------------
+
+# name -> (kind, requests, wrong positions, CHUNK or None)
+ORDER_CASES = {
+    "sig_16": ("sig", 16, (), None),
+    "dec_15": ("dec", 15, (), None),
+    "check_and_15": ("check+dec", 15, (), None),
+    "sig_16_byz5": ("sig", 16, BYZ5, None),
+    "dec_15_bisected": ("dec", 15, _seeded(15, 3), None),
+    "sig_16_in_4_chunks": ("sig", 16, (), 4),
+    # the wrong share lies in the last chunk: see ``verdict`` below
+    "sig_16_in_4_chunks_bisected": ("sig", 16, (13,), 4),
+    "dec_15_in_2_chunks": ("dec", 15, (), 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_the_hash_runs_after_the_scans_dispatch_and_before_the_pairs(
+    requests, decrypt_requests, monkeypatch, case
+):
+    """A check is ``scan_prep``, ``scan_dispatch``, ``rhs_prep`` (the
+    ``hash_to_g2`` spans inside it, one a distinct leg that brings no point:
+    16 shares on one document hash once, 15 decryption shares on one
+    ciphertext once, their ``W`` ready), ``pair_dispatch``, ``verdict_sync``:
+    on the flush's check, on every group of a bisection level (the next
+    group still prepared between ``pair_dispatch`` and ``verdict_sync``) and,
+    where a flush is several chunks, with every chunk's scan dispatched
+    before the first hash."""
+    kind, n, wrong, chunk = ORDER_CASES[case]
+    suite, sig_reqs = requests
+    if kind == "sig":
+        reqs = sig_reqs[:n]
+    else:
+        reqs = decrypt_requests[0 if kind == "check+dec" else 1:][: n + (kind == "check+dec")]
+    reqs = [VerifyRequest(r.kind, r.payload) for r in reqs]
+    bad = {id(reqs[i]) for i in wrong}
+    chunks = -(-len(reqs) // chunk) if chunk else 0
+    pair_calls = []
+
+    def verdict(group):
+        # the stub hands over the group prepared last, which is not what a
+        # flush of several chunks checks first: all of it, then (after a
+        # failure) chunk by chunk, before bisection prepares anything more
+        pair_calls.append(group)
+        if 1 < len(pair_calls) <= 1 + chunks:
+            group = reqs[(len(pair_calls) - 2) * chunk :][:chunk]
+        elif chunks and len(pair_calls) == 1:
+            group = reqs
+        return not bad & {id(r) for r in group}
+
+    prepared = stub_kernels(monkeypatch, verdict)
+    metrics = Metrics()
+    backend = stubbed_backend(suite, metrics)
+    if chunk:
+        backend.CHUNK = chunk
+    session = Session()
+    try:
+        got = backend.verify_batch(reqs)
+    finally:
+        spans = session.spans()
+    assert got == [i not in wrong for i in range(len(reqs))]
+
+    def named(stage):
+        return sorted(
+            (s, e, a) for _, name, s, e, a in spans if name == "crypto.tpu." + stage
+        )
+
+    def within(inner, outer):
+        return [x for x in inner if any(o[0] <= x[0] and x[1] <= o[1] for o in outer)]
+
+    hashes, rhs_preps = named("hash_to_g2"), named("rhs_prep")
+    # one rhs_prep a scan that was dispatched; a group's legs by its kinds
+    assert len(rhs_preps) == len(named("scan_dispatch")) == len(prepared)
+    want = []
+    for group in prepared:
+        kinds = {r.kind for r in group}
+        want.append({"legs": 1 + ("dec_share" in kinds), "hashed": 1})
+    assert [a for _, _, a in rhs_preps] == want
+    counters = metrics.counters
+    assert (
+        len(hashes) == counters["crypto.tpu.hash_to_g2_calls"]
+        == counters["crypto.tpu.rhs_hashed"] == len(prepared)
+    )
+    assert within(hashes, rhs_preps) == hashes
+    for stage in ("scan_prep", "build_legs", "scan_dispatch", "pair_dispatch"):
+        assert within(hashes, named(stage)) == [], stage
+    checks = named("check")
+    if chunk is None or wrong:
+        # the one-chunk check, and every group of bisection
+        one_chunk = [c for c in checks if within(named("scan_dispatch"), [c])]
+        assert len(one_chunk) == len(checks) if chunk is None else one_chunk
+        ahead = 0
+        for check in one_chunk:
+            (scan,), (rhs,), (pair,), (sync,) = (
+                within(named(stage), [check])
+                for stage in ("scan_dispatch", "rhs_prep", "pair_dispatch", "verdict_sync")
+            )
+            assert scan[1] <= rhs[0] and rhs[1] <= pair[0] and pair[1] <= sync[0]
+            (hashed,) = within(hashes, [check])
+            assert scan[1] <= hashed[0] and hashed[1] <= pair[0]
+            for prep in within(named("scan_prep"), [check]):
+                if prep[1] > scan[0]:
+                    assert pair[1] <= prep[0] and prep[1] <= sync[0]
+                    ahead += 1
+        assert ahead == counters["crypto.tpu.prepared_ahead"]
+        assert (ahead > 0) == (len(wrong) > 0)
+    if chunk:
+        # the flush's chunks: every scan dispatched, then every chunk's
+        # hash, then the one pair stage; all of it beside the checks
+        assert [len(g) for g in prepared[:chunks]] == [
+            len(reqs[i : i + chunk]) for i in range(0, len(reqs), chunk)
+        ]
+        scans, rhs = named("scan_dispatch")[:chunks], rhs_preps[:chunks]
+        assert max(e for _, e, _ in scans) <= min(s for s, _, _ in hashes)
+        assert max(e for _, e, _ in scans) <= rhs[0][0]
+        assert rhs[-1][1] <= named("pair_dispatch")[0][0] and rhs[-1][1] <= checks[0][0]
+        assert within(scans + rhs + named("scan_prep")[:chunks], checks) == []
+        # a failed combined check re-checks chunk by chunk on the points it
+        # has: no second hash before bisection prepares its groups
+        assert len(within(named("pair_dispatch"), checks[: 1 + chunks * bool(wrong)])) == (
+            1 + chunks * bool(wrong)
+        )
+        assert within(hashes, checks[: 1 + chunks * bool(wrong)]) == []
 
 
 def test_stats_op_of_an_eager_worker_keeps_its_shape():
@@ -611,10 +751,12 @@ def test_stats_op_of_an_eager_worker_keeps_its_shape():
     [
         (B._scan_kernel, (16, 16, 2), "hbbft_scan_16_16_2"),
         (B._pair_kernel, (3,), "hbbft_pair_3"),
+        (B._join_kernel, (3,), "hbbft_join_3"),
     ],
 )
 def test_the_two_programs_have_names_of_their_own(kernel, shape, name):
     """``jax.jit`` names a module ``jit_<function name>``: read off the
-    jitted object, nothing is lowered."""
+    jitted object, nothing is lowered.  The join between them is neither a
+    ``jit_hbbft_scan_`` nor a ``jit_hbbft_pair_`` module."""
     jitted = kernel(*shape)
     assert jitted.__name__ == jitted.__wrapped__.__name__ == name

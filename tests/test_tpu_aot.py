@@ -13,8 +13,9 @@ cases live in this one file, and every compile happens in the test's
 own process with the persistent compile cache off (an entry written for
 a described chip cannot be read back without one).
 
-The fast cases are what every flush is made of (seconds each).  The
-three flush programs chip_smoke.py runs are minutes each: ``slow``.
+The fast cases are what every flush is made of (seconds each), the join
+between a flush's two programs among them.  The three flush programs
+chip_smoke.py runs are minutes each: ``slow``.
 """
 
 import random
@@ -122,6 +123,30 @@ def test_kernel_compiles_for_v5e(case, one_chip, cache_off):
         assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_join_program_compiles_for_v5e_into_the_pair_programs_arguments(
+    one_chip, cache_off
+):
+    """``_join_kernel(3)`` on what one chunk's check hands it (the scan's
+    three left-hand rows and its generator-leg row, the two right-hand
+    points put on the device meanwhile): the module a trace shows as
+    ``jit_hbbft_join_3``, whose result is ``_pair_kernel(3)``'s two
+    arguments.  A stubbed scan's ``1 + legs`` rows join to the same."""
+    from hbbft_tpu.crypto.tpu import backend as B
+
+    join = B._join_kernel(3)
+    shapes = ([_g1(3)], [_g2(1)], [_g2(2)])
+    lowered = join.lower(*_spec(shapes, one_chip))
+    assert lowered.as_text().split("\n", 1)[0].startswith("module @jit_hbbft_join_3 ")
+    assert lowered.compile().memory_analysis().generated_code_size_in_bytes > 0
+    want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), (_g1(3), _g2(3)))
+    for gen_rows in (1, 3):
+        got = jax.eval_shape(join, [_g1(3)], [_g2(gen_rows)], [_g2(2)])
+        assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), got) == want
+    # several chunks, padded to the bucket
+    got = jax.eval_shape(B._join_kernel(16), [_g1(3)] * 4, [_g2(1)] * 4, [_g2(2)] * 4)
+    assert {int(a.shape[0]) for a in jax.tree_util.tree_leaves(got)} == {16}
+
+
 def _sig_share_reqs(n):
     """``n`` signature shares on one document (8 signatures reused, as
     chip_smoke.py builds a chunk)."""
@@ -173,23 +198,26 @@ def _dec_share_reqs(n):
 def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache_off):
     """The programs of chip_smoke.py and of the benchmark's cells:
     ``_scan_kernel(16,16,2)``, ``_scan_kernel(2048,2048,2)``, the decrypt
-    burst's ``_scan_kernel(32,16,2)`` and (once) ``_pair_kernel(3)``.
-    Prints seconds and ``memory_analysis()`` for each (run with ``-s``);
+    burst's ``_scan_kernel(32,16,2)``, each lowered from ``_scan_prep``'s
+    arguments (the right-hand points are not among them), and (once)
+    ``_pair_kernel(3)`` on what ``_join_kernel(3)`` makes of the scan's
+    result.  Prints seconds and ``memory_analysis()`` for each (run with ``-s``);
     with ``JAX_ENABLE_X64=0`` it compiles what the worker compiles."""
     from hbbft_tpu.crypto.tpu import backend as B
 
     suite, reqs = make(n_requests)
-    (n1, n2, nl), args = B.TpuBackend(suite)._scan_prep(reqs)
-    assert (n1, n2, nl) == shape
+    (n1, n2, nl), args, rhs = B.TpuBackend(suite)._scan_prep(reqs)
+    assert (n1, n2, nl) == shape and len(args) == 9 and len(rhs) <= nl
     programs = [
         (f"hbbft_scan_{n1}_{n2}_{nl}", B._scan_kernel(n1, n2, nl), args)
     ]
     if shape == (16, 16, 2):
-        _, lhs, rhs = jax.eval_shape(B._scan_kernel(n1, n2, nl), *args)
+        _, lhs, gen_leg = jax.eval_shape(B._scan_kernel(n1, n2, nl), *args)
         n_pairs = int(lhs[3].shape[0])
-        assert n_pairs == 3
+        assert n_pairs == 3 and int(gen_leg[3].shape[0]) == 1
+        pairs = jax.eval_shape(B._join_kernel(n_pairs), [lhs], [gen_leg], [_g2(nl)])
         programs.append(
-            (f"hbbft_pair_{n_pairs}", B._pair_kernel(n_pairs), (lhs, rhs))
+            (f"hbbft_pair_{n_pairs}", B._pair_kernel(n_pairs), pairs)
         )
     for name, kernel, shapes in programs:
         t0 = time.perf_counter()
