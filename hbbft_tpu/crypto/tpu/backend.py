@@ -19,7 +19,11 @@ pair program with a join of a few rows between them:
   one shared final exponentiation.
 
 Kernel shapes are bucketed to powers of two so recompilation is bounded;
-compiled kernels are cached per (n_g1, n_g2, n_legs) bucket.
+compiled kernels are cached per (n_g1, n_g2, n_legs) bucket.  The bucket is
+decided once a chunk, by the chunk's own rows, and every group bisection
+cuts from the chunk is prepared in it (``crypto/flush_shapes.py``), so a
+flush of any size and its fault isolation run in one scan program and one
+pair program.
 
 Multi-chip: with ``shard=True`` and more than one visible device, the
 batch axis is laid out over a data-parallel ``jax.sharding.Mesh`` — the
@@ -54,6 +58,7 @@ from hbbft_tpu.crypto.backend import (
 )
 from hbbft_tpu.crypto.bls import curve as ocurve
 from hbbft_tpu.crypto.bls.suite import BLSSuite
+from hbbft_tpu.crypto.flush_shapes import group_shape as _group_shape
 from hbbft_tpu.crypto.flush_shapes import pairs_bucket as _pairs_bucket
 from hbbft_tpu.crypto.flush_shapes import scan_shape as _scan_shape
 from hbbft_tpu.crypto.tpu import curve as dcurve
@@ -239,6 +244,17 @@ def _shard_mesh(max_devices: int = 16):
     return Mesh(np.array(devs[:n]).reshape(n), axis_names=("dp",))
 
 
+class _Group(list):
+    """The requests of one of bisection's groups, with the scan shape of
+    the chunk they were cut from: what :meth:`TpuBackend._scan_prep` pads
+    the group to (``flush_shapes.group_shape``).  A list that carries it,
+    because ``_scan_prep`` takes the requests and nothing else."""
+
+    def __init__(self, reqs, chunk_shape: Tuple[int, int, int]) -> None:
+        super().__init__(reqs)
+        self.chunk_shape = chunk_shape
+
+
 class TpuBackend(CryptoBackend):
     """RLC batch verification with the group algebra on the accelerator.
 
@@ -260,7 +276,9 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.hash_to_g2`` (``bytes``) a document or ciphertext, however
     many requests share it; a ciphertext's ``W`` comes ready) and puts the
     points on the device while the scan runs.  The
-    ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``; inside it
+    ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``, ``handed``:
+    1 where the shape is the chunk's, handed down to a group whose own
+    bucket is smaller, else 0; inside it
     ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs``,
     ``crypto.tpu.pack``: everything the scan program reads) before the
     dispatches is the check's own: the flush's, or that of a bisection
@@ -277,7 +295,8 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.rows`` (requests summed over checks),
     ``crypto.tpu.g1_rows`` and ``crypto.tpu.g2_rows`` (real rows of every
     ``scan_prep``), ``crypto.tpu.rows_padded`` (bucket rows less real
-    rows, G1 and G2 summed), ``crypto.tpu.hash_to_g2_calls``,
+    rows, G1 and G2 summed), ``crypto.tpu.groups_handed_shape`` (the
+    ``scan_prep``s with ``handed`` 1), ``crypto.tpu.hash_to_g2_calls``,
     ``crypto.tpu.rhs_hashed`` (legs hashed in an ``rhs_prep``, so after
     their group's scan was dispatched), ``crypto.tpu.leaves``,
     ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
@@ -376,7 +395,8 @@ class TpuBackend(CryptoBackend):
         is this group's :meth:`_scan_prep` where the check before it made
         it; ``ahead`` the requests of the group checked next, prepared
         here once both programs are dispatched, while the device runs
-        them.  Returns (verdict, what was prepared ahead or None)."""
+        them.  Returns (verdict, the scan shape the check ran in, what was
+        prepared ahead or None)."""
         with self.metrics.span("crypto.tpu.check", rows=len(reqs), depth=depth):
             if prepared is None:
                 prepared = self._scan_prep(reqs)
@@ -385,7 +405,7 @@ class TpuBackend(CryptoBackend):
             if ahead is not None:
                 ahead = self._scan_prep(ahead)
                 self.metrics.count("crypto.tpu.prepared_ahead")
-            return self._verdict(ok_dev, len(reqs)), ahead
+            return self._verdict(ok_dev, len(reqs)), prepared[0], ahead
 
     def _verdict(self, ok_dev, rows: int) -> bool:
         """The host's wait for a dispatched pair stage's verdict; counts
@@ -435,14 +455,21 @@ class TpuBackend(CryptoBackend):
         returns ((n1, n2, nl), kernel args, the legs' right-hand points as
         :meth:`_build_legs` gives them, for :meth:`_rhs_prep`).  Split from
         :meth:`_scan_dispatch` so that bisection prepares a group while
-        the device checks the one before it."""
+        the device checks the one before it.  The shape is the requests'
+        own (``flush_shapes.scan_shape``) for a chunk, and for a
+        :class:`_Group` the one its chunk hands down."""
         with self.metrics.span("crypto.tpu.scan_prep", rows=len(reqs)) as note:
             with self.metrics.span("crypto.tpu.coefficients"):
                 coeffs = _batch_coefficients(self.suite, reqs)
             with self.metrics.span("crypto.tpu.build_legs"):
                 g2e, g1e, rhs = self._build_legs(reqs, coeffs)
-            n1, n2, nl = _scan_shape(reqs, len(g1e), len(g2e), len(rhs))
-            note(n1=n1, n2=n2, legs=nl)
+            own = _scan_shape(reqs, len(g1e), len(g2e), len(rhs))
+            n1, n2, nl = shape = (
+                _group_shape(reqs.chunk_shape, own) if isinstance(reqs, _Group) else own
+            )
+            handed = int(shape != own)
+            note(n1=n1, n2=n2, legs=nl, handed=handed)
+            self.metrics.count("crypto.tpu.groups_handed_shape", handed)
             self.metrics.count("crypto.tpu.g1_rows", len(g1e))
             self.metrics.count("crypto.tpu.g2_rows", len(g2e))
             self.metrics.count(
@@ -567,12 +594,12 @@ class TpuBackend(CryptoBackend):
         if not chunks:
             return out
         if len(chunks) == 1:
-            ok, _ = self._aggregate_ok([reqs[i] for i in idxs])
+            ok, shape, _ = self._aggregate_ok([reqs[i] for i in idxs])
             if ok:
                 for i in idxs:
                     out[i] = True
             else:
-                self._bisect(reqs, idxs, out, depth=1)
+                self._bisect(reqs, idxs, out, shape)
             return out
         # Dispatch every chunk's SCAN kernel before syncing on anything:
         # jax dispatch is async, so the device pipelines the chunks and
@@ -595,12 +622,12 @@ class TpuBackend(CryptoBackend):
             for i in idxs:
                 out[i] = True
             return out
-        for c, part in zip(chunks, scans):
+        for c, (prepared, _), part in zip(chunks, dispatched, scans):
             if check([part], len(c)):
                 for i in c:
                     out[i] = True
             else:
-                self._bisect(reqs, c, out, depth=1)
+                self._bisect(reqs, c, out, prepared[0])
         return out
 
     def _bisect(
@@ -608,10 +635,12 @@ class TpuBackend(CryptoBackend):
         all_reqs: List[VerifyRequest],
         idxs: List[int],
         out: List[bool],
-        depth: int,
+        shape: Tuple[int, int, int],
     ) -> None:
-        """Bisection fallback — the caller knows idxs' aggregate FAILED
-        (``depth``: how many splits lie above its halves).
+        """Bisection fallback — the caller knows idxs' aggregate FAILED, in
+        the scan program of ``shape``: the chunk's, in which every group
+        below is prepared too (:class:`_Group`), so that isolating a wrong
+        share compiles and loads nothing the flush did not.
 
         Level by level: a level is the halves of every group that failed
         on the level above, checked in order.  Both halves of a failed
@@ -622,6 +651,7 @@ class TpuBackend(CryptoBackend):
         group of one whose check fails is convicted by it (class
         docstring): every verdict is the device's."""
         failed = [idxs]
+        depth = 1  # how many splits lie above the level's groups
         while failed:
             groups: List[List[int]] = []
             for g in failed:
@@ -631,11 +661,11 @@ class TpuBackend(CryptoBackend):
                         out[g[0]] = False
                 else:
                     groups += [g[: len(g) // 2], g[len(g) // 2 :]]
-            batches = [[all_reqs[i] for i in g] for g in groups]
+            batches = [_Group((all_reqs[i] for i in g), shape) for g in groups]
             failed = []
             prepared = None
             for g, batch, ahead in zip(groups, batches, batches[1:] + [None]):
-                ok, prepared = self._aggregate_ok(batch, depth, prepared, ahead)
+                ok, _, prepared = self._aggregate_ok(batch, depth, prepared, ahead)
                 if ok:
                     for i in g:
                         out[i] = True

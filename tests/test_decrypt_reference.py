@@ -8,8 +8,8 @@ evaluation of the legs that ``_build_legs`` made for the group being checked,
 their right-hand points as ``_rhs_points`` resolved them under the dispatched
 scan: the oracle's scalar multiplications, its r-torsion check on every marked row
 and its product of pairings.  So leg construction for ``dec_share`` and
-``ciphertext``, the floor, bisection and the verdict logic under test are
-the program's own, and no XLA flush program is compiled.  Every answer is
+``ciphertext``, the shapes (the floor; a chunk's shape handed down to its
+groups), bisection and the verdict logic under test are the program's own, and no XLA flush program is compiled.  Every answer is
 compared with ``chipbench.kinds.<kind>.verify`` on that request's wire bytes.
 The slow tier makes the same comparison on the real kernels.
 
@@ -157,6 +157,45 @@ def test_the_program_answers_a_decrypt_flush_as_the_plain_reference(
     # the flush and every group of it, the lone check among them
     assert set(launched) == {(32, 16, 2)}
     assert len(launched) == (1 if wrong_kind is None else 5 if got[0] else 3)
+
+
+# -- a flush over the floor: the shape handed down (PR 35) ----------------------
+
+HB18 = {"name": "hb18", "threshold": 5, "validators": 18}
+
+
+@pytest.mark.parametrize("wrong_kind", [None, "next_key", "identity", "other_w"])
+def test_a_flush_over_the_floor_and_its_groups_run_in_one_program(monkeypatch, wrong_kind):
+    """The smallest decrypt flush that crosses a bucket: the check and 17
+    shares, 35 G1 rows, ``scan(64,16,2)``, whose halves (17, 18, 8, 9, ...
+    rows) the rule up to PR 34 sent to ``scan(32,16,2)``.  The chunk's shape
+    is handed down, so the flush and every group bisection makes of it ask
+    for that one program, and the answers are the plain reference's."""
+    params = {"requests": 18, "ciphertext_checks": 1, "payload_bytes": 24}
+    if wrong_kind:
+        params.update(wrong=1, wrong_kinds=[wrong_kind])
+    seed = 3500003502
+    keys = decrypt_flushes.make_keys(HB18, params, seed)
+    flush = decrypt_flushes.make_flush(HB18, params, seed, 1, keys)
+    assert flush.kinds == ["ciphertext"] + ["dec_share"] * 17
+    launched = host_kernels(monkeypatch)
+    backend = B.TpuBackend(BLSSuite())
+
+    def no_oracle(reqs):
+        raise AssertionError("the oracle was asked for a verdict")
+
+    backend._eager.verify_batch = no_oracle
+    got = backend.verify_batch(flush.requests)
+    assert got == _by_reference(flush) == flush.expected
+    assert got.count(False) == (1 if wrong_kind else 0)
+    assert set(launched) == {(64, 16, 2)}
+    counters = backend.metrics.counters
+    assert len(launched) == counters["crypto.tpu.checks"]
+    # the point at infinity is a well-formed point and is bisected like the
+    # others; every group below the flush is smaller than it: all of them
+    # were handed its shape
+    assert (len(launched) > 1) == (wrong_kind is not None)
+    assert counters.get("crypto.tpu.groups_handed_shape", 0) == len(launched) - 1
 
 
 @pytest.mark.slow

@@ -415,9 +415,9 @@ def test_bisection_answers_the_wrong_set_with_the_recursions_checks(
 # -- the shapes a flush and its groups ask for ---------------------------------
 
 @pytest.fixture(scope="module")
-def decrypt_requests(requests):
-    """One ciphertext's decrypt phase at N = 16: the ciphertext check, then
-    the 15 other validators' decryption shares on it."""
+def wan_requests(requests):
+    """One ciphertext's decrypt phase at N = 104: the ciphertext check, then
+    the 103 other validators' decryption shares on it."""
     suite, _ = requests
     rng = random.Random(30)
     sks = SecretKeySet.random(1, rng, suite)
@@ -427,8 +427,14 @@ def decrypt_requests(requests):
         VerifyRequest.dec_share(
             pks.public_key_share(i), ct, sks.secret_key_share(i).decryption_share(ct)
         )
-        for i in range(15)
+        for i in range(103)
     ]
+
+
+@pytest.fixture(scope="module")
+def decrypt_requests(wan_requests):
+    """The same at N = 16: the check, then the 15 other validators' shares."""
+    return wan_requests[:16]
 
 
 def _seeded(n, k, seed=30):
@@ -446,6 +452,10 @@ SHAPE_CASES = {
     "dec_8_bisected": ("dec", 8, _seeded(8, 2)),
     "dec_15_bisected": ("dec", 15, _seeded(15, 3)),
     "check_and_15_bisected": ("check+dec", 15, (0,) + tuple(1 + i for i in _seeded(15, 2))),
+    "dec_17_bisected": ("dec", 17, _seeded(17, 2)),
+    "dec_103": ("dec", 103, ()),
+    "dec_103_bisected": ("dec", 103, _seeded(103, 2)),
+    "check_and_103_bisected": ("check+dec", 103, (0,) + tuple(1 + i for i in _seeded(103, 1))),
     "sig_2": ("sig", 2, ()),
     "sig_16": ("sig", 16, ()),
     "sig_16_byz5": ("sig", 16, BYZ5),
@@ -462,18 +472,21 @@ PADDED = {
 
 @pytest.mark.parametrize("case", sorted(SHAPE_CASES))
 def test_a_flush_and_every_group_of_it_ask_for_one_scan_program(
-    requests, decrypt_requests, monkeypatch, case
+    requests, wan_requests, monkeypatch, case
 ):
     """A decrypt burst of up to 16 requests and every group bisection makes
     of it, down to a lone share or the lone check: ``scan(32,16,2)`` and
     ``pair(3)`` and nothing else.  The coin's flushes keep the programs and
-    the padding they had; a flush above the floor buckets as before."""
+    the padding they had; a flush above the floor buckets as before, and
+    its groups run in ITS program (PR 35: the shape is handed down): a
+    104-node network's burst of 103 shares and every group of it in
+    ``scan(256,16,2)`` and ``pair(3)``."""
     kind, n, wrong = SHAPE_CASES[case]
     suite, sig_reqs = requests
     if kind == "sig":
         reqs = [sig_reqs[i % 16] for i in range(n)]
     else:
-        reqs = decrypt_requests[0 if kind == "check+dec" else 1:][: n + (kind == "check+dec")]
+        reqs = wan_requests[0 if kind == "check+dec" else 1:][: n + (kind == "check+dec")]
     # 40 shares are of 16 signers: an object each, to tell them by position
     reqs = [VerifyRequest(r.kind, r.payload) for r in reqs]
     bad = {id(reqs[i]) for i in wrong}
@@ -488,16 +501,29 @@ def test_a_flush_and_every_group_of_it_ask_for_one_scan_program(
     assert {shape for shape in launched if shape[0] == "pair"} == {("pair", 3)}
     if kind == "sig":
         # one G1 and one G2 row a share: the bucket with the floor of 16 rows
-        want = [(flush_shapes.bucket(len(g)),) * 2 + (2,) for g in prepared]
+        own = [(flush_shapes.bucket(len(g)),) * 2 + (2,) for g in prepared]
+        want = [own[0]] * len(prepared)
         if n <= 16:
-            assert set(want) == {(16, 16, 2)}
+            assert set(want) == set(own) == {(16, 16, 2)}
             assert backend.metrics.counters["crypto.tpu.rows_padded"] == PADDED[case]
         else:
             assert want[0] == (64, 64, 2)
-            assert all(w == (16, 16, 2) for w, g in zip(want, prepared) if len(g) <= 16)
     else:
-        want = [(32, 16, 2)] * len(prepared)
+        # two G1 rows a share and one for the check, which brings the G2 row
+        own = [
+            (flush_shapes.bucket(sum(2 - (r.kind == "ciphertext") for r in g), 32), 16, 2)
+            for g in prepared
+        ]
+        want = [own[0]] * len(prepared)
+        assert want[0] == ((32, 16, 2) if n <= 15 else (64, 16, 2) if n == 17 else (256, 16, 2))
     assert scans == want
+    # the groups whose own bucket is smaller than their flush's, counted
+    handed = sum(o != w for o, w in zip(own, want))
+    assert backend.metrics.counters.get("crypto.tpu.groups_handed_shape", 0) == handed
+    assert (handed > 0) == (len(wrong) > 0 and n > 16)
+    if handed:
+        # below a flush over the floor every group is smaller than its flush
+        assert handed == len(prepared) - 1
     if len(wrong) and n > 1:
         # bisection went down to groups of one, the lone check among them
         lone = [g[0].kind for g in prepared if len(g) == 1]

@@ -167,7 +167,8 @@ def _sig_share_reqs(n):
 
 def _dec_share_reqs(n):
     """``n`` decryption shares of distinct signers on one ciphertext with a
-    proposal of 4000 bytes: the flush of the benchmark's ``hb16.decrypt``."""
+    proposal of 4000 bytes: the flush of the benchmark's ``hb16.decrypt``
+    (15) and ``wan104.decrypt`` (103)."""
     from hbbft_tpu.crypto.backend import VerifyRequest
     from hbbft_tpu.crypto.bls.suite import BLSSuite
     from hbbft_tpu.crypto.keys import SecretKeySet
@@ -192,13 +193,15 @@ def _dec_share_reqs(n):
         (_sig_share_reqs, 16, (16, 16, 2)),
         (_sig_share_reqs, ROWS, (ROWS, ROWS, 2)),
         (_dec_share_reqs, 15, (32, 16, 2)),
+        (_dec_share_reqs, 103, (256, 16, 2)),
     ],
-    ids=["sig_share_16", f"sig_share_{ROWS}", "dec_share_15"],
+    ids=["sig_share_16", f"sig_share_{ROWS}", "dec_share_15", "dec_share_103"],
 )
 def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache_off):
     """The programs of chip_smoke.py and of the benchmark's cells:
     ``_scan_kernel(16,16,2)``, ``_scan_kernel(2048,2048,2)``, the decrypt
-    burst's ``_scan_kernel(32,16,2)``, each lowered from ``_scan_prep``'s
+    bursts' ``_scan_kernel(32,16,2)`` (N = 16) and ``_scan_kernel(256,16,2)``
+    (N = 104), each lowered from ``_scan_prep``'s
     arguments (the right-hand points are not among them), and (once)
     ``_pair_kernel(3)`` on what ``_join_kernel(3)`` makes of the scan's
     result.  Prints seconds and ``memory_analysis()`` for each (run with ``-s``);
