@@ -73,6 +73,37 @@ _IDENT1 = (1, 1, 0)
 _IDENT2 = ((1, 0), (1, 0), (0, 0))
 
 
+def _any_g2_row(g2_inf, g2_chk):
+    """The scan program's predicate for its G2 stage: some G2 row is not
+    the point at infinity, or is checked.  False exactly where every row
+    is padding (:meth:`TpuBackend._pack`: ``_IDENT2``, coefficient 0,
+    ``check`` 0); works on numpy arrays as on traced ones."""
+    return ((g2_inf == 0) | (g2_chk != 0)).any()
+
+
+def _g2_stage(g2_pts, g2_bits_s, g2_bits_q):
+    """The SCAN stage's G2 half: (``sub2``, the r-torsion verdict of every
+    row; ``gen_leg``, the sum of the scaled rows, which the generator pairs
+    with)."""
+    with jax.named_scope("scan_g2"):
+        scaled2, chain2 = dcurve.scalar_mul_rlc_g2(g2_pts, g2_bits_s, g2_bits_q)
+    with jax.named_scope("subgroup"):
+        sub2 = dcurve.endo_subgroup_eq(dcurve.G2_OPS, g2_pts, chain2)
+    with jax.named_scope("leg_sums"):
+        gen_leg = dcurve.tree_sum(dcurve.G2_OPS, scaled2)
+    return sub2, gen_leg
+
+
+def _g2_stage_skipped(g2_pts, g2_bits_s, g2_bits_q):
+    """What :func:`_g2_stage` gives where every row is the point at
+    infinity and unchecked, without computing it: a multiple of the point
+    at infinity is the point at infinity, so ``gen_leg`` is the identity
+    (flag 1: the pair program skips ``e(g1, O)`` = 1), and no row can fail
+    a check nobody asked for, so ``sub2`` is all true."""
+    n_g2 = g2_pts[3].shape[0]
+    return jnp.ones((n_g2,), dtype=bool), dcurve.identity(dcurve.G2_OPS)
+
+
 @lru_cache(maxsize=32)
 def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
     """Compiled SCAN stage for one shape bucket (per-row work).
@@ -93,6 +124,19 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
     itself is the separate :func:`_pair_kernel` stage so several chunks'
     pairs can share ONE batched Miller loop + final exponentiation.
 
+    The G2 stage (:func:`_g2_stage`: the 65-step scan, the r-torsion
+    verdicts, the generator leg's sum) sits under ONE ``jax.lax.cond`` on a
+    predicate the program computes from its own arguments: some G2 row is
+    "not the point at infinity, or checked".  Where none is (every row is
+    padding, as in every flush of ``dec_share`` alone, which brings no G2
+    row) the other branch, :func:`_g2_stage_skipped`, returns what the
+    stage returns for such rows without computing it.  The predicate is
+    BOTH halves: a real G2 row that is the point at infinity (a share a
+    Byzantine signer sends) carries ``check`` = 1 and takes the full stage,
+    so nobody narrows it to the identity flag alone; and it is read on the
+    device from the rows themselves, so nobody moves the choice to the
+    host (no flag, no per-kind test, no second program).
+
     The jitted function is named ``hbbft_scan_<n_g1>_<n_g2>_<n_legs>``, so
     a device trace's module is ``jit_hbbft_scan_...`` whatever a refactor
     renumbers, and its stages sit under the ``jax.named_scope``s
@@ -108,25 +152,25 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         # one LSB-first shared-doubling scan with the [x^2]P check-chain
         # adds unrolled at x^2's 17 static set bits; G2 splits each RLC
         # coefficient as c = q·|x| + s against the psi endomorphism —
-        # a 65-step two-scalar scan.  Soundness: the psi(Q) = [x]Q
+        # a 65-step two-scalar scan, run only where a G2 row is real
+        # (_scan_kernel's docstring).  Soundness: the psi(Q) = [x]Q
         # identity the decomposition relies on IS the subgroup check
         # verified in this same kernel (fail-closed; see dcurve notes).
         # Equivalence + soundness pinned in tests/test_bls.py and
         # tests/test_tpu_crypto.py.
         with jax.named_scope("scan_g1"):
             scaled1, chain1 = dcurve.scalar_mul_rlc_g1(g1_pts, g1_bits)
-        with jax.named_scope("scan_g2"):
-            scaled2, chain2 = dcurve.scalar_mul_rlc_g2(
-                g2_pts, g2_bits_s, g2_bits_q
-            )
+        sub2, gen_leg = jax.lax.cond(
+            _any_g2_row(g2_pts[3], g2_chk),
+            _g2_stage, _g2_stage_skipped,
+            g2_pts, g2_bits_s, g2_bits_q,
+        )
         with jax.named_scope("subgroup"):
             sub1 = dcurve.endo_subgroup_eq(dcurve.G1_OPS, g1_pts, chain1)
-            sub2 = dcurve.endo_subgroup_eq(dcurve.G2_OPS, g2_pts, chain2)
             sub_ok = (
                 jnp.all(sub1 | (g1_chk == 0)) & jnp.all(sub2 | (g2_chk == 0))
             )
         with jax.named_scope("leg_sums"):
-            gen_leg = dcurve.tree_sum(dcurve.G2_OPS, scaled2)
             leg_sums = []
             for l in range(n_legs):
                 masked = dcurve.select(
@@ -278,7 +322,8 @@ class TpuBackend(CryptoBackend):
     points on the device while the scan runs.  The
     ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``, ``handed``:
     1 where the shape is the chunk's, handed down to a group whose own
-    bucket is smaller, else 0; inside it
+    bucket is smaller, else 0; ``g2``: 0 where the group brings no G2 row,
+    so the scan program skips its G2 stage, else 1; inside it
     ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs``,
     ``crypto.tpu.pack``: everything the scan program reads) before the
     dispatches is the check's own: the flush's, or that of a bisection
@@ -296,7 +341,8 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.g1_rows`` and ``crypto.tpu.g2_rows`` (real rows of every
     ``scan_prep``), ``crypto.tpu.rows_padded`` (bucket rows less real
     rows, G1 and G2 summed), ``crypto.tpu.groups_handed_shape`` (the
-    ``scan_prep``s with ``handed`` 1), ``crypto.tpu.hash_to_g2_calls``,
+    ``scan_prep``s with ``handed`` 1), ``crypto.tpu.g2_stage_skipped`` (those
+    with ``g2`` 0), ``crypto.tpu.hash_to_g2_calls``,
     ``crypto.tpu.rhs_hashed`` (legs hashed in an ``rhs_prep``, so after
     their group's scan was dispatched), ``crypto.tpu.leaves``,
     ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
@@ -468,10 +514,13 @@ class TpuBackend(CryptoBackend):
                 _group_shape(reqs.chunk_shape, own) if isinstance(reqs, _Group) else own
             )
             handed = int(shape != own)
-            note(n1=n1, n2=n2, legs=nl, handed=handed)
+            # Every G2 entry is checked, so "no entry" is the scan
+            # program's own predicate (_any_g2_row), read on the host.
+            note(n1=n1, n2=n2, legs=nl, handed=handed, g2=int(bool(g2e)))
             self.metrics.count("crypto.tpu.groups_handed_shape", handed)
             self.metrics.count("crypto.tpu.g1_rows", len(g1e))
             self.metrics.count("crypto.tpu.g2_rows", len(g2e))
+            self.metrics.count("crypto.tpu.g2_stage_skipped", int(not g2e))
             self.metrics.count(
                 "crypto.tpu.rows_padded", n1 - len(g1e) + n2 - len(g2e)
             )

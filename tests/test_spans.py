@@ -609,6 +609,66 @@ def test_the_row_and_request_counters_of_a_decrypt_flush(decrypt_requests, reque
     assert not any(ls <= s and e <= le for ls, le in legs for s, e in hashes)
 
 
+# -- the scan program's G2 stage: which groups let it be skipped ----------------
+
+# name -> (kind, requests, wrong positions)
+G2_STAGE_CASES = {
+    "dec_15": ("dec", 15, ()),
+    "sig_16": ("sig", 16, ()),
+    "check_and_15": ("check+dec", 15, ()),
+    "sig_2_and_dec_8": ("sig+dec", 10, ()),
+    "dec_15_bisected": ("dec", 15, _seeded(15, 3)),
+    "sig_16_byz5": ("sig", 16, BYZ5),
+    "check_and_15_bisected": ("check+dec", 15, (0,) + tuple(1 + i for i in _seeded(15, 2))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(G2_STAGE_CASES))
+def test_the_g2_stage_is_counted_skipped_where_a_group_brings_no_g2_row(
+    requests, decrypt_requests, monkeypatch, case
+):
+    """``crypto.tpu.g2_stage_skipped`` and ``scan_prep``'s note ``g2``: a
+    flush of decryption shares alone and every group of it skip the stage,
+    a coin flush and its groups never do, and of a decrypt phase's
+    bisection exactly the groups that hold the ciphertext check (its ``W``
+    is the G2 row) run it."""
+    kind, n, wrong = G2_STAGE_CASES[case]
+    suite, sig_reqs = requests
+    if kind == "sig":
+        reqs = sig_reqs[:n]
+    elif kind == "sig+dec":
+        reqs = sig_reqs[:2] + decrypt_requests[1 : n - 1]
+    else:
+        reqs = decrypt_requests[0 if kind == "check+dec" else 1:][: n + (kind == "check+dec")]
+    reqs = [VerifyRequest(r.kind, r.payload) for r in reqs]
+    bad = {id(reqs[i]) for i in wrong}
+    prepared = stub_kernels(monkeypatch, lambda group: not bad & {id(r) for r in group})
+    metrics = Metrics()
+    backend = stubbed_backend(suite, metrics)
+    session = Session()
+    try:
+        got = backend.verify_batch(reqs)
+    finally:
+        spans = session.spans()
+    assert got == [i not in wrong for i in range(len(reqs))]
+    # a sig_share's share and a ciphertext's W are the G2 rows there are
+    want = [int(any(r.kind != "dec_share" for r in group)) for group in prepared]
+    notes = sorted((s, a) for _, name, s, _, a in spans if name == "crypto.tpu.scan_prep")
+    assert [a["g2"] for _, a in notes] == want
+    counters = metrics.counters
+    skipped = counters.get("crypto.tpu.g2_stage_skipped", 0)
+    assert skipped == want.count(0)
+    assert len(want) == counters["crypto.tpu.checks"]
+    assert (counters["crypto.tpu.g2_rows"] == 0) == (skipped == len(want))
+    if kind == "dec":
+        assert skipped == len(want)
+    elif kind == "check+dec" and wrong:
+        # the decrypt cells' probe: some groups of it took each branch
+        assert 0 < skipped < len(want)
+    else:
+        assert skipped == 0
+
+
 # -- the hash to G2 runs under the dispatched scan ------------------------------
 
 # name -> (kind, requests, wrong positions, CHUNK or None)

@@ -204,7 +204,9 @@ def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache
     (N = 104), each lowered from ``_scan_prep``'s
     arguments (the right-hand points are not among them), and (once)
     ``_pair_kernel(3)`` on what ``_join_kernel(3)`` makes of the scan's
-    result.  Prints seconds and ``memory_analysis()`` for each (run with ``-s``);
+    result.  Every scan program's optimized HLO holds a ``conditional`` (the
+    G2 stage, run only where a flush brings a G2 row).  Prints seconds and
+    ``memory_analysis()`` for each (run with ``-s``);
     with ``JAX_ENABLE_X64=0`` it compiles what the worker compiles."""
     from hbbft_tpu.crypto.tpu import backend as B
 
@@ -233,6 +235,10 @@ def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache
             f"module @jit_{name} "
         )
         mem = compiled.memory_analysis()
+        if name.startswith("hbbft_scan_"):
+            # the G2 stage's ``cond`` is still a branch on the chip (XLA did
+            # not turn it into a select, which would run both sides)
+            assert " conditional(" in compiled.as_text()
         print(
             f"\nAOT v5e {name} x64={jax.config.jax_enable_x64}: "
             f"lower {t1 - t0:.0f} s, whole compile {t2 - t0:.0f} s, "

@@ -304,6 +304,103 @@ def test_a_wrong_share_alone_in_a_flush_is_convicted_by_the_device():
     assert counters["crypto.tpu.prepared_ahead"] == 0
 
 
+def _decrypt_phase(seed, n=15):
+    """(suite, the ciphertext check, ``n`` decryption shares of distinct
+    signers on it, a valid share of the NEXT key index, the point at
+    infinity as a share)."""
+    from hbbft_tpu.crypto.keys import DecryptionShare
+
+    suite = BLSSuite()
+    rngpy = random.Random(seed)
+    sks = SecretKeySet.random(2, rngpy, suite)
+    pks = sks.public_keys()
+    ct = pks.public_key().encrypt(rngpy.randbytes(250), rngpy)
+
+    def share(i, by=None):
+        dec = sks.secret_key_share(i if by is None else by).decryption_share(ct)
+        return VerifyRequest.dec_share(pks.public_key_share(i), ct, dec)
+
+    infinity = VerifyRequest.dec_share(
+        pks.public_key_share(0), ct, DecryptionShare(suite.g1_identity(), suite)
+    )
+    return (
+        suite, VerifyRequest.ciphertext(ct), [share(i) for i in range(n)],
+        share(3, by=4), infinity,
+    )
+
+
+@heavy_compile
+def test_the_g2_stages_two_branches_agree_where_no_g2_row_is_real(monkeypatch):
+    """A burst of decryption shares brings no G2 row, so the scan program
+    skips its G2 stage.  The same arguments through a program whose
+    predicate is forced true (the stage as it ran before it could be
+    skipped: sixteen points at infinity times zero) give the same
+    ``sub_ok``, the same left-hand sums, a ``gen_leg`` that is the point at
+    infinity either way, and the same pair verdict: on a clean burst and on
+    one with a wrong share."""
+    from hbbft_tpu.crypto.tpu import backend as B
+
+    suite, _, shares, wrong, _ = _decrypt_phase(81)
+    backend = TpuBackend(suite)
+    short_kernel = B._scan_kernel(32, 16, 2)
+    monkeypatch.setattr(B, "_any_g2_row", lambda inf, chk: jnp.asarray(True))
+    full_kernel = B._scan_kernel.__wrapped__(32, 16, 2)  # past the cache
+    for reqs, want in ((shares, True), (shares[:7] + [wrong] + shares[8:], False)):
+        prepared = backend._scan_prep(reqs)
+        shape, args, _ = prepared
+        assert shape == (32, 16, 2)
+        assert not np.asarray(args[4][3] == 0).any() and not np.asarray(args[7]).any()
+        short, full = short_kernel(*args), full_kernel(*args)
+        assert bool(short[0]) is bool(full[0]) is True
+        for a, b in zip(short[1], full[1]):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        assert int(short[2][3][0]) == int(full[2][3][0]) == 1
+        verdicts = [
+            bool(backend._check_parts([backend._rhs_prep(prepared, scan)]))
+            for scan in (short, full)
+        ]
+        assert verdicts == [want, want]
+
+
+@heavy_compile
+def test_a_decrypt_phases_flushes_are_answered_as_the_batched_backend_answers_them():
+    """Both branches of the scan program's G2 stage against the host's RLC
+    backend: a burst with one wrong share (every group of its bisection
+    skips the stage), the burst with its ciphertext check and a wrong share
+    (the groups that hold the check run it), and the point at infinity
+    alone in a flush: as a decryption share (a G1 row: skipped, and
+    convicted) and as a signature share (a G2 row that IS the point at
+    infinity and is checked: the full stage, and convicted)."""
+    from hbbft_tpu.crypto.keys import SignatureShare
+
+    suite, check, shares, wrong, infinity = _decrypt_phase(82)
+    host = BatchedBackend(suite)
+    burst = shares[:7] + [wrong] + shares[8:]
+    sks = SecretKeySet.random(1, random.Random(83), suite)
+    sig_infinity = VerifyRequest.sig_share(
+        sks.public_keys().public_key_share(0), b"doc",
+        SignatureShare(suite.g2_identity(), suite),
+    )
+    for reqs, skipped_all in (
+        (burst, True),
+        ([check] + burst, False),
+        ([infinity], True),
+        ([sig_infinity], False),
+    ):
+        backend = TpuBackend(suite)
+        got = backend.verify_batch(reqs)
+        assert got == host.verify_batch(reqs)
+        assert not all(got)
+        counters = backend.metrics.counters
+        skipped = counters.get("crypto.tpu.g2_stage_skipped", 0)
+        if skipped_all:
+            assert skipped == counters["crypto.tpu.checks"]
+        elif len(reqs) == 1:
+            assert skipped == 0
+        else:
+            assert 0 < skipped < counters["crypto.tpu.checks"]
+
+
 @heavy_compile
 def test_device_subgroup_check_and_rejection():
     """TpuBackend rejects a share forged from a non-subgroup point (the
@@ -366,6 +463,25 @@ def test_tpu_backend_sharded_flush_matches():
     want = [True] * 16
     want[5] = False
     assert got == want
+
+
+@heavy_compile
+def test_tpu_backend_sharded_decrypt_flush_skips_its_g2_stage_and_matches():
+    """The same mesh on a burst of decryption shares with one wrong: the
+    predicate of the G2 stage's ``cond`` is a reduction over the sharded
+    batch axis, every device takes the skipped branch, and the verdicts
+    are the host RLC backend's."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs a multi-device platform")
+    suite, _, shares, wrong, _ = _decrypt_phase(84)
+    reqs = shares[:7] + [wrong] + shares[8:]
+    sharded = TpuBackend(suite, shard=True)
+    assert sharded._mesh is not None
+    got = sharded.verify_batch(reqs)
+    assert got == BatchedBackend(suite).verify_batch(reqs)
+    assert got == [i != 7 for i in range(15)]
+    counters = sharded.metrics.counters
+    assert counters["crypto.tpu.g2_stage_skipped"] == counters["crypto.tpu.checks"] > 1
 
 
 @heavy_compile
