@@ -10,7 +10,7 @@ start, one warm-up flush of the cell's own traffic (the compile, or the
 cache read), the traffic's probe flush (a round with one wrong share of each
 kind, so that a path that accepts everything shows in every cell) and,
 overlapped with the warm-up in helper processes, the building of the seeded
-traffic pool and the plain reference's verdict on every request of it.
+traffic pool and the plain reference's verdicts on it (:func:`judged_positions`).
 
 The pieces (:class:`Session`, :class:`Prepared`, :func:`judge`) are apart so
 that a builder can drive many seeds through one worker.
@@ -23,6 +23,7 @@ import importlib
 import json
 import multiprocessing
 import os
+import random
 import shutil
 import subprocess
 import sys
@@ -34,6 +35,13 @@ from chipbench.harness import stats as hstats
 from chipbench.harness import work
 from chipbench.harness.reduce_trace import find_trace
 from chipbench.harness.worker import DEFAULT_ENTRY, REPO_ROOT, Worker
+
+#: Seconds a worker has to stop after a window (it takes 13-15 on the chip),
+#: and after a run that did not end (a cut, no chip, a worker that died):
+#: nothing it could still say is wanted, and a cut must end before the
+#: killer's second signal.
+WORKER_STOP_S = 30.0
+WORKER_CUT_S = 2.0
 
 #: Exit codes of a run that prints no result.
 EXIT_USAGE = 2
@@ -90,42 +98,84 @@ class Cell:
 #: the worker warms up: plain Python, no jax, ended before the window.
 HELPERS = max(2, min(6, (os.cpu_count() or 2) // 2))
 
+#: Requests of a warm-up or pool flush that the plain reference judges where
+#: the flush holds more, at positions drawn from ``(seed, index)``.  16 is the
+#: largest flush of the 16-node cells, so they stay judged whole.  The rest
+#: of a large flush is held to the verdict its construction expects, which a
+#: generator knows for free; the reference is there to catch a generator
+#: whose expectations are wrong, and it still judges every request the
+#: construction expects to fail and the whole probe (:func:`judged_positions`).
+JUDGED_PER_FLUSH = 16
+
+#: Requests a helper judges in one task, so that a large flush is judged by
+#: every helper and not by one.
+JUDGE_SLICE = 32
+
 WARM_UP = 0    # index of the warm-up flush; the window's are 1..pool_flushes
 PROBE = -1     # index of the probe flush
 
 
 def _build_one(args: Tuple[str, Dict, Dict, int, int, Any]):
-    """One flush from the seed and the plain reference's verdict on each of
-    its requests, from their wire bytes, by the verifier of the request's
-    own kind (``chipbench/kinds/<kind>.py``)."""
+    """Flush ``index`` of the run with ``seed``, from the generator."""
+    generator, config, params, seed, index, keys = args
+    mod = importlib.import_module("chipbench.generators." + generator)
+    return mod.make_flush(config, params, seed, index, keys)
+
+
+def _judge_slice(args: Tuple[List[str], List[Tuple[bytes, ...]]]):
+    """The plain reference's verdict on each request of a slice, from its
+    wire bytes, by the verifier of the request's own kind
+    (``chipbench/kinds/<kind>.py``), and the seconds it took."""
     from chipbench import kinds
     from chipbench.reference.verify import Reference
 
-    generator, config, params, seed, index, keys = args
-    mod = importlib.import_module("chipbench.generators." + generator)
-    flush = mod.make_flush(config, params, seed, index, keys)
+    request_kinds, wires = args
     t = time.perf_counter()
     reference = Reference()
     verdicts = [
         kinds.load(kind).verify(reference, *wire)
-        for kind, wire in zip(flush.kinds, flush.wire)
+        for kind, wire in zip(request_kinds, wires)
     ]
-    return flush, verdicts, time.perf_counter() - t
+    return verdicts, time.perf_counter() - t
+
+
+def judged_positions(seed: int, index: int, expected: Sequence[bool]) -> List[int]:
+    """The positions of flush ``index`` that the plain reference judges:
+    all of the probe and of a flush of up to ``JUDGED_PER_FLUSH``; else
+    ``JUDGED_PER_FLUSH`` drawn from a stream of ``(seed, index)`` alone (not
+    the construction's, so parent and change judge the same positions) and
+    every request whose expected verdict is false."""
+    n = len(expected)
+    if index == PROBE or n <= JUDGED_PER_FLUSH:
+        return list(range(n))
+    drawn = random.Random(f"chipbench judged {seed} {index}").sample(
+        range(n), JUDGED_PER_FLUSH
+    )
+    return sorted(set(drawn).union(i for i, ok in enumerate(expected) if not ok))
 
 
 class Prepared:
-    """One seed's traffic and the plain reference's verdict on all of it.
+    """One seed's traffic and the plain reference's verdicts on it.
 
     Flush ``WARM_UP`` is the warm-up, ``PROBE`` the traffic's probe round
     (absent where the traffic file has no ``probe``), 1.. the window's pool.
-    ``HELPERS`` processes build each flush from the seed and judge it while
-    the worker warms up; :meth:`finish` waits for them and ends them, so
-    nothing of this runs inside the window."""
+    ``HELPERS`` processes build each flush from the seed while the worker
+    warms up, and :meth:`get` returns a flush as soon as it is built.  A
+    thread hands each built flush's judged positions to the same helpers in
+    slices of ``JUDGE_SLICE``; :meth:`finish` waits for every verdict and ends
+    the helpers, so nothing of this runs inside the window."""
 
     def __init__(self, cell: Cell, seed: int, pool_flushes: Optional[int] = None) -> None:
         traffic = cell.traffic
+        self.seed = seed
         self.pool_flushes = int(pool_flushes or traffic["pool_flushes"])
         self.keys = cell.generator.make_keys(cell.config, traffic["params"], seed)
+        self.flushes: List[Any] = []
+        self.verdicts: Dict[int, List[Optional[bool]]] = {}
+        self.reference_s = 0.0
+        self.judged_requests = 0
+        self._judging: Dict[int, List[Tuple[List[int], concurrent.futures.Future]]] = {}
+        self._feeder = threading.Thread(target=self._feed, name="chipbench-judge", daemon=True)
         self._executor = concurrent.futures.ProcessPoolExecutor(
             max_workers=HELPERS, mp_context=multiprocessing.get_context("spawn"),
         )
@@ -133,31 +183,78 @@ class Prepared:
         if traffic.get("probe"):
             order.append((PROBE, traffic["probe"]))
         order += [(i, traffic["params"]) for i in range(1, 1 + self.pool_flushes)]
-        self._futures = {
-            index: self._executor.submit(
-                _build_one,
-                (traffic["generator"], cell.config, params, seed, index, self.keys),
-            )
-            for index, params in order
-        }
-        self.has_probe = PROBE in self._futures
-        self.flushes: List[Any] = []
-        self.reference_s = 0.0
+        self._built: Dict[int, concurrent.futures.Future] = {}
+        try:
+            for index, params in order:
+                self._built[index] = self._executor.submit(
+                    _build_one,
+                    (traffic["generator"], cell.config, params, seed, index, self.keys),
+                )
+            self._feeder.start()
+        except BaseException:  # a cut (SIGTERM) among the submissions
+            self.abandon()
+            raise
+        self.has_probe = PROBE in self._built
+
+    def _feed(self) -> None:
+        """Send each flush's judged requests to the helpers once it is built,
+        in the order the flushes were asked for."""
+        for index, built in self._built.items():
+            try:
+                flush = built.result()
+            except Exception:  # abandoned, or a build failed: get() and finish() raise it
+                return
+            part_of = judged_positions(self.seed, index, flush.expected)
+            slices = []
+            for start in range(0, len(part_of), JUDGE_SLICE):
+                part = part_of[start:start + JUDGE_SLICE]
+                try:
+                    judging = self._executor.submit(
+                        _judge_slice,
+                        ([flush.kinds[p] for p in part], [flush.wire[p] for p in part]),
+                    )
+                except RuntimeError:  # shut down: the run was abandoned
+                    return
+                slices.append((part, judging))
+            self._judging[index] = slices
 
     def get(self, index: int):
-        return self._futures[index].result()[0]
+        """Flush ``index``, once built; its verdicts may not exist yet."""
+        return self._built[index].result()
 
-    def reference(self, index: int) -> List[bool]:
-        return self._futures[index].result()[1]
+    def reference(self, index: int) -> List[Optional[bool]]:
+        """The plain reference's verdict on each request of flush ``index``,
+        None where it was not judged; after :meth:`finish`."""
+        return self.verdicts[index]
 
     def finish(self) -> None:
+        """Wait for every flush and every verdict, then end the helpers."""
+        for built in self._built.values():
+            built.result()
         self.flushes = [self.get(i) for i in range(1 + self.pool_flushes)]
-        self.reference_s = sum(f.result()[2] for f in self._futures.values())
+        self._feeder.join()
+        for index, slices in self._judging.items():
+            verdicts: List[Optional[bool]] = [None] * len(self.get(index).expected)
+            for part, judging in slices:
+                got, seconds = judging.result()
+                for p, verdict in zip(part, got):
+                    verdicts[p] = verdict
+                self.reference_s += seconds
+                self.judged_requests += len(part)
+            self.verdicts[index] = verdicts
         self._executor.shutdown(wait=True)
 
     def abandon(self) -> None:
-        """End the helpers, built or not."""
-        self._executor.shutdown(wait=True, cancel_futures=True)
+        """End the helpers at once, built, judged or not: a run that is cut
+        does not wait for a task to end.  (``ProcessPoolExecutor`` has no
+        public way to end its processes before Python 3.14.)"""
+        helpers = list((self._executor._processes or {}).values())
+        self._executor.shutdown(wait=False, cancel_futures=True)
+        for p in helpers:
+            p.terminate()
+        self._executor.shutdown(wait=True)
+        if self._feeder.is_alive():
+            self._feeder.join(timeout=10)
 
 
 class _NoFallback:
@@ -387,12 +484,13 @@ class Session:
             return None
         return self.worker.control(op="memory")["memory_peak_bytes"]
 
-    def close(self) -> Optional[int]:
-        """Close the client, stop the worker and wait until it has ended."""
+    def close(self, grace_s: float) -> Optional[int]:
+        """Close the client, stop the worker, kill it and its process group
+        after ``grace_s``, and wait until it has ended."""
         if self.client is not None:
             self.client.close()
             self.client = None
-        self.worker_rc = self.worker.stop()
+        self.worker_rc = self.worker.stop(grace_s)
         return self.worker_rc
 
 
@@ -405,9 +503,10 @@ def judge(
     and what went wrong on the way, for one window.
 
     Every answer of the window and of the set-up's flushes is held against
-    the plain reference's verdict on that request and against the verdict
-    the construction expects, and the construction against the reference on
-    every request built.  All limits are 0: the comparison is exact."""
+    the verdict the construction expects, and, where the plain reference
+    judged that request (:func:`judged_positions`), against the reference's
+    verdict; the construction is held against the reference on every request
+    judged.  All limits are 0: the comparison is exact."""
     n_req = cell.requests_per_flush
     calls = len(obs["lat"])
     c0 = obs["stats0"].get("counters", {})
@@ -424,35 +523,46 @@ def judge(
     if obs["stats1"] is not None and worker_flushes != calls:
         problems.append(f"worker counted {worker_flushes} flushes for {calls} calls")
 
-    vs_reference = vs_construction = checked = 0
+    vs_reference = vs_construction = by_reference = by_construction = 0
+    judged = due = 0
     every = [(s["index"], s["answers"]) for s in setup_answers] + list(obs["answers"])
     for index, got in every:
-        vs_reference += sum(g != w for g, w in zip(got, prep.reference(index)))
-        vs_construction += sum(g != w for g, w in zip(got, prep.get(index).expected))
-        checked += len(got)
+        expected = prep.get(index).expected
+        verdicts = prep.reference(index)
+        vs_construction += sum(g != e for g, e in zip(got, expected))
+        by_construction += min(len(got), len(expected))
+        due += len(expected)
+        positions = [p for p, v in enumerate(verdicts) if v is not None]
+        answered = [p for p in positions if p < len(got)]
+        vs_reference += sum(got[p] != verdicts[p] for p in answered)
+        by_reference += len(answered)
+        judged += len(positions)
     built = [WARM_UP] + ([PROBE] if prep.has_probe else [])
     built += range(1, 1 + prep.pool_flushes)
     construction_vs_reference = sum(
         e != v
         for i in built
         for e, v in zip(prep.get(i).expected, prep.reference(i))
+        if v is not None
     )
-    due = sum(len(prep.get(index).requests) for index, _ in every)
     compared = {
         "answers_differing_from_reference": {"value": vs_reference, "limit": 0},
         "answers_differing_from_construction": {"value": vs_construction, "limit": 0},
         "construction_differing_from_reference": {
             "value": construction_vs_reference, "limit": 0,
         },
-        "answers_checked_by_reference": {"value": checked, "at_least": due},
+        "answers_checked_by_reference": {"value": by_reference, "at_least": judged},
+        "answers_checked_by_construction": {"value": by_construction, "at_least": due},
         "requests_not_answered_by_chip_path": {"value": failed, "limit": 0},
         "flush_count_mismatch_or_errors": {"value": len(problems), "limit": 0},
         "programs_compiled_in_window": {
             "value": obs["new_cache_entries"], "limit": 0,
         },
     }
-    correct = checked >= due and all(
-        entry["value"] == 0 for entry in compared.values() if "limit" in entry
+    correct = all(
+        entry["value"] >= entry["at_least"] if "at_least" in entry
+        else entry["value"] <= entry["limit"]
+        for entry in compared.values()
     )
     return correct, compared, failed, problems
 
@@ -585,10 +695,11 @@ def run_cell(
         cell, require_tpu=require_tpu, worker_entry=worker_entry,
         worker_entry_args=worker_entry_args, worker_overrides=worker_overrides,
     )
-    session.start()
     prep: Optional[Prepared] = None
     setup: Dict[str, float] = {}
+    ended = False
     try:
+        session.start()
         prep = Prepared(cell, seed)
         session.connect()
         set_up = [session.setup_flush(WARM_UP, prep.get(WARM_UP))]
@@ -602,13 +713,16 @@ def run_cell(
             on_start=lambda: setup.update(s=time.perf_counter() - t0),
         )
         memory = session.memory_peak()
+        ended = True
     except NoResult as e:
         say(str(e))
         return e.code
     finally:
+        # also where a cut (SIGTERM, chipbench/run.py) unwinds through here:
+        # the helpers and the worker end before this process does
         if prep is not None:
             prep.abandon()
-        session.close()
+        session.close(grace_s=WORKER_STOP_S if ended else WORKER_CUT_S)
 
     correct, compared, failed, problems = judge(
         cell, prep, obs, set_up, worker_rc=session.worker_rc
@@ -667,6 +781,7 @@ def run_cell(
         "warmup_flush_s": set_up[0]["seconds"],
         "probe_flush_s": set_up[-1]["seconds"] if prep.has_probe else None,
         "reference_s": prep.reference_s,
+        "judged_requests": prep.judged_requests,
         "parent_behind_worker_s": parent_behind_s,
         "pool_wraps": obs["wraps"],
         "compile_cache_dir": ready.get("compile_cache_dir"),

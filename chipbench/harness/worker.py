@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import json
 import os
+import signal
 import socket
 import subprocess
 import sys
@@ -78,6 +79,7 @@ class Worker:
             text=True,
             env=worker_env(dict(os.environ)),
             cwd=REPO_ROOT,
+            start_new_session=True,  # its own process group: stop() ends all of it
         )
         self._pump_thread = threading.Thread(
             target=self._pump, name="chipbench-pump", daemon=True
@@ -139,9 +141,10 @@ class Worker:
             raise RuntimeError(f"control {cmd.get('op')}: {reply.get('error')}")
         return reply
 
-    def stop(self, grace_s: float = 30.0) -> Optional[int]:
-        """Ask the worker to stop, wait until it has ended (kill it if it
-        will not), and return its exit code."""
+    def stop(self, grace_s: float) -> Optional[int]:
+        """Ask the worker to stop, wait ``grace_s`` for it to end, kill its
+        process group (whatever is left of it, or all of it where it would
+        not stop), and return its exit code."""
         proc = self.proc
         if proc is None:
             return None
@@ -166,8 +169,12 @@ class Worker:
         try:
             proc.wait(timeout=grace_s)
         except subprocess.TimeoutExpired:
-            proc.kill()
-            proc.wait()
+            pass
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:  # the group has ended
+            pass
+        proc.wait()
         if self._pump_thread is not None:
             self._pump_thread.join(timeout=10)
         return proc.returncode

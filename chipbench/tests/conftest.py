@@ -27,7 +27,8 @@ def tiny_bench(bench):
     """The real metrics and the real ``coin16`` configuration over traffic
     small enough for the pure-Python ``eager`` worker, and a decrypt phase
     (``hb4``) that is a configuration file, a traffic file and these
-    entries: what a PR that adds ``hb16.decrypt`` brings, and no more."""
+    entries: what a PR that adds ``hb16.decrypt`` brings, and no more; and
+    ``tiny.wide``, whose flushes of 20 are more than the reference judges."""
     tiny = dict(bench)
     tiny["paths"] = ["."]
     tiny["configs"] = [
@@ -38,6 +39,7 @@ def tiny_bench(bench):
         {"name": "tiny.clean", "config": "coin16", "traffic": "tiny_clean", "chips": 1},
         {"name": "tiny.byz", "config": "coin16", "traffic": "tiny_byz", "chips": 1},
         {"name": "hb4.decrypt", "config": "hb4", "traffic": "tiny_decrypt", "chips": 1},
+        {"name": "tiny.wide", "config": "coin16", "traffic": "tiny_wide", "chips": 1},
     ]
     for group in ("end_to_end", "per_layer"):
         tiny[group] = [

@@ -8,7 +8,7 @@ import time
 
 import pytest
 
-from chipbench.harness.bench import EXIT_NO_CHIP, run_cell
+from chipbench.harness.bench import EXIT_NO_CHIP, WARM_UP, judged_positions, run_cell
 
 from .conftest import DATA, HERE
 
@@ -44,8 +44,11 @@ def test_a_run_prints_the_contracts_line(tiny_bench):
     }
     assert all(m["value"] > 0 and m["unit"] for m in line["metrics"].values())
     # every answer of the window, the warm-up and the probe is judged
-    checked = line["compared"]["answers_checked_by_reference"]
-    assert checked["value"] == checked["at_least"] == line["attempted"] + 2 * 2
+    for entry in ("answers_checked_by_reference", "answers_checked_by_construction"):
+        checked = line["compared"][entry]
+        assert checked["value"] == checked["at_least"] == line["attempted"] + 2 * 2
+    # flushes of 2 are judged whole: the warm-up's, the probe's, the pool's 6
+    assert line["run"]["judged_requests"] == 2 * (1 + 1 + 6)
     assert line["run"]["probe_flush_s"] > 0
     # the numbers compared are the last lines of the standard error too
     tail = err.strip().splitlines()[-(len(line["compared"]) + 1):]
@@ -123,3 +126,36 @@ def test_a_broken_timed_path_is_not_correct(tiny_bench, workload, mode):
     if mode == "flip":
         # one answer of every flush: the warm-up's, the probe's, the window's
         assert compared["answers_differing_from_reference"] == 2 + run["flushes"]
+
+
+WIDE_SEED = 173  # its draws leave position 19 unjudged in the warm-up and flushes 1, 2
+
+
+@pytest.mark.parametrize("mode", ["flip", "accept"])
+def test_a_broken_answer_outside_the_sample_is_not_correct(tiny_bench, mode):
+    """``tiny.wide``'s flushes of 20 are judged at 16 drawn positions (and the
+    probe whole): an answer altered where the reference did not look is
+    caught by the construction's verdict on it."""
+    unjudged = [
+        19 not in judged_positions(WIDE_SEED, i, [True] * 20) for i in (WARM_UP, 1, 2)
+    ]
+    assert all(unjudged)
+    rc, line, _ = _run(tiny_bench, "tiny.wide", WIDE_SEED, entry=BROKEN_ENTRY, mode=mode)
+    assert rc == 0
+    assert line["correct"] is False
+    compared = {k: v["value"] for k, v in line["compared"].items()}
+    assert compared["construction_differing_from_reference"] == 0
+    run = line["run"]
+    # the warm-up and each pool flush judged at 16 positions, the probe whole
+    assert run["judged_requests"] == 20 + 16 * 3
+    assert compared["answers_checked_by_reference"] == 20 + 16 * (1 + run["flushes"])
+    assert compared["answers_checked_by_construction"] == 20 * (2 + run["flushes"])
+    if mode == "flip":
+        # the last answer of every flush: the reference sees the probe's alone
+        assert run["flushes"] <= 2  # flushes 1 and 2, not wrapped
+        assert compared["answers_differing_from_construction"] == 2 + run["flushes"]
+        assert compared["answers_differing_from_reference"] == 1
+    else:
+        # the probe's one wrong share let through, judged whole
+        assert compared["answers_differing_from_construction"] == 1
+        assert compared["answers_differing_from_reference"] == 1
