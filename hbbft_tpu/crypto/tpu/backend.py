@@ -149,8 +149,8 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         g2_pts, g2_bits_s, g2_bits_q, g2_chk, gen_pt,
     ):
         # The scans (dcurve "static-endo flush scans" notes): G1 is
-        # one LSB-first shared-doubling scan with the [x^2]P check-chain
-        # adds unrolled at x^2's 17 static set bits; G2 splits each RLC
+        # one LSB-first shared-doubling scan whose chain points at x^2's
+        # 17 static set bits sum to the [x^2]P check chain; G2 splits each RLC
         # coefficient as c = q·|x| + s against the psi endomorphism —
         # a 65-step two-scalar scan, run only where a G2 row is real
         # (_scan_kernel's docstring).  Soundness: the psi(Q) = [x]Q
@@ -171,17 +171,23 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
                 jnp.all(sub1 | (g1_chk == 0)) & jnp.all(sub2 | (g2_chk == 0))
             )
         with jax.named_scope("leg_sums"):
-            leg_sums = []
-            for l in range(n_legs):
-                masked = dcurve.select(
-                    seg[l], scaled1,
-                    dcurve.identity(dcurve.G1_OPS, (n_g1,)), dcurve.G1_OPS,
-                )
-                leg_sums.append(dcurve.tree_sum(dcurve.G1_OPS, masked))
+            # Every leg's sum in ONE tree_sum over the rows of an
+            # (n_g1, n_legs) grid: row i of leg l is the scaled row i
+            # where the one-hot ``seg`` puts it in leg l, else the point
+            # at infinity.
+            grid = tuple(
+                jnp.broadcast_to(c[:, None], (n_g1, n_legs) + c.shape[1:])
+                for c in scaled1
+            )
+            masked = dcurve.select(
+                seg.T, grid,
+                dcurve.identity(dcurve.G1_OPS, (n_g1, n_legs)), dcurve.G1_OPS,
+            )
+            leg_sums = dcurve.tree_sum(dcurve.G1_OPS, masked)
         # Pair list: (gen, gen_leg) + (leg_sum_l, rhs_l), the rhs_l joined
         # on later.
         lhs = tuple(
-            jnp.stack([gen_pt[c]] + [p[c] for p in leg_sums]) for c in range(4)
+            jnp.concatenate([gen_pt[c][None], leg_sums[c]]) for c in range(4)
         )
         return sub_ok, lhs, tuple(jnp.stack([gen_leg[c]]) for c in range(4))
 

@@ -372,20 +372,23 @@ def scalars_to_bits_lsb(scalars, nbits: int) -> jnp.ndarray:
 #     holds, Q ∈ G2 (Bowe 2019/814 / Scott 2021/1130, bls.curve notes)
 #     and the decomposition is exact group algebra.
 #
-# Structure note: the check chain is assembled as tree_sum over the
-# COLLECTED doubling-chain points [2^k]P at the static set bits
-# ([m]P = sum of distinct powers), not as adds interleaved between the
-# scan segments — XLA 0.9.0's CPU pipeline dies with "Unknown MLIR
-# failure" on scan→add→scan chains (reproduced + bisected round 4),
-# while sequential scans plus one trailing tree reduction compile fine.
+# Structure note: each doubling chain is ONE lax.scan that writes the
+# chain points [2^k]P at the static set bits k into slots of its carry,
+# and the check chain is the tree_sum of the slots after the scan ([m]P =
+# sum of distinct powers): the schedule is data (each step's slot), not
+# code, so the program holds one copy of each step body however many
+# set bits the scalar has.  Nothing accumulates between scans — XLA
+# 0.9.0's CPU pipeline dies with "Unknown MLIR failure" on scan→add→scan
+# chains (reproduced + bisected round 4).
 #
 # add_unsafe safety for the NEW uses (CLAUDE.md invariant — on top of
 # the scalar_mul2 docstring argument):
 #
 #   * check-chain tree_sum (both groups): every partial sum is
 #     [m1]P for m1 a sub-mask of the fixed scalar's set bits, every
-#     addend [m2]P for a DISJOINT nonzero sub-mask; coincidence needs
-#     m1 ≡ ±m2 (mod r) with m1 ≠ m2, both < 2^128 ≪ r — impossible.
+#     addend [m2]P for a DISJOINT nonzero sub-mask (tree_sum pairs sums
+#     over disjoint sets of its rows); coincidence needs m1 ≡ ±m2
+#     (mod r) with m1 ≠ m2, both < 2^128 ≪ r — impossible.
 #   * B01 = Q + (-psi(Q)) precompute: forbidden iff psi(Q) == ±Q, i.e.
 #     [x ∓ 1]Q = O — impossible for genuine G2 points (0 < |x ∓ 1| <
 #     2^65 < r); adversarial non-subgroup points can poison it, but
@@ -406,48 +409,41 @@ def scalars_to_bits_lsb(scalars, nbits: int) -> jnp.ndarray:
 XSQ = (F.BLS_X * F.BLS_X)  # 128-bit G1 endo-check scalar (positive)
 
 
-def _lsb_set_positions(value: int, nbits: int) -> Tuple[int, ...]:
-    return tuple(i for i in range(nbits) if (value >> i) & 1)
+def _chain_slots(scalar: int, steps: int) -> np.ndarray:
+    """Where step j of a doubling scan, which makes [2^(j+1)]P, writes it:
+    slot i where j + 1 is ``scalar``'s i-th set bit, else the one spare
+    slot past them, which nobody reads.  Bit 0 of both check scalars
+    (x^2, |x|) is clear, so no chain point precedes the first double."""
+    set_bits = [k for k in range(scalar.bit_length()) if (scalar >> k) & 1]
+    if set_bits[0] == 0 or set_bits[-1] > steps:
+        raise ValueError(f"{steps} doublings cannot make the chain of {scalar}")
+    slots = np.full(steps, len(set_bits), dtype=np.int32)
+    slots[np.array(set_bits) - 1] = np.arange(len(set_bits))
+    return slots
 
 
-def _stack_points(pts_list, ops: Ops) -> Point:
-    """Stack unbatched-or-batched points along a new leading axis."""
+def _chain_init(base: Point, scalar: int) -> Point:
+    """The slots a doubling scan's carry writes ``scalar``'s chain points
+    into (:func:`_chain_slots`): one row per set bit, and the spare."""
+    n = bin(scalar).count("1") + 1
+    return tuple(jnp.zeros((n, *c.shape), c.dtype) for c in base)
+
+
+def _chain_write(chain: Point, slot, pt: Point) -> Point:
     return tuple(
-        jnp.stack([p[c] for p in pts_list]) for c in range(4)
+        jax.lax.dynamic_update_index_in_dim(c, p, slot, 0)
+        for c, p in zip(chain, pt)
     )
 
 
-def _tree_sum_axis0(ops: Ops, pts: Point) -> Point:
-    """Pairwise-halving sum over a SMALL static leading axis (any
-    trailing batch dims; contrast tree_sum, whose identity padding
-    assumes a single batch dim).  add_unsafe safety is the CALLER's
-    obligation for the pair sums it induces."""
-    m = pts[0].shape[0]
-    while m > 1:
-        half = m // 2
-        lo = tuple(x[:half] for x in pts)
-        hi = tuple(x[half : 2 * half] for x in pts)
-        summed = add_unsafe(ops, lo, hi)
-        if m % 2:
-            tail = tuple(x[2 * half :] for x in pts)
-            pts = tuple(
-                jnp.concatenate([s, t]) for s, t in zip(summed, tail)
-            )
-            m = half + 1
-        else:
-            pts = summed
-            m = half
-    return tuple(x[0] for x in pts)
-
-
 def scalar_mul_rlc_g1(base: Point, bits_lsb: jnp.ndarray) -> Tuple[Point, Point]:
-    """([c]P, [x^2]P) per row — LSB-first shared-doubling scan.
+    """([c]P, [x^2]P) per row — ONE LSB-first shared-doubling scan.
 
     ``bits_lsb``: (..., 128) LSB-first RLC coefficient bits.  One base
     doubling chain serves both results; the RLC add is conditional per
     step, and the [x^2]P check chain is the tree_sum of the chain
-    points [2^k]P collected at x^2's 17 static set bits (structure +
-    safety: section notes above).
+    points [2^k]P that the scan writes into its carry at x^2's 17 static
+    set bits (structure + safety: section notes above).
     """
     ops = G1_OPS
     nbits = bits_lsb.shape[-1]
@@ -465,32 +461,23 @@ def scalar_mul_rlc_g1(base: Point, bits_lsb: jnp.ndarray) -> Tuple[Point, Point]
     acc = identity(ops, batch)
     started = jnp.zeros(batch, dtype=jnp.int32)
     xs_all = jnp.moveaxis(bits_lsb, -1, 0)  # (nbits, ...)
+    slots = jnp.asarray(_chain_slots(XSQ, nbits))
 
-    def step(carry, bit):
-        acc, started, cur = carry
+    def step(carry, xs):
+        acc, started, cur, chain = carry
+        bit, slot = xs
         summed = add_unsafe(ops, (acc[0], acc[1], acc[2], 1 - started), cur)
         acc = select(bit, summed, acc, ops)
         started = started | bit
-        return (acc, started, double(ops, cur)), None
+        cur = double(ops, cur)
+        return (acc, started, cur, _chain_write(chain, slot, cur)), None
 
-    # Segment the scan at the static x^2 set bits: at bit k the carry
-    # holds cur = [2^k]P once steps 0..k-1 have run, so each segment
-    # ends just before a set bit (whose step opens the next segment).
-    positions = _lsb_set_positions(XSQ, nbits)
-    carry = (acc, started, base)
-    curs = []
-    prev = 0
-    for k in positions:
-        if k > prev:
-            carry, _ = jax.lax.scan(step, carry, xs_all[prev:k])
-            prev = k
-        curs.append(carry[2])
-    if prev < nbits:
-        carry, _ = jax.lax.scan(step, carry, xs_all[prev:nbits])
-    acc, started, _ = carry
+    (acc, started, _, chain), _ = jax.lax.scan(
+        step, (acc, started, base, _chain_init(base, XSQ)), (xs_all, slots)
+    )
     inf = (1 - started) | base[3]
     scaled = (acc[0], acc[1], acc[2], inf)
-    chain = _tree_sum_axis0(ops, _stack_points(curs, ops))
+    chain = tree_sum(ops, tuple(c[:-1] for c in chain))
     chain = (chain[0], chain[1], chain[2], chain[3] | base[3])
     return scaled, chain
 
@@ -517,9 +504,9 @@ def scalar_mul_rlc_g2(
     :func:`decompose_g2_scalar`.  The RLC sum is ONE MSB-first scan —
     per step one accumulator double and one add_unsafe of the addend
     selected from {O, Q, -psi(Q), Q-psi(Q)}; the [|x|]Q check chain is
-    the tree_sum of [2^j]Q collected from a double-only chain at |x|'s
-    6 static set bits.  ~129 doubles + ~72 adds replaces the shared
-    128-step scan's 128 doubles + 256 computed adds — and the G2 rows
+    the tree_sum of [2^j]Q that ONE double-only scan writes into its
+    carry at |x|'s 6 static set bits.  ~129 doubles + ~72 adds replaces the
+    shared 128-step scan's 128 doubles + 256 computed adds — and the G2 rows
     are the most expensive in the flush (every Fq2 op is ~3 Fq muls).
     """
     ops = G2_OPS
@@ -551,21 +538,21 @@ def scalar_mul_rlc_g2(
 
     acc, _ = jax.lax.scan(step, acc, xs)
 
-    # [|x|]Q check chain: double-only scan segments over the base,
-    # collecting [2^j]Q at |x|'s set bits, then one tree reduction
+    # [|x|]Q check chain: one double-only scan over the base up to |x|'s
+    # top bit, [2^j]Q written at its set bits, then one tree reduction
     # (structure + add_unsafe safety: section notes above).
-    def dbl_step(cur, _):
-        return double(ops, cur), None
+    def dbl_step(carry, slot):
+        cur, chain = carry
+        cur = double(ops, cur)
+        return (cur, _chain_write(chain, slot, cur)), None
 
-    curs = []
-    cur = base
-    prev = 0
-    for j in _lsb_set_positions(-F.BLS_X, 64):
-        if j > prev:
-            cur, _ = jax.lax.scan(dbl_step, cur, None, length=j - prev)
-            prev = j
-        curs.append(cur)
-    chain = _tree_sum_axis0(ops, _stack_points(curs, ops))
+    x_abs = -F.BLS_X
+    (_, chain), _ = jax.lax.scan(
+        dbl_step,
+        (base, _chain_init(base, x_abs)),
+        jnp.asarray(_chain_slots(x_abs, x_abs.bit_length() - 1)),
+    )
+    chain = tree_sum(ops, tuple(c[:-1] for c in chain))
 
     # Identity flags: acc started as identity and add_unsafe tracked
     # flags through every add (a zero scalar leaves the flag set); the
@@ -575,27 +562,65 @@ def scalar_mul_rlc_g2(
     return scaled, chain
 
 
+#: Point adds that one add_unsafe step of a loop takes about as long for
+#: as for one: a v5e runs a tree level of 256 G1 adds in about the time
+#: of a level of 32 (0.2 ms), and 2048 in seven times that.
+ADDS_PER_STEP = 256
+
+
 def tree_sum(ops: Ops, pts: Point) -> Point:
-    """Sum a batch of points over axis 0 (log2 rounds of add_unsafe)."""
-    n = pts[0].shape[0]
-    while n > 1:
-        half = (n + 1) // 2
-        top = _slice_or_identity(pts, half, n, ops)
-        bottom = tuple(x[:half] for x in pts)
-        # safety: tree reduction over RLC-scaled points (module
-        # docstring, second bullet — committed-coefficient partial sums).
-        pts = add_unsafe(ops, bottom, top)
-        n = half
-    return tuple(x[0] for x in pts)
+    """Sum points over axis 0 (any trailing batch dims) in two loops of
+    ONE ``add_unsafe`` body each, so the program's size does not depend
+    on the m rows: the rows, padded with points at infinity to blocks of
+    w, fold block by block into the first (a ``lax.scan`` of ceil(m/w) - 1
+    steps), then the w sums halve to one (log2 w levels adding adjacent
+    pairs at a fixed width w/2).  w is a power of two below m, as small as
+    puts :data:`ADDS_PER_STEP` adds (over the trailing dims) in a step:
+    about m - 1 adds in all, as a halving tree makes, in about as few
+    steps where a step's time is its dispatch.
 
+    add_unsafe safety (the CALLER's argument covers the pairs; this is
+    which pairs there are): every add is of two sums over DISJOINT sets
+    of the input rows, or has the point at infinity on one side, which
+    add_unsafe routes by its flag.  So it adds what a halving tree adds:
+    committed-coefficient partial sums of RLC-scaled rows (module
+    docstring, second bullet), or disjoint sub-masks of a check chain
+    (static-endo section notes)."""
+    m = pts[0].shape[0]
+    if m == 1:
+        return tuple(x[0] for x in pts)
+    batch = pts[3].shape[1:]
+    fill = -(-ADDS_PER_STEP // max(1, int(np.prod(batch))))
+    w = min(1 << ((m - 1).bit_length() - 1), 1 << (fill - 1).bit_length())
+    blocks = -(-m // w)
+    pad = identity(ops, (blocks * w - m, *batch))
+    rows = tuple(
+        jnp.concatenate([x, p]).reshape((blocks, w) + x.shape[1:])
+        for x, p in zip(pts, pad)
+    )
 
-def _slice_or_identity(pts: Point, half: int, n: int, ops: Ops) -> Point:
-    """pts[half:n] padded with identities up to length half."""
-    idx = jnp.arange(half)
-    valid = idx + half < n
-    gather = jnp.clip(idx + half, 0, n - 1)
-    sliced = tuple(x[gather] for x in pts)
-    return select(valid, sliced, identity(ops, (half,)), ops)
+    def fold(acc, block):
+        # safety: row i of acc sums rows i + j·w of the blocks before,
+        # disjoint from the block's row i (docstring above).
+        return add_unsafe(ops, acc, block), None
+
+    acc, _ = jax.lax.scan(
+        fold, tuple(x[0] for x in rows), tuple(x[1:] for x in rows)
+    )
+    if w > 1:
+        half = identity(ops, (w // 2, *batch))
+
+        def level(acc, _):
+            # safety: adjacent rows sum disjoint blocks (docstring above).
+            summed = add_unsafe(
+                ops, tuple(x[0::2] for x in acc), tuple(x[1::2] for x in acc)
+            )
+            return tuple(
+                jnp.concatenate([s, h]) for s, h in zip(summed, half)
+            ), None
+
+        acc, _ = jax.lax.scan(level, acc, None, length=w.bit_length() - 1)
+    return tuple(x[0] for x in acc)
 
 
 # ---------------------------------------------------------------------------

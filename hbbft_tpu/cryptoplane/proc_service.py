@@ -154,7 +154,8 @@ def _build_backend(
 ) -> CryptoBackend:
     """``metrics`` is the worker's one :class:`Metrics`: a backend that has
     spans and counters of its own (``tpu``) writes them there, so they
-    come back through the ``stats`` op."""
+    come back through the ``stats`` op, and a ``tpu`` worker's set-up
+    counters beside them (``jaxcache.count_setup_seconds``)."""
     if name == "batched":
         from hbbft_tpu.crypto.backend import BatchedBackend
 
@@ -166,9 +167,11 @@ def _build_backend(
     if name == "tpu":
         # jax import happens HERE, in the service process only — node
         # processes stay jax-free whatever backend serves them.
-        from hbbft_tpu.utils.jaxcache import enable_cache
+        from hbbft_tpu.utils.jaxcache import count_setup_seconds, enable_cache
 
         enable_cache()  # before the first jit
+        if metrics is not None:
+            count_setup_seconds(metrics)  # read back by the ``stats`` op
         platform = _jax_device()["platform"]
         if platform != "tpu" and os.environ.get("JAX_PLATFORMS") != "cpu":
             raise RuntimeError(

@@ -70,6 +70,25 @@ def test_enable_cache_defaults_to_the_checkout(monkeypatch, config_updates):
     )
 
 
+def test_setup_seconds_are_counted_from_jaxs_own_events():
+    import jax
+    import jax.numpy as jnp
+
+    from hbbft_tpu.utils.metrics import Metrics
+
+    metrics = Metrics()
+    stop = jaxcache.count_setup_seconds(metrics)
+    try:
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(5))
+    finally:
+        stop()
+    counted = dict(metrics.counters)
+    for name in ("trace_s", "lower_s", "compile_s"):
+        assert counted["crypto.jax." + name] > 0, counted
+    jax.jit(lambda x: x - 7)(jnp.arange(3))
+    assert metrics.counters == counted  # stopped
+
+
 # -- the worker's device check ----------------------------------------------
 
 def _fake_device(monkeypatch, platform):

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from hbbft_tpu.crypto.bls import curve as oc
 from hbbft_tpu.crypto.bls import fields as OF
+from hbbft_tpu.crypto.bls import pairing as OP
 from hbbft_tpu.crypto.bls.suite import BLSSuite
 from hbbft_tpu.crypto.tpu import curve as dc
 from hbbft_tpu.crypto.tpu import fq, fq2
@@ -166,6 +167,79 @@ def test_curve_identity_flags(rng):
         assert oc.jac_eq(oc.FQ_OPS, dc.g1_from_dev(s, i), g1s[i])
 
 
+def _coeffs(rng, n):
+    """128-bit RLC coefficients, the second of them zero."""
+    return [0 if i == 1 else int.from_bytes(rng.bytes(16), "big") for i in range(n)]
+
+
+def test_rlc_g1_scan_matches_oracle(rng):
+    """The G1 scan (one ``lax.scan``, chain points gathered from its
+    outputs): [c]P and [x^2]P per row, a point at infinity and a zero
+    coefficient among the rows."""
+    g1s, _ = _rand_points(rng, 3)
+    jacs = g1s + [oc.jac_identity(oc.FQ_OPS)]
+    coeffs = _coeffs(rng, len(jacs))
+    bits = dc.scalars_to_bits_lsb(coeffs, dc.ENDO_NBITS)
+    scaled, chain = jax.jit(dc.scalar_mul_rlc_g1)(dc.g1_to_dev(jacs), bits)
+    for i, (j, c) in enumerate(zip(jacs, coeffs)):
+        assert oc.jac_eq(oc.FQ_OPS, dc.g1_from_dev(scaled, i), oc.jac_mul(oc.FQ_OPS, j, c))
+        assert oc.jac_eq(oc.FQ_OPS, dc.g1_from_dev(chain, i), oc.jac_mul(oc.FQ_OPS, j, dc.XSQ))
+    assert [int(v) for v in np.asarray(scaled[3])] == [0, 1, 0, 1]
+    assert [int(v) for v in np.asarray(chain[3])] == [0, 0, 0, 1]
+
+
+def test_rlc_g2_scan_matches_oracle(rng):
+    """The G2 scan and its chain (one double-only scan, points gathered at
+    |x|'s set bits): [c]Q and [|x|]Q per row, with the same rows."""
+    _, g2s = _rand_points(rng, 3)
+    jacs = g2s + [oc.jac_identity(oc.FQ2_OPS)]
+    coeffs = _coeffs(rng, len(jacs))
+    sq = [dc.decompose_g2_scalar(c) for c in coeffs]
+    bs = dc.scalars_to_bits([s for s, _ in sq], dc.G2_SCAN_NBITS)
+    bq = dc.scalars_to_bits([q for _, q in sq], dc.G2_SCAN_NBITS)
+    scaled, chain = jax.jit(dc.scalar_mul_rlc_g2)(dc.g2_to_dev(jacs), bs, bq)
+    for i, (j, c) in enumerate(zip(jacs, coeffs)):
+        assert oc.jac_eq(oc.FQ2_OPS, dc.g2_from_dev(scaled, i), oc.jac_mul(oc.FQ2_OPS, j, c))
+        assert oc.jac_eq(oc.FQ2_OPS, dc.g2_from_dev(chain, i), oc.jac_mul(oc.FQ2_OPS, j, -OF.BLS_X))
+    assert [int(v) for v in np.asarray(scaled[3])] == [0, 1, 0, 1]
+
+
+@pytest.mark.parametrize(
+    "n, adds_per_step", [(1, 256), (2, 256), (3, 256), (5, 256), (16, 256),
+                         (5, 1), (16, 4)],
+)
+def test_tree_sum_matches_oracle(rng, monkeypatch, n, adds_per_step):
+    """The fold and the level loop against the oracle's sum, every third
+    row the point at infinity: one block folded into another, then levels
+    (the first five), several blocks with no level loop, and several
+    blocks of four then two levels."""
+    monkeypatch.setattr(dc, "ADDS_PER_STEP", adds_per_step)
+    g1s, _ = _rand_points(rng, n)
+    jacs = [oc.jac_identity(oc.FQ_OPS) if i % 3 == 1 else p for i, p in enumerate(g1s)]
+    got = jax.jit(lambda p: dc.tree_sum(dc.G1_OPS, p))(dc.g1_to_dev(jacs))
+    want = oc.jac_identity(oc.FQ_OPS)
+    for p in jacs:
+        want = oc.jac_add(oc.FQ_OPS, want, p)
+    assert oc.jac_eq(oc.FQ_OPS, dc.g1_from_dev(got), want)
+
+
+def test_tree_sum_over_a_grid_sums_each_column(rng):
+    """Trailing batch dims are columns summed apart (the scan program's
+    legs): G2 rows on a (5, 2) grid, one column's rows all infinity but
+    one."""
+    _, g2s = _rand_points(rng, 6)
+    ident = oc.jac_identity(oc.FQ2_OPS)
+    cols = [g2s[:5], [ident, ident, g2s[5], ident, ident]]
+    flat = dc.g2_to_dev([cols[c][r] for r in range(5) for c in range(2)])
+    grid = tuple(x.reshape((5, 2) + x.shape[1:]) for x in flat)
+    got = jax.jit(lambda p: dc.tree_sum(dc.G2_OPS, p))(grid)
+    for c, rows in enumerate(cols):
+        want = ident
+        for p in rows:
+            want = oc.jac_add(oc.FQ2_OPS, want, p)
+        assert oc.jac_eq(oc.FQ2_OPS, dc.g2_from_dev(got, c), want)
+
+
 def test_add_safe_degenerate_cases(rng):
     g1s, _ = _rand_points(rng, 2)
     P1 = dc.g1_to_dev(g1s)
@@ -208,6 +282,54 @@ def test_fq12_ops_vs_oracle(rng):
     assert OF.fq12_eq(OF.fq12_mul(got_inv, A), OF.FQ12_ONE)
     assert bool(dp.is_one(jnp.asarray(dp.FQ12_ONE)))
     assert not bool(dp.is_one(dA))
+
+
+def test_pow_x_abs_matches_oracle(rng):
+    """f^|x| (one scan over |x|'s bits, the multiply under a cond on each
+    bit)."""
+    A = _rand_fq12(rng)
+    got = jax.jit(dp.pow_x_abs)(_to_dev12(A))
+    assert OF.fq12_eq(_from_dev12(got), OF.fq12_pow(A, dp.X_ABS))
+
+
+def _affine_pairs(rng, n):
+    g1s, g2s = _rand_points(rng, n)
+    return (
+        [oc.jac_to_affine(oc.FQ_OPS, j) for j in g1s],
+        [oc.jac_to_affine(oc.FQ2_OPS, j) for j in g2s],
+    )
+
+
+def test_miller_loop_matches_oracle(rng):
+    """The Miller loop (one scan over |x|'s bits, the add step under a cond
+    on each bit) equals the oracle's up to the lines' Fq2 scaling, which
+    the final exponentiation kills."""
+    ps, qs = _affine_pairs(rng, 2)
+    px = jnp.asarray(np.stack([fq.to_mont_np(p[0]) for p in ps]))
+    py = jnp.asarray(np.stack([fq.to_mont_np(p[1]) for p in ps]))
+    qx = jnp.asarray(np.stack([fq2.to_mont_np(q[0]) for q in qs]))
+    qy = jnp.asarray(np.stack([fq2.to_mont_np(q[1]) for q in qs]))
+    fs = np.asarray(jax.jit(dp.miller_loop)(px, py, qx, qy))
+    for i in range(2):
+        got = OP.final_exponentiation(_from_dev12(fs[i]))
+        assert OF.fq12_eq(got, OP.pairing(ps[i], qs[i]))
+
+
+def test_miller_product_matches_oracle(rng):
+    """The product over pairs (one scan of Fq12 products): two pairings and
+    a pair with the point at infinity, which contributes 1."""
+    g1s, g2s = _rand_points(rng, 3)
+    g1s[2] = oc.jac_identity(oc.FQ_OPS)
+    f = jax.jit(dp.miller_product)(dc.g1_to_dev(g1s), dc.g2_to_dev(g2s))
+    want = OP.miller_loop(
+        oc.jac_to_affine(oc.FQ_OPS, g1s[0]), oc.jac_to_affine(oc.FQ2_OPS, g2s[0])
+    )
+    want = OF.fq12_mul(want, OP.miller_loop(
+        oc.jac_to_affine(oc.FQ_OPS, g1s[1]), oc.jac_to_affine(oc.FQ2_OPS, g2s[1])
+    ))
+    assert OF.fq12_eq(
+        OP.final_exponentiation(_from_dev12(f)), OP.final_exponentiation(want)
+    )
 
 
 @heavy_compile

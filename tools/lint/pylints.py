@@ -14,8 +14,8 @@ Rules (ids referenced from docs/INVARIANTS.md):
   unbounded XLA/LLVM compile time; CLAUDE.md environment gotchas).
 * HBT004 — no accumulator chain updated *between* sequential
   ``lax.scan`` segments (XLA 0.9.0 "Unknown MLIR failure", bisected
-  round 4; collect per-segment values and reduce once after all scans —
-  see ``_tree_sum_axis0`` in ``crypto/tpu/curve.py``).
+  round 4; run one scan and reduce what it collected once after it —
+  see ``scalar_mul_rlc_g1`` in ``crypto/tpu/curve.py``).
 * HBT005 — wire-deserialization and verify-batch surfaces must reach a
   subgroup check on point inputs (CLAUDE.md: "wire-sourced points MUST
   get subgroup checks somewhere").
@@ -415,9 +415,9 @@ def rule_scan_accumulator(path: str, src: str, tree: ast.AST) -> List[Finding]:
                         f"accumulator '{name}' is updated between sequential"
                         " lax.scan segments without flowing through the scan"
                         " carry: XLA 0.9.0 dies with 'Unknown MLIR failure'"
-                        " on this shape (bisected round 4). Collect the"
-                        " per-segment values and reduce once AFTER all scans"
-                        " (see _tree_sum_axis0 in crypto/tpu/curve.py)",
+                        " on this shape (bisected round 4). Run one scan,"
+                        " collect what it yields and reduce once AFTER it"
+                        " (see scalar_mul_rlc_g1 in crypto/tpu/curve.py)",
                     )
                 )
     return findings
