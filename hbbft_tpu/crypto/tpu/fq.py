@@ -30,6 +30,12 @@ Design rules (each independently forced by TPU constraints):
 * **Value folding**: ops that grow values re-fold bits above 2^385
   through ``2^385 mod P`` (≈ 0.7P, shrinking ≈5 bits per stage); the
   number of stages is chosen statically per op from its worst-case bound.
+* **A carry round is slices of one array**: the input is padded once and
+  every round reads windows of it, never a ``pad`` or ``concatenate`` of
+  an intermediate.  XLA on the TPU materialises a shifted intermediate,
+  at two kernels a round, and a step's device time follows its kernel
+  count (a few hundred ns each), not its arithmetic.  Likewise a fold
+  stage is a constant matmul, not a per-row scalar broadcast back.
 
 Invariant (every public op requires and guarantees):
     limbs in [0, 4096],  value in [0, 2^385.9).
@@ -128,33 +134,71 @@ def from_mont_int(a) -> int:
     return (from_limbs_int(a) * pow(R, -1, P)) % P
 
 
-def _carry(x: jnp.ndarray, rounds: int) -> jnp.ndarray:
+def _pad_limbs(x: jnp.ndarray, below: int, above: int) -> jnp.ndarray:
+    """Zero limbs added below and above the limb axis."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(below, above)])
+
+
+def _carry(x: jnp.ndarray, rounds: int, width: int | None = None) -> jnp.ndarray:
     """Redistribute nonneg limbs down to [0, 4096]; value preserved.
 
-    Pads rounds+1 limbs so the top limb never receives a carry (carries
-    travel one limb per round) — value conservation is structural, and
-    all quantities stay nonnegative (input limbs must be >= 0).
+    The result has ``n + rounds + 1`` limbs for an input of ``n`` (or
+    its lowest ``width``).  Carries travel one limb per round, so limb j
+    of the result reads input limbs j - rounds .. j only: the input is
+    padded ONCE with ``rounds`` zero guard limbs below and ``rounds + 1``
+    above, and ``ws[k]`` is its window starting at guard offset k.  One
+    round maps windows k, k + 1 to ``(ws[k + 1] & MASK) + (ws[k] >> B)``
+    (each limb keeps its low bits and takes the carry of the limb below;
+    below limb 0 a guard, whose carry is 0); after ``rounds`` rounds the
+    one window left is the result.  The carry out of the top limb is read
+    by no window, and the top limb never receives a carry (carries start
+    in the input's ``n`` limbs and the ``rounds + 1`` zero limbs above
+    absorb them), so value conservation is structural, and all quantities
+    stay nonnegative (input limbs must be >= 0).  Every round is
+    elementwise on slices of the one padded array: nothing shifted is
+    materialised between rounds (module docstring).
     """
-    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, rounds + 1)])
+    if width is None:
+        width = x.shape[-1] + rounds + 1
+    x = _pad_limbs(x, rounds, rounds + 1)
+    ws = [x[..., k : k + width] for k in range(rounds + 1)]
     for _ in range(rounds):
-        lo = x & MASK
-        c = x >> B
-        x = lo + jnp.pad(c[..., :-1], [(0, 0)] * (x.ndim - 1) + [(1, 0)])
-    return x
+        ws = [(ws[k + 1] & MASK) + (ws[k] >> B) for k in range(len(ws) - 1)]
+    return ws[0]
+
+
+def _fold_mat() -> np.ndarray:
+    """(NL + 2, NL - 1) constant of one fold stage: the identity on limbs
+    0..34 and, for the excess limb 35 + k, ``FOLD385 · 2^(11k)``.  Row
+    37's entries do not fit int32 and are kept modulo 2^32, as the
+    products ``e · FOLD385`` they stand for wrap; that limb is 0 for
+    every value < 2^407, so the product is exact in range."""
+    M = np.zeros((NL + 2, NL - 1), dtype=np.int64)
+    M[: NL - 1] = np.eye(NL - 1, dtype=np.int64)
+    for k in range(3):
+        M[NL - 1 + k] = FOLD385.astype(np.int64) << (B * k)
+    return ((M + (1 << 31)) % (1 << 32) - (1 << 31)).astype(np.int32)
+
+
+_FOLD_MAT = _fold_mat()
 
 
 def _fold(x: jnp.ndarray, stages: int) -> jnp.ndarray:
     """Fold value bits above 2^385 back in via 2^385 mod P, ``stages``
     times, then truncate to NL limbs.  Requires nonneg limbs (post-carry)
     and value < 2^398; each stage shrinks the excess ~5 bits, and the
-    final truncation is provably lossless for value < 2^396."""
-    for _ in range(stages):
-        e = x[..., NL - 1]
-        for i in range(NL, min(x.shape[-1], NL + 2)):
-            e = e + x[..., i] * (1 << (B * (i - (NL - 1))))
-        folded = x[..., : NL - 1] + e[..., None] * jnp.asarray(FOLD385)
-        x = _carry(folded, rounds=2)
-    return x[..., :NL]
+    final truncation is provably lossless for value < 2^396.
+
+    A stage is one constant matmul (``_FOLD_MAT``: the low limbs plus
+    the excess ``e = limbs 35..37`` times FOLD385, in the style of
+    ``_conv_const``) and a 2-round carry: no per-row scalar is read out
+    and broadcast back across the limbs.  The last stage's carry makes
+    the NL limbs kept and no more."""
+    for s in range(stages):
+        k = min(x.shape[-1], NL + 2)
+        folded = jnp.matmul(x[..., :k], jnp.asarray(_FOLD_MAT[:k]))
+        x = _carry(folded, rounds=2, width=NL if s == stages - 1 else None)
+    return x
 
 
 def _conv(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -205,6 +249,9 @@ def _conv_const(a: jnp.ndarray, mat: np.ndarray) -> jnp.ndarray:
     return jnp.matmul(a, jnp.asarray(mat))
 
 
+_LANE0 = np.eye(1, NL, dtype=np.int32)[0]  # 1 at limb 0
+
+
 def mont_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Montgomery product a·b·R^-1 (mod P), batched.
 
@@ -214,17 +261,14 @@ def mont_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     read off limb 35.  Inputs: value < 2^386.  Output: value < 2^382.5.
     """
     t = _carry(_conv(a, b), rounds=3)
-    m = _carry(_conv_const(t[..., :NL], _NPRIME_MAT), rounds=3)[..., :NL]
-    mp = _conv_const(m, _P_MAT)
-    full = jnp.pad(
-        t, [(0, 0)] * (t.ndim - 1) + [(0, max(0, mp.shape[-1] - t.shape[-1]))]
-    )
-    full = full.at[..., : mp.shape[-1]].add(mp)
-    full = _carry(full, rounds=3)
-    lo, hi = full[..., :NL], full[..., NL : 2 * NL]
-    # value(lo) = corr·R exactly; limb 35 sits in [2048·corr - 3, 2048·corr].
-    corr = (lo[..., NL - 1] + 3) >> B
-    return _carry(hi.at[..., 0].add(corr), rounds=1)[..., :NL]
+    m = _carry(_conv_const(t[..., :NL], _NPRIME_MAT), rounds=3, width=NL)
+    mp = _conv_const(m, _P_MAT)  # 2*NL - 1 limbs, under t's 2*NL + 3
+    full = _carry(t + _pad_limbs(mp, 0, t.shape[-1] - mp.shape[-1]), rounds=3)
+    # value(full[:NL]) = corr·R exactly; limb 35 sits in
+    # [2048·corr - 3, 2048·corr].  corr is added to limb NL as a lane of
+    # a window (the window's lane 0 is limb 35), not as a per-row scalar.
+    corr = ((full[..., NL - 1 : 2 * NL - 1] + 3) >> B) * jnp.asarray(_LANE0)
+    return _carry(full[..., NL : 2 * NL] + corr, rounds=1, width=NL)
 
 
 def mont_sqr(a: jnp.ndarray) -> jnp.ndarray:

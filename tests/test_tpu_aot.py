@@ -5,7 +5,8 @@ the flush path can be lowered and compiled for one v5e chip on a box
 that has none: a tiling the chip refuses, a program that does not fit
 its memory, a 64-bit op that slipped in — all raise here, at no chip
 time.  Nothing runs, so nothing here says anything about results or
-speed.
+speed; what the compiler emits can be counted, though, and the field
+ops' kernel counts are held under ceilings.
 
 Only one process at a time may load the TPU library, so the topology is
 described inside a fixture (never at import or collection time), all
@@ -29,6 +30,7 @@ from jax.sharding import SingleDeviceSharding
 
 from hbbft_tpu.crypto.tpu import curve as dcurve
 from hbbft_tpu.crypto.tpu import fq
+from hbbft_tpu.crypto.tpu import pairing as dpairing
 
 ROWS = 2048  # one production chunk (TpuBackend.CHUNK)
 
@@ -121,6 +123,44 @@ def test_kernel_compiles_for_v5e(case, one_chip, cache_off):
     if case is _keccak_case:
         # the Pallas kernel itself, not an XLA fallback
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def _entry_fusions(hlo_text):
+    """``fusion`` instructions of the ENTRY computation of an optimized
+    HLO module: the kernels one call launches, loops aside."""
+    entry = hlo_text.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    return sum(" fusion(" in line for line in entry.splitlines())
+
+
+# Fusions in one call, on a described v5e (cache off).  The device time of
+# a step follows its kernel count at 0.15-0.21 us a kernel, not its
+# arithmetic, so these counts say how far the limb normalisation's fusion
+# reaches; no span or counter sees it at run time.  Counted 2026-10-17:
+#   before (a carry round padding its shifted carry back, the fold's
+#   excess read out per row and broadcast back): add 10, sub 23,
+#   mont_mul 25, Fq12 sqr 344;
+#   now (a carry round is slices of one padded array, a fold stage one
+#   constant matmul): add 3, sub 6, mont_mul 8, Fq12 sqr 114.
+_FUSION_CEILINGS = [
+    ("fq_add", fq.add, 2, (36, fq.NL), 4),
+    ("fq_sub", fq.sub, 2, (36, fq.NL), 8),
+    ("fq_mont_mul", fq.mont_mul, 2, (36, fq.NL), 10),
+    ("fq12_sqr", dpairing.sqr, 1, (6, 2, fq.NL), 140),
+]
+
+
+@pytest.mark.parametrize(
+    "name,fn,arity,shape,ceiling",
+    _FUSION_CEILINGS,
+    ids=[c[0] for c in _FUSION_CEILINGS],
+)
+def test_field_op_launches_few_kernels_on_v5e(
+    name, fn, arity, shape, ceiling, one_chip, cache_off
+):
+    arg = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(fn).lower(*[arg] * arity).compile()
+    n = _entry_fusions(compiled.as_text())
+    assert 0 < n <= ceiling, f"{name}: {n} fusions, ceiling {ceiling}"
 
 
 def test_join_program_compiles_for_v5e_into_the_pair_programs_arguments(

@@ -91,6 +91,185 @@ def test_fq_limb_invariant_zero_and_identity():
     assert fq.from_mont_int(np.asarray(fq.mont_mul(one, one))) == 1
 
 
+# The limb normalisation as it was written before each carry round became
+# slices of one padded array (a shifted carry padded back per round, the
+# fold's excess read out per row and broadcast back, ``.at[...].add``
+# updates in mont_mul's tail).  Kept as the reference the slice form must
+# match limb for limb.
+
+
+def _carry_ref(x, rounds):
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, rounds + 1)])
+    for _ in range(rounds):
+        lo = x & fq.MASK
+        c = x >> fq.B
+        x = lo + jnp.pad(c[..., :-1], [(0, 0)] * (x.ndim - 1) + [(1, 0)])
+    return x
+
+
+def _fold_ref(x, stages):
+    NL = fq.NL
+    for _ in range(stages):
+        e = x[..., NL - 1]
+        for i in range(NL, min(x.shape[-1], NL + 2)):
+            e = e + x[..., i] * (1 << (fq.B * (i - (NL - 1))))
+        folded = x[..., : NL - 1] + e[..., None] * jnp.asarray(fq.FOLD385)
+        x = _carry_ref(folded, rounds=2)
+    return x[..., :NL]
+
+
+def _mont_mul_ref(a, b):
+    NL = fq.NL
+    t = _carry_ref(fq._conv(a, b), rounds=3)
+    m = _carry_ref(fq._conv_const(t[..., :NL], fq._NPRIME_MAT), rounds=3)[..., :NL]
+    mp = fq._conv_const(m, fq._P_MAT)
+    full = jnp.pad(
+        t, [(0, 0)] * (t.ndim - 1) + [(0, max(0, mp.shape[-1] - t.shape[-1]))]
+    )
+    full = full.at[..., : mp.shape[-1]].add(mp)
+    full = _carry_ref(full, rounds=3)
+    lo, hi = full[..., :NL], full[..., NL : 2 * NL]
+    corr = (lo[..., NL - 1] + 3) >> fq.B
+    return _carry_ref(hi.at[..., 0].add(corr), rounds=1)[..., :NL]
+
+
+_CVEC, _DELTA = jnp.asarray(fq.CVEC), jnp.asarray(fq.DELTA)
+# (reference, program, arity, Python-int value it must be congruent to mod P)
+_FIELD_OPS = {
+    "add": (
+        lambda a, b: _fold_ref(_carry_ref(a + b, 2), 1), fq.add, 2,
+        lambda a, b: a + b,
+    ),
+    "sub": (
+        lambda a, b: _fold_ref(_carry_ref(a + (_CVEC - b) + _DELTA, 2), 3),
+        fq.sub, 2, lambda a, b: a - b,
+    ),
+    "neg": (
+        lambda a: _fold_ref(_carry_ref((_CVEC - a) + _DELTA, 2), 3), fq.neg, 1,
+        lambda a: -a,
+    ),
+    "small_mul": (
+        lambda a: _fold_ref(_carry_ref(a * 16, 2), 1),
+        lambda a: fq.small_mul(a, 16), 1, lambda a: 16 * a,
+    ),
+    "normalize": (
+        lambda a: _fold_ref(_carry_ref(a, 2), 1), fq.normalize, 1, lambda a: a,
+    ),
+    "mont_mul": (
+        _mont_mul_ref, fq.mont_mul, 2,
+        lambda a, b: a * b * pow(fq.R, -1, P),
+    ),
+}
+
+
+def _redundant(rng, v, n, limb_max):
+    """Limbs of value ``v`` (n of them, each in [0, limb_max]): the strict
+    limbs with random multiples of 2^11 moved down one limb, top first."""
+    x = fq.to_limbs_np(v, n).astype(np.int64)
+    for i in range(n - 2, -1, -1):
+        t = int(rng.integers(0, min(x[i + 1], (limb_max - x[i]) // 2048) + 1))
+        x[i] += 2048 * t
+        x[i + 1] -= t
+    assert fq.from_limbs_int(x) == v and x.min() >= 0 and x.max() <= limb_max
+    return x.astype(np.int32)
+
+
+def _under(rng, bound_bits, n, limb_max, rows):
+    """``rows`` redundant limb vectors with values under 2^bound_bits by
+    less than 2^296."""
+    top = int(2.0**bound_bits)
+    return np.stack([
+        _redundant(rng, top - 1 - int.from_bytes(rng.bytes(37), "big"), n, limb_max)
+        for _ in range(rows)
+    ])
+
+
+@pytest.mark.parametrize("rounds", [1, 2, 3])
+@pytest.mark.parametrize(
+    "kind", ["random_4096", "random_raw_sums", "all_4096", "random_conv_sums"]
+)
+def test_carry_matches_the_padded_shift_form(rng, kind, rounds):
+    """Limb for limb, every width a call site carries (35, 36, 71, 75)
+    and the lowest ``width`` limbs alone."""
+    for n in (35, 36, 71, 75):
+        if kind == "random_4096":
+            x = rng.integers(0, 4097, size=(64, n))
+        elif kind == "random_raw_sums":  # what ``sub`` accepts
+            x = rng.integers(0, 1 << 15, size=(64, n))
+        elif kind == "all_4096":
+            x = np.full((4, n), 4096)
+        else:  # the 36-term sums of a convolution, < 2^29.2
+            x = rng.integers(0, 1 << 29, size=(64, n))
+        x = jnp.asarray(x, dtype=jnp.int32)
+        want = np.asarray(_carry_ref(x, rounds))
+        got = np.asarray(fq._carry(x, rounds))
+        assert got.shape == want.shape == (x.shape[0], n + rounds + 1)
+        np.testing.assert_array_equal(got, want)
+        for width in (fq.NL, n):
+            np.testing.assert_array_equal(
+                np.asarray(fq._carry(x, rounds, width=width)), want[:, :width]
+            )
+
+
+@pytest.mark.parametrize("stages", [1, 3])
+@pytest.mark.parametrize(
+    "kind", ["carried_sums", "under_2_398", "all_4096", "carried_raw_sums"]
+)
+def test_fold_matches_the_scalar_broadcast_form(rng, kind, stages):
+    """Post-carry inputs in the fold's range (value < 2^398), at the widths
+    ``add`` and ``sub`` hand it (39 limbs) and a fold stage makes (38)."""
+    if kind == "carried_sums":  # what add / normalize fold
+        x = _carry_ref(jnp.asarray(rng.integers(0, 4097, size=(64, fq.NL)), jnp.int32), 2)
+    elif kind == "carried_raw_sums":  # what sub folds: value < 2^403
+        x = _carry_ref(jnp.asarray(rng.integers(0, 1 << 16, size=(64, fq.NL)), jnp.int32), 2)
+    elif kind == "under_2_398":
+        x = jnp.asarray(_under(rng, 398, 39, 4096, 32))
+    else:  # value about 2^397
+        x = jnp.full((4, fq.NL), 4096, jnp.int32)
+    for w in sorted({x.shape[-1], 38}):
+        xw = x[..., :w]
+        if w < x.shape[-1]:
+            assert not np.asarray(x[..., w:]).any()
+        want = np.asarray(_fold_ref(xw, stages))
+        got = np.asarray(fq._fold(xw, stages))
+        assert got.shape == want.shape == (x.shape[0], fq.NL)
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kind", ["random", "at_the_bounds"])
+@pytest.mark.parametrize("op", list(_FIELD_OPS))
+def test_field_op_matches_the_reference_normalisation_and_ints(rng, op, kind):
+    """Every public op of ``fq`` equals its reference form limb for limb
+    and is congruent mod P to the Python-int result; its limbs stay in
+    [0, 4096] (and mont_mul's value under 2^382.5).
+    ``at_the_bounds``: values just under each op's input bound: 2^385.9
+    (the invariant), 2^389 with limbs up to 2^15 - 1 (``sub``'s raw
+    sums), 2^386 (``mont_mul``)."""
+    ref, prog, arity, want = _FIELD_OPS[op]
+    rows = 32
+    if kind == "random":
+        args = [
+            np.stack([fq.to_mont_np(int.from_bytes(rng.bytes(48), "big") % P)
+                      for _ in range(rows)])
+            for _ in range(arity)
+        ]
+    elif op == "sub":
+        args = [_under(rng, 389, fq.NL, (1 << 15) - 1, rows) for _ in range(2)]
+    elif op == "mont_mul":
+        args = [_under(rng, 386, fq.NL, 4096, rows) for _ in range(2)]
+    else:
+        args = [_under(rng, 385.9, fq.NL, 4096, rows) for _ in range(arity)]
+    args = [jnp.asarray(a) for a in args]
+    got = np.asarray(prog(*args))
+    np.testing.assert_array_equal(got, np.asarray(ref(*args)))
+    assert got.min() >= 0 and got.max() <= 4096
+    for r in range(rows):
+        vals = [fq.from_limbs_int(a[r]) for a in args]
+        v = fq.from_limbs_int(got[r])
+        assert op != "mont_mul" or v < 2**382.5
+        assert (v - want(*vals)) % P == 0
+
+
 # ---------------------------------------------------------------------------
 # Fq2
 # ---------------------------------------------------------------------------
