@@ -63,7 +63,7 @@ from hbbft_tpu.crypto.flush_shapes import pairs_bucket as _pairs_bucket
 from hbbft_tpu.crypto.flush_shapes import scan_shape as _scan_shape
 from hbbft_tpu.crypto.tpu import curve as dcurve
 from hbbft_tpu.crypto.tpu import pairing as dpairing
-from hbbft_tpu.utils import canonical_bytes
+from hbbft_tpu.utils import canonical_bytes, op_scopes
 from hbbft_tpu.utils.metrics import Metrics
 
 NBITS = 128  # RLC coefficient width
@@ -140,8 +140,10 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
     The jitted function is named ``hbbft_scan_<n_g1>_<n_g2>_<n_legs>``, so
     a device trace's module is ``jit_hbbft_scan_...`` whatever a refactor
     renumbers, and its stages sit under the ``jax.named_scope``s
-    ``scan_g1``, ``scan_g2``, ``subgroup`` and ``leg_sums`` (metadata of
-    the ops: the compiled code is the same with and without them).
+    ``scan_g1``, ``g2_stage`` (the predicate and the ``cond``; inside it
+    ``scan_g2``, ``subgroup`` and ``leg_sums``), ``subgroup`` and
+    ``leg_sums`` (metadata of the ops: the compiled code is the same with
+    and without them; :func:`_program_scopes` reads them back).
     """
 
     def run(
@@ -160,11 +162,12 @@ def _scan_kernel(n_g1: int, n_g2: int, n_legs: int):
         # tests/test_tpu_crypto.py.
         with jax.named_scope("scan_g1"):
             scaled1, chain1 = dcurve.scalar_mul_rlc_g1(g1_pts, g1_bits)
-        sub2, gen_leg = jax.lax.cond(
-            _any_g2_row(g2_pts[3], g2_chk),
-            _g2_stage, _g2_stage_skipped,
-            g2_pts, g2_bits_s, g2_bits_q,
-        )
+        with jax.named_scope("g2_stage"):
+            sub2, gen_leg = jax.lax.cond(
+                _any_g2_row(g2_pts[3], g2_chk),
+                _g2_stage, _g2_stage_skipped,
+                g2_pts, g2_bits_s, g2_bits_q,
+            )
         with jax.named_scope("subgroup"):
             sub1 = dcurve.endo_subgroup_eq(dcurve.G1_OPS, g1_pts, chain1)
             sub_ok = (
@@ -239,6 +242,21 @@ def _join_kernel(n_pairs: int):
 
     run.__name__ = run.__qualname__ = f"hbbft_join_{n_pairs}"
     return jax.jit(run)
+
+
+def _program_scopes(kernel, args) -> str:
+    """The ``scopes`` arg of a program's dispatch span: the op-to-scope map
+    (``utils/op_scopes.py``) of the executable that ``kernel`` runs on
+    ``args``.  Asked of the jit after its first call on them, so that its
+    caches answer: the trace is a cache hit and nothing is lowered or
+    compiled again, and the HLO is that of the executable that runs,
+    compiled here or read back from the persistent cache (whose key leaves
+    the scopes out: an executable compiled before a scope existed names its
+    ops by the scopes it had).  Empty for a kernel with no HLO (a stub)."""
+    lower = getattr(kernel, "lower", None)
+    if lower is None:
+        return ""
+    return op_scopes.encode(*op_scopes.scopes_of_hlo(lower(*args).compile().as_text()))
 
 
 #: PAIR-stage compiles started ahead of their first use, by pair count.
@@ -320,16 +338,18 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.scan_dispatch``, ``crypto.tpu.rhs_prep`` (``legs``,
     ``hashed``), ``crypto.tpu.pair_dispatch`` (``pairs``: the join program,
     then the pair program) and ``crypto.tpu.verdict_sync`` (the host blocked
-    on the device).  The scan program reads none of the G2 points its leg
-    sums pair with, so it is dispatched before they exist: ``rhs_prep``
-    hashes each distinct leg that brings no point (one
+    on the device).  Each dispatch span carries ``scopes``, its program's
+    op-to-scope map (:func:`_program_scopes`): built once a shape, after the
+    program's first call (set-up), noted on that call's span and passed to
+    every later one as it opens.  The scan program reads none of the G2
+    points its leg sums pair with, so it is dispatched before they exist:
+    ``rhs_prep`` hashes each distinct leg that brings no point (one
     ``crypto.tpu.hash_to_g2`` (``bytes``) a document or ciphertext, however
     many requests share it; a ciphertext's ``W`` comes ready) and puts the
     points on the device while the scan runs.  The
     ``crypto.tpu.scan_prep`` (``rows``, ``n1``, ``n2``, ``legs``, ``handed``:
     1 where the shape is the chunk's, handed down to a group whose own
-    bucket is smaller, else 0; ``g2``: 0 where the group brings no G2 row,
-    so the scan program skips its G2 stage, else 1; inside it
+    bucket is smaller, else 0; inside it
     ``crypto.tpu.coefficients``, ``crypto.tpu.build_legs``,
     ``crypto.tpu.pack``: everything the scan program reads) before the
     dispatches is the check's own: the flush's, or that of a bisection
@@ -347,12 +367,13 @@ class TpuBackend(CryptoBackend):
     ``crypto.tpu.g1_rows`` and ``crypto.tpu.g2_rows`` (real rows of every
     ``scan_prep``), ``crypto.tpu.rows_padded`` (bucket rows less real
     rows, G1 and G2 summed), ``crypto.tpu.groups_handed_shape`` (the
-    ``scan_prep``s with ``handed`` 1), ``crypto.tpu.g2_stage_skipped`` (those
-    with ``g2`` 0), ``crypto.tpu.hash_to_g2_calls``,
+    ``scan_prep``s with ``handed`` 1), ``crypto.tpu.hash_to_g2_calls``,
     ``crypto.tpu.rhs_hashed`` (legs hashed in an ``rhs_prep``, so after
     their group's scan was dispatched), ``crypto.tpu.leaves``,
     ``crypto.tpu.prepared_ahead`` (``scan_prep``s that ran between a
     check's ``pair_dispatch`` and its ``verdict_sync``),
+    ``crypto.tpu.scope_map_bytes`` (the dispatch spans' ``scopes``, summed
+    once a program; the timer ``crypto.tpu.scope_map`` times building them),
     ``crypto.tpu.requests.<kind>`` (well-formed requests that entered a
     flush, by kind; once a flush, not again in its groups).
     A flush of several chunks dispatches every chunk's scan, then makes
@@ -375,6 +396,9 @@ class TpuBackend(CryptoBackend):
         # and chipbench/tests answer through it.
         self._eager = EagerBackend(self.suite)
         self._mesh = _shard_mesh() if shard else None
+        # ("scan", n1, n2, legs) or ("pair", pairs) -> its dispatch span's
+        # ``scopes``, once the program has run
+        self._scopes: Dict[Tuple[Any, ...], str] = {}
 
     # -- leg construction (host, cheap): mirrors backend._rlc_pairs ----
 
@@ -481,8 +505,29 @@ class TpuBackend(CryptoBackend):
         (n1, n2, nl), args, _ = prepared
         if alone and self._mesh is None:
             _compile_pair_kernel_early(_pairs_bucket(1 + nl))
-        with self.metrics.span("crypto.tpu.scan_dispatch"):
-            return _scan_kernel(n1, n2, nl)(*args)
+        key = ("scan", n1, n2, nl)
+        with self.metrics.span("crypto.tpu.scan_dispatch", **self._scopes_arg(key)) as note:
+            kernel = _scan_kernel(n1, n2, nl)
+            out = kernel(*args)
+            self._map_scopes(key, kernel, args, note)
+            return out
+
+    def _scopes_arg(self, key) -> Dict[str, str]:
+        """The dispatch span's ``scopes`` where the program of ``key`` has
+        a map."""
+        scopes = self._scopes.get(key)
+        return {"scopes": scopes} if scopes else {}
+
+    def _map_scopes(self, key, kernel, args, note) -> None:
+        """After the first call of the program of ``key``: build its map
+        (set-up, once a shape) and note it on the span of that call."""
+        if key in self._scopes:
+            return
+        with self.metrics.timer("crypto.tpu.scope_map"):
+            self._scopes[key] = scopes = _program_scopes(kernel, args)
+        if scopes:
+            self.metrics.count("crypto.tpu.scope_map_bytes", len(scopes))
+            note(scopes=scopes)
 
     def _rhs_prep(self, prepared, scan):
         """The host's work under a dispatched scan: hash the chunk's
@@ -520,13 +565,10 @@ class TpuBackend(CryptoBackend):
                 _group_shape(reqs.chunk_shape, own) if isinstance(reqs, _Group) else own
             )
             handed = int(shape != own)
-            # Every G2 entry is checked, so "no entry" is the scan
-            # program's own predicate (_any_g2_row), read on the host.
-            note(n1=n1, n2=n2, legs=nl, handed=handed, g2=int(bool(g2e)))
+            note(n1=n1, n2=n2, legs=nl, handed=handed)
             self.metrics.count("crypto.tpu.groups_handed_shape", handed)
             self.metrics.count("crypto.tpu.g1_rows", len(g1e))
             self.metrics.count("crypto.tpu.g2_rows", len(g2e))
-            self.metrics.count("crypto.tpu.g2_stage_skipped", int(not g2e))
             self.metrics.count(
                 "crypto.tpu.rows_padded", n1 - len(g1e) + n2 - len(g2e)
             )
@@ -609,14 +651,19 @@ class TpuBackend(CryptoBackend):
         """
         n = sum(int(p[1][3].shape[0]) for p in parts)
         b = _pairs_bucket(n)
-        with self.metrics.span("crypto.tpu.pair_dispatch", pairs=b):
+        key = ("pair", b)
+        with self.metrics.span(
+            "crypto.tpu.pair_dispatch", pairs=b, **self._scopes_arg(key)
+        ) as note:
             lhs, rhs = _join_kernel(b)(
                 [p[1] for p in parts], [p[2] for p in parts], [p[3] for p in parts]
             )
             early = _EARLY_PAIR_COMPILES.get(b)
             if early is not None:
                 early.join()
-            ok = _pair_kernel(b)(lhs, rhs)
+            kernel = _pair_kernel(b)
+            ok = kernel(lhs, rhs)
+            self._map_scopes(key, kernel, (lhs, rhs), note)
             for p in parts:
                 ok = ok & p[0]
             return ok

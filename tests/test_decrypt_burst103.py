@@ -33,6 +33,8 @@ from hbbft_tpu.crypto import flush_shapes  # noqa: E402
 BURST = "chipbench.generators.decrypt_burst103"
 CELL = "wan104.decrypt"
 NEW_METRICS = ["scan256_ms", "scan256_roofline", "burst_decode_ms", "burst_pack_ms"]
+# added after the cell, for every cell: device time by the programs' named scopes
+SCOPE_METRICS = ["miller_loop_ms", "final_exp_ms", "scan_g1_ms", "device_unscoped_ms"]
 
 
 def _load(*parts):
@@ -166,11 +168,15 @@ def test_the_benchmark_gained_one_configuration_one_cell_and_four_metrics():
     assert [m["name"] for m in cell.metrics("end_to_end")] == [
         "verifies_per_s", "flush_ms.p50", "setup_s",
     ]
-    assert [m["name"] for m in cell.metrics("per_layer")] == NEW_METRICS
-    assert [m["name"] for m in bench_json["per_layer"][-4:]] == NEW_METRICS
-    # and no accepted cell reports them
+    assert [m["name"] for m in cell.metrics("per_layer")] == NEW_METRICS + SCOPE_METRICS
+    names = [m["name"] for m in bench_json["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    assert names[first:first + 4] == NEW_METRICS
+    assert set(names[first + 4:]) >= set(SCOPE_METRICS)
+    # and no cell accepted before it reports them
     for m in bench_json["per_layer"]:
-        assert (CELL in m["workloads"]) == (m["name"] in NEW_METRICS)
+        if m["name"] not in SCOPE_METRICS:
+            assert (CELL in m["workloads"]) == (m["name"] in NEW_METRICS)
 
 
 def _window(**over):

@@ -80,8 +80,8 @@ def _packed(suite, reqs):
 
 @pytest.mark.parametrize("case", sorted(PREDICATE_CASES))
 def test_the_predicate_on_what_pack_makes_of_a_group(material, case):
-    """False exactly where every G2 lane is padding; the host's reading
-    (``crypto.tpu.g2_stage_skipped``: no G2 entry) is the same answer."""
+    """False exactly where every G2 lane is padding; the host's reading (no
+    G2 entry) is the same answer."""
     suite, by_kind = material
     kinds, want = PREDICATE_CASES[case]
     reqs = [r for kind in kinds for r in by_kind[kind]]
@@ -89,7 +89,7 @@ def test_the_predicate_on_what_pack_makes_of_a_group(material, case):
     inf, chk = np.asarray(args[4][3]), np.asarray(args[7])
     assert bool(B._any_g2_row(inf, chk)) is want
     assert bool(g2e) is want
-    # every entry _build_legs makes is checked: what lets the host count it
+    # every entry _build_legs makes is checked: why the two readings agree
     assert all(check == 1 for _, _, check in g2e)
     if case == "a_lone_identity_share":
         # a REAL row that is the point at infinity: the identity flag alone

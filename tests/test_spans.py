@@ -627,11 +627,13 @@ G2_STAGE_CASES = {
 def test_the_g2_stage_is_counted_skipped_where_a_group_brings_no_g2_row(
     requests, decrypt_requests, monkeypatch, case
 ):
-    """``crypto.tpu.g2_stage_skipped`` and ``scan_prep``'s note ``g2``: a
-    flush of decryption shares alone and every group of it skip the stage,
-    a coin flush and its groups never do, and of a decrypt phase's
-    bisection exactly the groups that hold the ciphertext check (its ``W``
-    is the G2 row) run it."""
+    """The scan program's predicate for its G2 stage (``_any_g2_row``, on
+    the arguments each group's scan is dispatched with): a flush of
+    decryption shares alone and every group of it skip the stage, a coin
+    flush and its groups never do, and of a decrypt phase's bisection
+    exactly the groups that hold the ciphertext check (its ``W`` is the G2
+    row) run it.  The host counts no skip: the stage's device time
+    (``scan_g2_ms``) is its witness."""
     kind, n, wrong = G2_STAGE_CASES[case]
     suite, sig_reqs = requests
     if kind == "sig":
@@ -643,6 +645,19 @@ def test_the_g2_stage_is_counted_skipped_where_a_group_brings_no_g2_row(
     reqs = [VerifyRequest(r.kind, r.payload) for r in reqs]
     bad = {id(reqs[i]) for i in wrong}
     prepared = stub_kernels(monkeypatch, lambda group: not bad & {id(r) for r in group})
+    stage = []
+    stub = B._scan_kernel
+
+    def scan_kernel(n1, n2, nl):
+        run = stub(n1, n2, nl)
+
+        def call(*args):
+            stage.append(int(B._any_g2_row(args[4][3], args[7])))
+            return run(*args)
+
+        return call
+
+    monkeypatch.setattr(B, "_scan_kernel", scan_kernel)
     metrics = Metrics()
     backend = stubbed_backend(suite, metrics)
     session = Session()
@@ -653,20 +668,20 @@ def test_the_g2_stage_is_counted_skipped_where_a_group_brings_no_g2_row(
     assert got == [i not in wrong for i in range(len(reqs))]
     # a sig_share's share and a ciphertext's W are the G2 rows there are
     want = [int(any(r.kind != "dec_share" for r in group)) for group in prepared]
-    notes = sorted((s, a) for _, name, s, _, a in spans if name == "crypto.tpu.scan_prep")
-    assert [a["g2"] for _, a in notes] == want
+    assert stage == want
+    notes = [a for _, name, _, _, a in spans if name == "crypto.tpu.scan_prep"]
+    assert len(notes) == len(want) and not any("g2" in a for a in notes)
     counters = metrics.counters
-    skipped = counters.get("crypto.tpu.g2_stage_skipped", 0)
-    assert skipped == want.count(0)
+    assert "crypto.tpu.g2_stage_skipped" not in counters
     assert len(want) == counters["crypto.tpu.checks"]
-    assert (counters["crypto.tpu.g2_rows"] == 0) == (skipped == len(want))
+    assert (counters["crypto.tpu.g2_rows"] == 0) == (not any(want))
     if kind == "dec":
-        assert skipped == len(want)
+        assert not any(want)
     elif kind == "check+dec" and wrong:
         # the decrypt cells' probe: some groups of it took each branch
-        assert 0 < skipped < len(want)
+        assert 0 < sum(want) < len(want)
     else:
-        assert skipped == 0
+        assert all(want)
 
 
 # -- the hash to G2 runs under the dispatched scan ------------------------------
@@ -846,3 +861,71 @@ def test_the_two_programs_have_names_of_their_own(kernel, shape, name):
     ``jit_hbbft_scan_`` nor a ``jit_hbbft_pair_`` module."""
     jitted = kernel(*shape)
     assert jitted.__name__ == jitted.__wrapped__.__name__ == name
+
+
+# -- the dispatch spans carry their programs' op-to-scope maps ---------------
+
+def _scoped_programs(monkeypatch):
+    """Small jitted programs in the two kernels' places, with the kernels'
+    signatures and a loop under a named scope each; the pair program passes
+    every check."""
+    programs = {}
+
+    def scan_kernel(n1, n2, nl):
+        def hbbft_scan(*args):
+            with jax.named_scope("scan_g1"):
+                bits = jax.lax.fori_loop(0, 7, lambda i, b: b * 3 + 1, args[1])
+            return (
+                jnp.all(bits != 7),
+                dc.identity(dc.G1_OPS, (1 + nl,)),
+                dc.identity(dc.G2_OPS, (1,)),
+            )
+
+        return programs.setdefault(("scan", n1, n2, nl), jax.jit(hbbft_scan))
+
+    def pair_kernel(n_pairs):
+        def hbbft_pair(lhs, rhs):
+            with jax.named_scope("miller_loop"):
+                flags = jax.lax.fori_loop(0, 7, lambda i, f: f * 3 + 1, lhs[3])
+            return jnp.all(flags != 7)
+
+        return programs.setdefault(("pair", n_pairs), jax.jit(hbbft_pair))
+
+    monkeypatch.setattr(B, "_scan_kernel", scan_kernel)
+    monkeypatch.setattr(B, "_pair_kernel", pair_kernel)
+    monkeypatch.setattr(B, "_compile_pair_kernel_early", lambda n_pairs: None)
+
+
+def test_the_dispatch_spans_carry_their_programs_scopes(requests, monkeypatch):
+    """From a program's first call on, each dispatch span names, in its arg
+    ``scopes``, the scope of every op of the executable it runs: the first
+    dispatch of a shape notes it once the program has run, the next ones
+    carry it from the start.  A kernel with no HLO (a stub) gives none."""
+    from hbbft_tpu.utils import op_scopes
+
+    suite, reqs = requests
+    _scoped_programs(monkeypatch)
+    backend = stubbed_backend(suite)
+    session = Session()
+    try:
+        assert backend.verify_batch(reqs) == [True] * 16
+        assert backend.verify_batch(reqs) == [True] * 16
+    finally:
+        spans = session.spans()
+    dispatches = {"crypto.tpu.scan_dispatch": ("jit_hbbft_scan", "scan_g1"),
+                  "crypto.tpu.pair_dispatch": ("jit_hbbft_pair", "miller_loop")}
+    for name, (module, scope) in dispatches.items():
+        args = [a for _, n, _, _, a in spans if n == name]
+        assert len(args) == 2 and args[0]["scopes"] == args[1]["scopes"]
+        assert "," not in args[0]["scopes"]
+        got_module, scopes = op_scopes.decode(args[0]["scopes"])
+        assert got_module == module and scope in scopes.values()
+    # the stubs of the benchmark's traced tests have no HLO: no map, no arg
+    stub_kernels(monkeypatch, lambda group: True)
+    backend = stubbed_backend(suite)
+    session = Session()
+    try:
+        assert backend.verify_batch(reqs) == [True] * 16
+    finally:
+        spans = session.spans()
+    assert [a.get("scopes") for _, n, _, _, a in spans if n in dispatches] == [None] * 2

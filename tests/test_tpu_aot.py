@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 from hbbft_tpu.crypto.tpu import curve as dcurve
 from hbbft_tpu.crypto.tpu import fq
 from hbbft_tpu.crypto.tpu import pairing as dpairing
+from hbbft_tpu.utils import op_scopes
 
 ROWS = 2048  # one production chunk (TpuBackend.CHUNK)
 
@@ -245,9 +246,11 @@ def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache
     arguments (the right-hand points are not among them), and (once)
     ``_pair_kernel(3)`` on what ``_join_kernel(3)`` makes of the scan's
     result.  Every scan program's optimized HLO holds a ``conditional`` (the
-    G2 stage, run only where a flush brings a G2 row).  Prints seconds and
-    ``memory_analysis()`` for each (run with ``-s``);
-    with ``JAX_ENABLE_X64=0`` it compiles what the worker compiles."""
+    G2 stage, run only where a flush brings a G2 row), and every ``while``
+    and ``conditional`` of each program resolves to a named scope
+    (``utils/op_scopes.py``: what a trace's ops are put down to).  Prints
+    seconds and ``memory_analysis()`` for each (run with ``-s``); with
+    ``JAX_ENABLE_X64=0`` it compiles what the worker compiles."""
     from hbbft_tpu.crypto.tpu import backend as B
 
     suite, reqs = make(n_requests)
@@ -275,10 +278,19 @@ def test_flush_programs_compile_for_v5e(make, n_requests, shape, one_chip, cache
             f"module @jit_{name} "
         )
         mem = compiled.memory_analysis()
+        hlo = compiled.as_text()
         if name.startswith("hbbft_scan_"):
             # the G2 stage's ``cond`` is still a branch on the chip (XLA did
             # not turn it into a select, which would run both sides)
-            assert " conditional(" in compiled.as_text()
+            assert " conditional(" in hlo
+        # every loop and branch a device trace shows resolves to a scope
+        _, scopes = op_scopes.scopes_of_hlo(hlo)
+        control = [
+            line.split(" = ", 1)[0].split()[-1].lstrip("%")
+            for line in hlo.splitlines()
+            if " while(" in line or " conditional(" in line
+        ]
+        assert control and not [n for n in control if n not in scopes], name
         print(
             f"\nAOT v5e {name} x64={jax.config.jax_enable_x64}: "
             f"lower {t1 - t0:.0f} s, whole compile {t2 - t0:.0f} s, "

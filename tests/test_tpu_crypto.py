@@ -663,8 +663,31 @@ def test_the_g2_stages_two_branches_agree_where_no_g2_row_is_real(monkeypatch):
         assert verdicts == [want, want]
 
 
+def _g2_stage_runs(monkeypatch):
+    """Whether each call of the scan program runs its G2 stage, in order:
+    the program's own predicate on the arguments it is called with."""
+    from hbbft_tpu.crypto.tpu import backend as B
+
+    runs = []
+    kernel = B._scan_kernel
+
+    def scan_kernel(n1, n2, nl):
+        run = kernel(n1, n2, nl)
+
+        def call(*args):
+            runs.append(bool(B._any_g2_row(args[4][3], args[7])))
+            return run(*args)
+
+        return call
+
+    monkeypatch.setattr(B, "_scan_kernel", scan_kernel)
+    return runs
+
+
 @heavy_compile
-def test_a_decrypt_phases_flushes_are_answered_as_the_batched_backend_answers_them():
+def test_a_decrypt_phases_flushes_are_answered_as_the_batched_backend_answers_them(
+    monkeypatch,
+):
     """Both branches of the scan program's G2 stage against the host's RLC
     backend: a burst with one wrong share (every group of its bisection
     skips the stage), the burst with its ciphertext check and a wrong share
@@ -682,24 +705,27 @@ def test_a_decrypt_phases_flushes_are_answered_as_the_batched_backend_answers_th
         sks.public_keys().public_key_share(0), b"doc",
         SignatureShare(suite.g2_identity(), suite),
     )
+    runs = _g2_stage_runs(monkeypatch)
     for reqs, skipped_all in (
         (burst, True),
         ([check] + burst, False),
         ([infinity], True),
         ([sig_infinity], False),
     ):
+        runs.clear()
         backend = TpuBackend(suite)
         got = backend.verify_batch(reqs)
         assert got == host.verify_batch(reqs)
         assert not all(got)
-        counters = backend.metrics.counters
-        skipped = counters.get("crypto.tpu.g2_stage_skipped", 0)
+        checks = backend.metrics.counters["crypto.tpu.checks"]
+        assert len(runs) == checks
+        skipped = runs.count(False)
         if skipped_all:
-            assert skipped == counters["crypto.tpu.checks"]
+            assert skipped == checks
         elif len(reqs) == 1:
             assert skipped == 0
         else:
-            assert 0 < skipped < counters["crypto.tpu.checks"]
+            assert 0 < skipped < checks
 
 
 @heavy_compile
@@ -767,7 +793,7 @@ def test_tpu_backend_sharded_flush_matches():
 
 
 @heavy_compile
-def test_tpu_backend_sharded_decrypt_flush_skips_its_g2_stage_and_matches():
+def test_tpu_backend_sharded_decrypt_flush_skips_its_g2_stage_and_matches(monkeypatch):
     """The same mesh on a burst of decryption shares with one wrong: the
     predicate of the G2 stage's ``cond`` is a reduction over the sharded
     batch axis, every device takes the skipped branch, and the verdicts
@@ -776,13 +802,13 @@ def test_tpu_backend_sharded_decrypt_flush_skips_its_g2_stage_and_matches():
         pytest.skip("needs a multi-device platform")
     suite, _, shares, wrong, _ = _decrypt_phase(84)
     reqs = shares[:7] + [wrong] + shares[8:]
+    runs = _g2_stage_runs(monkeypatch)
     sharded = TpuBackend(suite, shard=True)
     assert sharded._mesh is not None
     got = sharded.verify_batch(reqs)
     assert got == BatchedBackend(suite).verify_batch(reqs)
     assert got == [i != 7 for i in range(15)]
-    counters = sharded.metrics.counters
-    assert counters["crypto.tpu.g2_stage_skipped"] == counters["crypto.tpu.checks"] > 1
+    assert not any(runs) and len(runs) == sharded.metrics.counters["crypto.tpu.checks"] > 1
 
 
 @heavy_compile
