@@ -150,23 +150,25 @@ class EagerBackend(CryptoBackend):
         return out
 
 
-def _warm_affine_caches(suite: Suite, reqs: Sequence[VerifyRequest]) -> None:
+def _warm_affine_caches(suite: Suite, reqs: Sequence[VerifyRequest]) -> int:
     """Batch-invert all points about to be serialized (one inversion per
-    group instead of two ``pow(·, -1, p)`` per request)."""
+    group instead of two ``pow(·, -1, p)`` per request); returns how many
+    points were converted (``BLSSuite.batch_affine``), 0 for a suite
+    without the method or a batch it refuses."""
     batch_affine = getattr(suite, "batch_affine", None)
     if batch_affine is None:
-        return
-    pts = []
-    for r in reqs:
-        for obj in r.payload:
-            for attr in ("g1", "g2", "u", "w"):
-                v = getattr(obj, attr, None)
-                if v is not None:
-                    pts.append(v)
+        return 0
     try:
-        batch_affine(pts)
+        pts = []
+        for r in reqs:
+            for obj in r.payload:
+                for attr in ("g1", "g2", "u", "w"):
+                    v = getattr(obj, attr, None)
+                    if v is not None:
+                        pts.append(v)
+        return batch_affine(pts)
     except Exception:
-        pass  # fall back to lazy per-element conversion
+        return 0  # fall back to lazy per-element conversion
 
 
 def _batch_coefficients(suite: Suite, reqs: Sequence[VerifyRequest]) -> List[int]:

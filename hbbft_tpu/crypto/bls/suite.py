@@ -207,29 +207,34 @@ class BLSSuite(Suite):
         aff_pairs = [(a.affine(), b.affine()) for a, b in pairs]
         return PR.multi_pairing_is_one(aff_pairs)
 
-    def batch_affine(self, elems: Sequence[Any]) -> None:
+    def batch_affine(self, elems: Sequence[Any]) -> int:
         """Warm the affine caches of many points with ONE field inversion
-        per group (Montgomery's batch-inversion trick).
+        per group (Montgomery's batch-inversion trick); returns how many
+        points it filled, the identity's ``None`` included.
 
         ``to_bytes``/``affine`` otherwise cost two ``pow(·, -1, p)`` per
         point, which dominates Fiat-Shamir coefficient derivation at
         flush batch sizes (BASELINE.md round-1 measurements).  Non-point
-        objects and already-cached/identity points are skipped.
+        objects and already-cached points are skipped, and so are points
+        whose bytes are cached (a decoded point: ``to_bytes`` needs no
+        affine form, and its z is 1, so a later ``affine()`` inverts 1).
+        An object listed more than once (a ciphertext's ``W`` in every
+        share of it) is converted once.
         """
+        filled = 0
         for cls, ops in ((G1Elem, C.FQ_OPS), (G2Elem, C.FQ2_OPS)):
-            todo = []
-            for e in elems:
-                if (
-                    type(e) is cls
-                    and e._affine is _UNSET
-                    and isinstance(e.jac, tuple)
-                    and len(e.jac) == 3
-                ):
-                    todo.append(e)
-            if not todo:
-                continue
+            todo = {
+                id(e): e
+                for e in elems
+                if type(e) is cls
+                and e._affine is _UNSET
+                and e._bytes is None
+                and isinstance(e.jac, tuple)
+                and len(e.jac) == 3
+            }
+            filled += len(todo)
             finite = []
-            for e in todo:
+            for e in todo.values():
                 if ops.is_zero(e.jac[2]):
                     e._affine = None
                 else:
@@ -251,6 +256,7 @@ class BLSSuite(Suite):
                     ops.mul(x, zi2),
                     ops.mul(y, ops.mul(zi2, z_inv)),
                 )
+        return filled
 
 
 # The decode memo's bound.  An N = 104 epoch's key shares (104 G1), its

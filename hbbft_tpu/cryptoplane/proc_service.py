@@ -69,7 +69,11 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import hbbft_tpu.wire  # noqa: F401  (registers the "vreq" serde struct)
-from hbbft_tpu.crypto.backend import CryptoBackend, VerifyRequest
+from hbbft_tpu.crypto.backend import (
+    CryptoBackend,
+    VerifyRequest,
+    _warm_affine_caches,
+)
 from hbbft_tpu.cryptoplane.service import CryptoPlaneService
 from hbbft_tpu.transport.framing import (
     CRYPTO_KINDS,
@@ -563,7 +567,6 @@ class RpcServiceClient(CryptoBackend):
         reqs = list(reqs)
         if not reqs:
             return []
-        items = self._encode_items(reqs)
         sock = self._ensure_conn()
         if sock is None:
             return self._local(reqs, "unavailable")
@@ -583,7 +586,7 @@ class RpcServiceClient(CryptoBackend):
                     sock.sendall(
                         encode_frame(
                             KIND_CRYPTO_REQ,
-                            serde.dumps((req_id, "verify", tuple(items))),
+                            self._encode(req_id, reqs),
                             self.max_frame_len,
                             kinds=CRYPTO_KINDS,
                         )
@@ -613,22 +616,22 @@ class RpcServiceClient(CryptoBackend):
                     requests=len(reqs), backend="rpc", span=span, ok=ok,
                 )
 
-    def _encode_items(self, reqs: List[VerifyRequest]) -> List[Any]:
-        # The common case (every payload a real suite object) costs one
-        # serde encode later; only when something refuses to encode do
-        # we probe per item and ship None placeholders.
-        try:
-            serde.dumps(tuple(reqs))
-            return list(reqs)
-        except Exception:
-            items: List[Any] = []
-            for r in reqs:
-                try:
-                    serde.dumps(r)
-                    items.append(r)
-                except Exception:
-                    items.append(None)
-            return items
+    def _encode(self, req_id: int, reqs: List[VerifyRequest]) -> bytes:
+        """The REQ payload, serialised once.  The points that lack their
+        bytes are first made affine in one inversion per group; only a
+        batch that then refuses to encode is probed item by item, and
+        ships ``None`` (which the server answers False) for each item
+        that does not encode."""
+        with self.metrics.timer("crypto.rpc.encode"):
+            self.metrics.count(
+                "crypto.rpc.encode_affine_points",
+                _warm_affine_caches(self.suite, reqs),
+            )
+            try:
+                return serde.dumps((req_id, "verify", tuple(reqs)))
+            except Exception:
+                items = tuple(r if _encodes(r) else None for r in reqs)
+                return serde.dumps((req_id, "verify", items))
 
     def _read_resp(self, req_id: int) -> Tuple[bool, bytes, int, int]:
         assert self._sock is not None and self._dec is not None
@@ -655,6 +658,14 @@ class RpcServiceClient(CryptoBackend):
         self.metrics.count("crypto.rpc.fallback_requests", len(reqs))
         self.metrics.count(f"crypto.rpc.fallback.{why}")
         return self.fallback.verify_batch(reqs)
+
+
+def _encodes(obj: Any) -> bool:
+    try:
+        serde.dumps(obj)
+        return True
+    except Exception:
+        return False
 
 
 def fetch_stats(

@@ -183,6 +183,35 @@ def test_batch_affine_edge_cases():
     assert p1.affine() == q.affine()
 
 
+@pytest.mark.parametrize("group", ["g1", "g2"])
+def test_batch_affine_converts_a_repeated_element_once(group):
+    """An element listed many times is converted once (the count it
+    returns), a decoded element (bytes cached) not at all, and every
+    element's ``affine()`` and ``to_bytes()`` equal the per-element
+    ``jac_to_affine`` result, identity points included."""
+    import pickle
+
+    from hbbft_tpu.crypto.bls import curve as oc
+    from hbbft_tpu.crypto.bls.suite import BLSSuite
+
+    suite = BLSSuite()
+    gen = getattr(suite, f"{group}_generator")()
+    ident = getattr(suite, f"{group}_identity")
+    shared = gen * 11
+    pts = [gen * k for k in (3, 5, 7)]
+    elems = [shared, pts[0], ident(), shared, pts[1], shared, ident(), pts[2]]
+    fresh = pickle.loads(pickle.dumps(elems))  # no memo crosses a pickle
+    assert fresh[0] is fresh[3] is fresh[5]
+    decoded = getattr(suite, f"{group}_from_bytes")((gen * 13).to_bytes())
+    assert suite.batch_affine(fresh * 3 + [decoded]) == 6
+    assert suite.batch_affine(fresh) == 0  # every cache already warm
+    assert decoded.affine() == (gen * 13).affine()
+    for got, ref in zip(fresh, elems):
+        assert got.affine() == oc.jac_to_affine(ref.ops, ref.jac)
+        assert got.to_bytes() == ref.to_bytes()
+    assert fresh[2].to_bytes() == b"\x00" * (97 if group == "g1" else 193)
+
+
 def test_native_kem_matches_python():
     """The native scalar-suite KEM (hbe_kem_encrypt/decrypt) is
     byte-identical to the pure-Python path: same ciphertext for the same

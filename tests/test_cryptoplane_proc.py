@@ -30,6 +30,7 @@ cryptoplane-smoke`` runs this with the round-13 tier.
 
 from __future__ import annotations
 
+import pickle
 import random
 import socket
 import threading
@@ -42,7 +43,7 @@ from hbbft_tpu.chaos.oracle import batch_keys, batches_sha, fault_entries
 from hbbft_tpu.crypto.backend import BatchedBackend, VerifyRequest
 from hbbft_tpu.crypto.keys import SecretKeySet
 from hbbft_tpu.crypto.suite import ScalarSuite
-from hbbft_tpu.cryptoplane import CryptoPlaneService
+from hbbft_tpu.cryptoplane import CryptoPlaneService, proc_service
 from hbbft_tpu.cryptoplane.proc_service import (
     CryptoRpcServer,
     RpcServiceClient,
@@ -56,6 +57,7 @@ from hbbft_tpu.transport.framing import (
     CRYPTO_KINDS,
     KIND_CRYPTO_HELLO,
     KIND_CRYPTO_REQ,
+    KIND_CRYPTO_RESP,
     KIND_MSG,
     FrameDecoder,
     FrameError,
@@ -494,6 +496,236 @@ def test_client_times_out_on_silent_service_and_recovers():
         listener.close()
         for s in conns:
             s.close()
+
+
+# ---------------------------------------------------------------------------
+# the client's encode: one inversion per group, one serialisation a frame
+# ---------------------------------------------------------------------------
+
+
+class _RecordingService:
+    """A fake service that handshakes, records every REQ payload, and
+    answers the k-th REQ of its one connection as request id k with
+    ``width`` True verdicts: the client's frames with no backend behind
+    them."""
+
+    def __init__(self, suite, width):
+        self.suite = suite
+        self.width = width
+        self.requests = []
+        self._listener = socket.socket()
+        self._listener.bind(("127.0.0.1", 0))
+        self._listener.listen(1)
+        self.addr = self._listener.getsockname()[:2]
+        threading.Thread(target=self._run, daemon=True).start()
+
+    def _run(self):
+        try:
+            sock, _ = self._listener.accept()
+        except OSError:
+            return
+        try:
+            sock.settimeout(10)
+            dec = FrameDecoder(kinds=CRYPTO_KINDS)
+            while True:
+                f = dec.next_frame()
+                if f is None:
+                    data = sock.recv(1 << 16)
+                    if not data:
+                        return
+                    dec.feed(data)
+                    continue
+                kind, payload = f
+                if kind == KIND_CRYPTO_HELLO:
+                    body = serde.dumps((1, self.suite.name))
+                else:
+                    kind = KIND_CRYPTO_RESP
+                    self.requests.append(payload)
+                    body = serde.dumps((
+                        len(self.requests), "verify", True,
+                        b"\x01" * self.width, self.width, 1,
+                    ))
+                sock.sendall(encode_frame(kind, body, kinds=CRYPTO_KINDS))
+        except (OSError, FrameError):
+            pass
+        finally:
+            sock.close()
+
+    def close(self):
+        self._listener.close()
+
+
+def _jacobian_batch():
+    """A BLS batch as a node or the benchmark builds it: key shares
+    evaluated from the commitment, shares and a ciphertext made by scalar
+    products, so every point is Jacobian with z != 1; a decryption share
+    at infinity; one ciphertext shared by four dec_share requests; a G2
+    signature share.  Pickled, as the benchmark's flushes are, so that no
+    point carries an affine form or bytes."""
+    from hbbft_tpu.crypto.bls import BLSSuite
+    from hbbft_tpu.crypto.keys import DecryptionShare
+
+    suite = BLSSuite()
+    rng = random.Random(9)
+    sks = SecretKeySet.random(2, rng, suite)
+    pks = sks.public_keys()
+    ct = pks.public_key().encrypt(b"one ciphertext, four shares", rng)
+    reqs = [
+        VerifyRequest.dec_share(
+            pks.public_key_share(i), ct,
+            sks.secret_key_share(i).decryption_share(ct),
+        )
+        for i in range(3)
+    ]
+    reqs.append(VerifyRequest.dec_share(
+        pks.public_key_share(3), ct, DecryptionShare(suite.g1_identity(), suite)
+    ))
+    reqs.append(VerifyRequest.sig_share(
+        pks.public_key_share(4), b"doc", sks.secret_key_share(4).sign(b"doc")
+    ))
+    return suite, pickle.loads(pickle.dumps(reqs))
+
+
+def _batch(suite_name):
+    """(suite, requests, distinct points the batch conversion fills)."""
+    if suite_name == "scalar":
+        suite, good, bad = _scalar_fixture()
+        return suite, [good, bad, good], 0
+    suite, reqs = _jacobian_batch()
+    # five key shares, U, W, three shares, the share at infinity, and
+    # the signature share (G2): W and U are named four times each
+    return suite, reqs, 12
+
+
+def _points(reqs):
+    return [
+        getattr(obj, attr)
+        for r in reqs
+        for obj in r.payload
+        for attr in ("g1", "g2", "u", "w")
+        if getattr(obj, attr, None) is not None
+    ]
+
+
+@pytest.mark.parametrize("suite_name", ["scalar", "bls"])
+def test_client_frame_bytes_equal_the_per_point_encoding(suite_name):
+    """The frame a client sends is byte for byte the frame of the same
+    batch serialised with each point made affine on its own
+    (``jac_to_affine``, one inversion a point), as a client did before
+    the batch conversion; the scalar suite's frame is its plain encode."""
+    suite, reqs, distinct = _batch(suite_name)
+    lazy = pickle.loads(pickle.dumps(reqs))
+    want = serde.dumps((1, "verify", tuple(lazy)))
+    if suite_name == "bls":
+        jac = [p for p in _points(reqs) if not p.is_identity()]
+        assert all(not p.ops.eq(p.jac[2], p.ops.one) for p in jac)
+        assert any(p.is_identity() for p in _points(reqs))
+        assert {type(p).__name__ for p in jac} == {"G1Elem", "G2Elem"}
+    svc = _RecordingService(suite, len(reqs))
+    try:
+        cli = RpcServiceClient(svc.addr, suite, BatchedBackend(suite),
+                               timeout_s=10.0)
+        assert cli.verify_batch(reqs) == [True] * len(reqs)
+        assert svc.requests == [want]
+        assert cli.metrics.counters[
+            "crypto.rpc.encode_affine_points"] == distinct
+        assert cli.metrics.timers["crypto.rpc.encode"].count == 1
+        assert cli.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("suite_name", ["scalar", "bls"])
+def test_clean_batch_costs_one_frame_encode(suite_name, monkeypatch):
+    """Once connected, a clean batch is serialised once: the frame it
+    sends (no probing encode of the batch before it)."""
+    suite, reqs, _ = _batch(suite_name)
+    svc = _RecordingService(suite, len(reqs))
+    try:
+        cli = RpcServiceClient(svc.addr, suite, BatchedBackend(suite),
+                               timeout_s=10.0)
+        assert cli.verify_batch(reqs[:1] * len(reqs))  # dial and HELLO
+        calls = []
+        real, me = serde.dumps, threading.get_ident()
+
+        def counting(obj):
+            if threading.get_ident() == me:
+                calls.append(obj)
+            return real(obj)
+
+        monkeypatch.setattr(serde, "dumps", counting)
+        assert cli.verify_batch(reqs) == [True] * len(reqs)
+        assert [c[:2] for c in calls] == [(2, "verify")]
+        assert calls[0][2] == tuple(reqs)
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("suite_name", ["scalar", "bls"])
+def test_unencodable_items_ship_none_placeholders(suite_name, monkeypatch):
+    """Junk that refuses to encode (an object in a payload, a payload that
+    is no tuple) rides as ``None``, which the server answers False, as
+    the local backend does: one probe per item, only on that path."""
+    junk = [
+        VerifyRequest("sig_share", (object(), b"m", object())),
+        VerifyRequest("dec_share", None),
+    ]
+    if suite_name == "scalar":
+        suite, good, bad = _scalar_fixture()
+        batch = [good, junk[0], bad, junk[1], good]
+    else:
+        from hbbft_tpu.crypto.bls import BLSSuite
+
+        suite = BLSSuite()
+        batch = junk + junk[:1]  # no pairing on the server: every item junk
+    want = BatchedBackend(suite).verify_batch(batch)
+    sent = []
+    real = proc_service.encode_frame
+
+    def recording(kind, payload, *a, **kw):
+        if kind == KIND_CRYPTO_REQ:
+            sent.append(payload)
+        return real(kind, payload, *a, **kw)
+
+    monkeypatch.setattr(proc_service, "encode_frame", recording)
+    server = _server(suite)
+    try:
+        cli = RpcServiceClient(
+            (server.host, server.port), suite, BatchedBackend(suite),
+        )
+        assert cli.verify_batch(batch) == want
+        assert cli.metrics.counters["crypto.rpc.calls"] == 1
+        assert cli.metrics.counters.get("crypto.rpc.fallbacks", 0) == 0
+        items = tuple(
+            None if any(r is j for j in junk) else r for r in batch
+        )
+        assert sent == [serde.dumps((1, "verify", items))]
+    finally:
+        server.stop()
+
+
+def test_encode_counts_no_point_of_a_decoded_batch():
+    """``crypto.rpc.encode_affine_points`` counts the points the batch
+    conversion made affine: every distinct Jacobian point of a fresh
+    batch once (``W`` and ``U`` shared by four requests count once), and
+    none of a batch decoded from wire bytes, whose points have their
+    bytes already; a batch sent twice converts nothing the second time."""
+    suite, reqs, distinct = _batch("bls")
+    copy = pickle.loads(pickle.dumps(reqs))
+    wire = serde.loads(serde.dumps(tuple(copy)), suite=suite)
+    svc = _RecordingService(suite, len(reqs))
+    try:
+        cli = RpcServiceClient(svc.addr, suite, BatchedBackend(suite),
+                               timeout_s=10.0)
+        counters = cli.metrics.counters
+        assert cli.verify_batch(wire) == [True] * len(reqs)
+        assert counters["crypto.rpc.encode_affine_points"] == 0
+        assert cli.verify_batch(reqs) == [True] * len(reqs)
+        assert counters["crypto.rpc.encode_affine_points"] == distinct
+        assert cli.verify_batch(reqs) == [True] * len(reqs)
+        assert counters["crypto.rpc.encode_affine_points"] == distinct
+    finally:
+        svc.close()
 
 
 # ---------------------------------------------------------------------------
